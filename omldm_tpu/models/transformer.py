@@ -30,7 +30,6 @@ import jax.numpy as jnp
 
 from omldm_tpu.ops.attention import attention
 from omldm_tpu.ops.ring_attention import ring_attention
-from omldm_tpu.utils.jaxcompat import axis_size
 
 
 @dataclasses.dataclass(frozen=True)
@@ -169,7 +168,7 @@ def _attention_block(cfg, layer, x, axes: AxisSpec):
     q = qkv[:, :, 0].reshape(b, lc, heads_local, dh)
     k = qkv[:, :, 1].reshape(b, lc, heads_local, dh)
     v = qkv[:, :, 2].reshape(b, lc, heads_local, dh)
-    if axes.sp and axis_size(axes.sp) > 1:
+    if axes.sp and jax.lax.axis_size(axes.sp) > 1:
         if cfg.seq_parallel == "ulysses":
             from omldm_tpu.ops.ulysses import ulysses_attention
 
@@ -228,7 +227,7 @@ def _moe_block_ep(layer, x, ep_axis: str, capacity_factor: float):
     switch semantics) — their block output is 0 and the residual carries
     them through."""
     b, lc, d = x.shape
-    ep = axis_size(ep_axis)
+    ep = jax.lax.axis_size(ep_axis)
     e_local = layer["w1"].shape[0]        # experts owned by this shard
     n_experts = ep * e_local
     t = x.reshape(-1, d)                  # [T, D] local tokens

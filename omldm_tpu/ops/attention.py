@@ -32,10 +32,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-# Default Pallas tile sizes. Forward and backward prefer different shapes
-# on v5e (bf16, causal L=8192, dh=64 — benchmarks/tune_flash_blocks.py):
-# the forward is fastest at 1024x1024, the dq/dkdv backward passes at
-# 512x1024. Override per call via block_q/block_k.
+# Default Pallas tile sizes; forward and backward use different shapes
+# (chosen on a v5e at bf16, causal L=8192, dh=64, under an earlier jax; not
+# re-tuned since). Override per call via block_q/block_k.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 DEFAULT_BWD_BLOCK_Q = 512
@@ -237,30 +236,21 @@ def _causal_q_index(block_q, block_k, q_offset, kv_offset, n_q):
 def _vma_struct_factory(ref_array):
     """ShapeDtypeStruct builder inheriting ``ref_array``'s varying-axis type
     (required for pallas_call outputs under shard_map's vma checking)."""
-    try:
-        vma = jax.typeof(ref_array).vma
-    except Exception:
-        vma = None
+    vma = jax.typeof(ref_array).vma
 
     def _struct(shape, dtype):
-        if vma:
-            return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
-        return jax.ShapeDtypeStruct(shape, dtype)
+        return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
 
     return _struct
 
 
 def _tpu_compiler_kwargs(interpret: bool) -> dict:
     """dimension_semantics for the canonical (parallel, parallel, arbitrary)
-    flash grids, tolerant of the CompilerParams name moving across JAX
-    versions."""
-    params_cls = getattr(pltpu, "CompilerParams", None) or getattr(
-        pltpu, "TPUCompilerParams", None
-    )
-    if interpret or params_cls is None:
+    flash grids; the interpreter takes no compiler parameters."""
+    if interpret:
         return {}
     return {
-        "compiler_params": params_cls(
+        "compiler_params": pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         )
     }

@@ -13,9 +13,7 @@ distributed SGD). Two families of kernels live here:
   twins of the host kernels for the SPMD engine, applied to the vectors
   entering/leaving the protocol collectives inside the compiled step.
   They are pure elementwise/reduction ops — no ``shard_map`` or
-  collective primitives of their own (anything that did need one would
-  route through ``omldm_tpu.utils.jaxcompat``, never raw
-  ``jax.shard_map``: the pinned jax 0.4.37 image lacks vma typing).
+  collective primitives of their own.
 
 Error feedback is the CALLER's job (the transport codec keeps per-stream
 residual accumulators; the SPMD step keeps an ``ef`` state leaf): the

@@ -3,9 +3,12 @@
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
+import warnings
 from typing import Optional, Tuple
 
 import numpy as np
@@ -13,15 +16,17 @@ import numpy as np
 _SRC = os.path.join(os.path.dirname(__file__), "fastparse.cpp")
 _BUILD_DIR = os.path.join(os.path.dirname(__file__), "_build")
 
+_BASE_FLAGS = ("-O3", "-shared", "-fPIC", "-pthread")
+# -march=native squeezes a few percent out of the SWAR paths; the plain
+# build is the fallback for toolchains/CPUs that reject it
+_FLAG_SETS = (("-march=native",) + _BASE_FLAGS, _BASE_FLAGS)
+
 
 def _host_tag() -> str:
     """ISA identity for the build cache: -march=native output is only
     valid on CPUs with the same feature set, and the cache can travel
     inside the package tree (containers, shared volumes) — a stale lib
     would SIGILL with no catchable error."""
-    import hashlib
-    import platform
-
     ident = platform.machine()
     try:
         with open("/proc/cpuinfo") as f:
@@ -34,36 +39,65 @@ def _host_tag() -> str:
     return ident
 
 
-_LIB_PATH = os.path.join(_BUILD_DIR, f"libfastparse_{_host_tag()}.so")
+def _lib_path(flags: Tuple[str, ...]) -> str:
+    """Library path for one (source, flags, host) triple. The name is a
+    hash of the source BYTES, the compile flags and the host tag, so an
+    object copied in with the tree or left from older source is never
+    loaded for this source — it simply has another name."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update("\0".join(flags).encode())
+    h.update(_host_tag().encode())
+    return os.path.join(_BUILD_DIR, f"libfastparse_{h.hexdigest()[:20]}.so")
+
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_failed = False
 
 
+def _compile() -> Optional[str]:
+    """Path of a library built from the current source, compiling it when
+    no object with the matching name exists; None when every compile
+    failed."""
+    os.makedirs(_BUILD_DIR, exist_ok=True)
+    errors = []
+    for flags in _FLAG_SETS:
+        path = _lib_path(flags)
+        if os.path.exists(path):
+            return path
+        # build beside the final name and rename: a concurrent process
+        # never dlopens a half-written object
+        tmp = f"{path}.{os.getpid()}.tmp"
+        try:
+            subprocess.run(
+                ["g++", *flags, "-o", tmp, _SRC],
+                check=True, capture_output=True, text=True, timeout=120,
+            )
+            os.replace(tmp, path)
+            return path
+        except (subprocess.SubprocessError, OSError) as exc:
+            errors.append(f"{type(exc).__name__}: {exc}")
+            if os.path.exists(tmp):
+                os.remove(tmp)
+    warnings.warn(
+        "omldm_tpu: the native parser did not build (" + "; ".join(errors)
+        + "); falling back to the Python parser, the fused ingest routes "
+        "are unavailable",
+        RuntimeWarning,
+        stacklevel=2,
+    )
+    return None
+
+
 def _build() -> Optional[ctypes.CDLL]:
     global _build_failed
-    os.makedirs(_BUILD_DIR, exist_ok=True)
-    if not os.path.exists(_LIB_PATH) or (
-        os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC)
-    ):
-        base = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _LIB_PATH, _SRC]
-        # -march=native squeezes a few percent out of the SWAR paths; the
-        # plain build is the fallback for toolchains/CPUs that reject it
-        ok = False
-        for cmd in (base[:1] + ["-march=native"] + base[1:], base):
-            try:
-                subprocess.run(
-                    cmd, check=True, capture_output=True, text=True, timeout=120
-                )
-                ok = True
-                break
-            except (subprocess.SubprocessError, FileNotFoundError, OSError):
-                continue
-        if not ok:
-            _build_failed = True
-            return None
-    lib = ctypes.CDLL(_LIB_PATH)
+    path = _compile()
+    if path is None:
+        _build_failed = True
+        return None
+    lib = ctypes.CDLL(path)
     base_argtypes = [
         ctypes.c_void_p,
         ctypes.c_long,

@@ -25,11 +25,15 @@ LANE = 128
 
 
 def _pa_kernel(x_ref, y_ref, m_ref, w0_ref, w_out_ref, loss_ref, *, variant: str, C: float):
+    # every ref is 2-D ([1, D] weights, [1, LANE] loss): when the cohort
+    # engine vmaps this call, each operand becomes a (squeezed, full, full)
+    # block, which Mosaic accepts only if the last TWO dims are whole — a
+    # 1-D [D] ref would turn into a one-row block of a [C, D] array
     B = x_ref.shape[0]
 
     def body(i, carry):
         w, acc = carry
-        x = x_ref[i, :]
+        x = x_ref[pl.ds(i, 1), :]  # [1, D]
         ys = jnp.where(y_ref[i, 0] > 0.0, 1.0, -1.0)
         margin = jnp.sum(w * x)
         hinge = jnp.maximum(0.0, 1.0 - ys * margin)
@@ -43,10 +47,10 @@ def _pa_kernel(x_ref, y_ref, m_ref, w0_ref, w_out_ref, loss_ref, *, variant: str
         m = m_ref[i, 0]
         return w + (tau * ys * m) * x, acc + hinge * m
 
-    w, loss_sum = jax.lax.fori_loop(0, B, body, (w0_ref[:], jnp.float32(0.0)))
-    w_out_ref[:] = w
+    w, loss_sum = jax.lax.fori_loop(0, B, body, (w0_ref[...], jnp.float32(0.0)))
+    w_out_ref[...] = w
     # TPU VMEM stores must be vector-shaped: broadcast the scalar loss sum
-    loss_ref[:] = jnp.full((LANE,), loss_sum, jnp.float32)
+    loss_ref[...] = jnp.full((1, LANE), loss_sum, jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("variant", "C", "interpret"))
@@ -66,11 +70,11 @@ def pa_scan_update(w, x, y, mask, variant: str = "PA-I", C: float = 0.01,
     new_w, loss_vec = pl.pallas_call(
         functools.partial(_pa_kernel, variant=variant, C=float(C)),
         out_shape=(
-            jax.ShapeDtypeStruct((D + pad,), jnp.float32),
-            jax.ShapeDtypeStruct((LANE,), jnp.float32),
+            jax.ShapeDtypeStruct((1, D + pad), jnp.float32),
+            jax.ShapeDtypeStruct((1, LANE), jnp.float32),
         ),
         interpret=interpret,
     )(x.astype(jnp.float32), y2.astype(jnp.float32), m2.astype(jnp.float32),
-      w.astype(jnp.float32))
+      w.astype(jnp.float32).reshape(1, D + pad))
     total = jnp.maximum(jnp.sum(mask), 1.0)
-    return new_w[:D], loss_vec[0] / total
+    return new_w[0, :D], loss_vec[0, 0] / total

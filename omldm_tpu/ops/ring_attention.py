@@ -19,8 +19,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from omldm_tpu.utils.jaxcompat import axis_size, shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -39,7 +37,7 @@ def ring_attention(
     shard i owns absolute positions [i*Lc, (i+1)*Lc). Must run inside
     ``shard_map`` with the sequence dim sharded over ``axis_name``."""
     b, lc, h, dh = q.shape
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     idx = jax.lax.axis_index(axis_name)
     q32 = q.astype(jnp.float32)
     q_pos = idx * lc + jnp.arange(lc)  # absolute query positions [Lc]
@@ -92,7 +90,7 @@ def ring_attention_sharded(
     """Whole-array convenience wrapper: shards the sequence dim of
     [B, L, H, Dh] inputs over ``axis_name`` of ``mesh`` and runs the ring."""
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ring_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -20,8 +20,6 @@ from __future__ import annotations
 import functools
 
 import jax
-
-from omldm_tpu.utils.jaxcompat import axis_size, shard_map
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -37,7 +35,7 @@ def ulysses_attention(
 ) -> jnp.ndarray:
     """Per-shard Ulysses attention. q,k,v: the LOCAL chunk [B, Lc, H, Dh];
     returns the local chunk of the attention output [B, Lc, H, Dh]."""
-    n = axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return attention(q, k, v, causal=causal)
     h = q.shape[2]
@@ -73,7 +71,7 @@ def ulysses_attention_sharded(
     """Whole-array convenience wrapper (testing): shards the sequence dim of
     [B, L, H, Dh] inputs over ``axis_name`` and runs Ulysses."""
     spec = P(None, axis_name, None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(ulysses_attention, axis_name=axis_name, causal=causal),
         mesh=mesh,
         in_specs=(spec, spec, spec),

@@ -34,32 +34,6 @@ from jax.experimental import mesh_utils
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def _enable_cpu_collectives(enable: bool = True) -> None:
-    """Multi-process groups on the CPU backend need an explicit
-    cross-process collectives implementation (gloo) on jax releases that
-    ship it opt-in — without it every collective fails with
-    "Multiprocess computations aren't implemented on the CPU backend".
-    Gloo needs the jax.distributed client, so it must be switched back OFF
-    (``enable=False``) when no process group forms — a single-process run
-    with the knob stuck on cannot even initialize the CPU backend. No-op
-    on TPU/GPU and on releases without the knob."""
-    import os
-
-    platforms = (
-        getattr(jax.config, "jax_platforms", None)
-        or os.environ.get("JAX_PLATFORMS")
-        or ""
-    )
-    if "cpu" not in platforms.split(","):
-        return
-    try:
-        jax.config.update(
-            "jax_cpu_collectives_implementation", "gloo" if enable else "none"
-        )
-    except Exception:
-        pass  # newer jax: gloo is the built-in default, knob removed
-
-
 def initialize_multihost(
     coordinator_address: Optional[str] = None,
     num_processes: Optional[int] = None,
@@ -84,7 +58,6 @@ def initialize_multihost(
     if coordinator_address is not None or num_processes is not None:
         from omldm_tpu.utils.backoff import with_backoff
 
-        _enable_cpu_collectives(enable=(num_processes or 1) > 1)
         kwargs = {}
         if connect_timeout_s is not None:
             # the overall deadline bounds the whole join; each ATTEMPT gets
@@ -109,14 +82,14 @@ def initialize_multihost(
         )
         return jax.process_index(), jax.process_count()
     try:
-        _enable_cpu_collectives()
         jax.distributed.initialize()  # cluster auto-detection
-    except Exception:
-        # no cluster found, or the backend was already initialized (e.g. a
-        # single-host run that did jax work first): report what exists —
-        # and withdraw the gloo request, which cannot work without the
-        # process-group client
-        _enable_cpu_collectives(enable=False)
+    except (ValueError, RuntimeError, OSError):
+        # no cluster found (ValueError); the backend was already
+        # initialized, e.g. a single-host run that did jax work first
+        # (RuntimeError); or a TPU host whose metadata server cannot be
+        # reached, so the Cloud TPU detection's request fails (OSError,
+        # seen on a sealed one-host v5e machine): report what exists
+        pass
     return jax.process_index(), jax.process_count()
 
 

@@ -24,8 +24,6 @@ from __future__ import annotations
 from typing import Any, Dict, Optional
 
 import jax
-
-from omldm_tpu.utils.jaxcompat import axis_size, grad_sync, shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -38,9 +36,6 @@ from omldm_tpu.models.transformer import (
 )
 from omldm_tpu.parallel.optim import adam_opt_specs, adam_update, init_adam_state
 from omldm_tpu.ops.attention import attention
-
-
-from omldm_tpu.utils.jaxcompat import pvary as _pvary
 
 
 def make_pp_mesh(dp: int = 1, pp: int = 1, devices=None) -> Mesh:
@@ -100,7 +95,7 @@ def pp_lm_loss(
     """Global-mean LM loss of the pipelined forward. Runs INSIDE shard_map
     over a ("dp", "pp") mesh."""
     params = cast_params(params, cfg.dtype)
-    n = axis_size(pp_axis)
+    n = jax.lax.axis_size(pp_axis)
     i = jax.lax.axis_index(pp_axis)
     m = tokens.shape[0]
     lc = tokens.shape[2]
@@ -114,9 +109,10 @@ def pp_lm_loss(
     # the nll accumulators are scalars: carrying logits for all microbatches
     # would checkpoint an [M, B, L, vocab] buffer per tick — at real vocab
     # sizes that dominates HBM and defeats the pipelining.
-    state0 = _pvary(jnp.zeros(emb.shape[1:], emb.dtype), (dp_axis, pp_axis))
-    num0 = _pvary(jnp.float32(0.0), (dp_axis, pp_axis))
-    den0 = _pvary(jnp.float32(0.0), (dp_axis, pp_axis))
+    state0, num0, den0 = jax.lax.pcast(
+        (jnp.zeros(emb.shape[1:], emb.dtype), jnp.float32(0.0), jnp.float32(0.0)),
+        (dp_axis, pp_axis), to="varying",
+    )
 
     def tick(carry, t):
         state, num, den = carry
@@ -214,14 +210,11 @@ class PPTrainer:
             loss, grads = jax.value_and_grad(
                 lambda p: pp_lm_loss(cfg, p, tokens, targets, mask)
             )(params)
-            # pre-vma jax: manual psum of replicated leaves' gradients
-            # (no-op where the vma transpose inserts them; jaxcompat)
-            grads = grad_sync(grads, pspecs, ("dp", "pp"))
             new_params, new_opt = adam_update(params, grads, opt, lr, b1, b2, eps)
             return new_params, new_opt, loss
 
         self._step = jax.jit(
-            shard_map(
+            jax.shard_map(
                 step_impl,
                 mesh=self.mesh,
                 in_specs=(pspecs, ospecs, data_spec, data_spec, data_spec),

@@ -26,8 +26,6 @@ from __future__ import annotations
 from typing import Any, Dict, Optional, Tuple
 
 import jax
-
-from omldm_tpu.utils.jaxcompat import grad_sync, shard_map
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -142,10 +140,8 @@ class SeqTrainer:
 
         # check_vma=True (default): shard_map tracks which mesh axes every
         # intermediate varies over, so jax.grad's transpose inserts the
-        # gradient psums for replicated parameter leaves automatically; on
-        # pre-vma releases (check_rep=False fallback) _step_impl adds the
-        # equivalent psums by hand via jaxcompat.grad_sync.
-        step = shard_map(
+        # gradient psums for replicated parameter leaves automatically.
+        step = jax.shard_map(
             self._step_impl,
             mesh=self.mesh,
             in_specs=(pspecs, ospecs, data_spec, label_spec, data_spec),
@@ -167,10 +163,6 @@ class SeqTrainer:
 
     def _step_impl(self, params, opt, tokens, targets, mask):
         loss, grads = jax.value_and_grad(self._loss)(params, tokens, targets, mask)
-        # pre-vma jax (check_rep=False fallback): the transpose does NOT
-        # psum replicated leaves' gradients — sync them manually (no-op on
-        # releases with automatic vma psums; see jaxcompat.grad_sync)
-        grads = grad_sync(grads, self._pspecs, ("dp", "sp", "tp"))
         new_params, new_opt = adam_update(
             params, grads, opt, self.lr, self.b1, self.b2, self.eps
         )
@@ -224,7 +216,7 @@ class SeqTrainer:
                 return params, opt, losses
 
             self._step_many = jax.jit(
-                shard_map(
+                jax.shard_map(
                     many_impl,
                     mesh=self.mesh,
                     in_specs=(

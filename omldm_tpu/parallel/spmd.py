@@ -51,8 +51,6 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
-
-from omldm_tpu.utils.jaxcompat import shard_map
 import jax.flatten_util
 import jax.numpy as jnp
 import numpy as np
@@ -66,8 +64,6 @@ from omldm_tpu.parallel.mesh import make_mesh
 from omldm_tpu.runtime.codec import comm_codec_name
 from omldm_tpu.utils import batch_valid_counts
 
-
-from omldm_tpu.utils.jaxcompat import pvary as _pvary
 
 SPMD_PROTOCOLS = (
     "Synchronous",
@@ -215,7 +211,7 @@ class SPMDTrainer:
         self._step = _program(
             ("step",) + self.program_key,
             lambda: jax.jit(
-                shard_map(
+                jax.shard_map(
                     step_impl,
                     mesh=self.mesh,
                     in_specs=(
@@ -314,7 +310,7 @@ class SPMDTrainer:
         my = jax.lax.dynamic_slice(flat, (i * self.shard_size,), (self.shard_size,))
         avg = jax.lax.pmean(my, "dp")
         full = jax.lax.all_gather(avg, "hub", tiled=True)
-        return _pvary(full, "dp")
+        return jax.lax.pcast(full, "dp", to="varying")
 
     def _build_step(self):
         learner = self.learner
@@ -337,16 +333,17 @@ class SPMDTrainer:
             # or ([1,B,K] idx, [1,B,K] val) padded-COO. Inputs may arrive
             # in a narrow feed dtype (float16 staging halves host->device
             # bytes); compute is always f32.
+            f32 = jnp.float32
             if sparse:
                 idx, val = x
                 x = (
-                    _pvary(idx[0], "hub"),
-                    _pvary(val[0].astype(jnp.float32), "hub"),
+                    jax.lax.pcast(idx[0], "hub", to="varying"),
+                    jax.lax.pcast(val[0].astype(f32), "hub", to="varying"),
                 )
             else:
-                x = _pvary(x[0].astype(jnp.float32), "hub")
-            y = _pvary(y[0].astype(jnp.float32), "hub")
-            mask = _pvary(mask[0].astype(jnp.float32), "hub")
+                x = jax.lax.pcast(x[0].astype(f32), "hub", to="varying")
+            y = jax.lax.pcast(y[0].astype(f32), "hub", to="varying")
+            mask = jax.lax.pcast(mask[0].astype(f32), "hub", to="varying")
             params = jax.tree_util.tree_map(_sq, state["params"])
             prep_states = [jax.tree_util.tree_map(_sq, s) for s in state["preps"]]
             est = _sq(state["est"])
@@ -598,7 +595,7 @@ class SPMDTrainer:
             self._step_many = _program(
                 ("step_many",) + self.program_key,
                 lambda: jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         many_impl,
                         mesh=self.mesh,
                         in_specs=(
@@ -642,7 +639,7 @@ class SPMDTrainer:
             self._step_many_dense = _program(
                 ("step_many_dense",) + self.program_key,
                 lambda: jax.jit(
-                    shard_map(
+                    jax.shard_map(
                         many_dense_impl,
                         mesh=self.mesh,
                         in_specs=(self._state_specs, batch_spec, batch_spec),

@@ -48,8 +48,7 @@ exact pre-cohort code path), ``"auto"`` (cohorts form once
 **Device sharding** (``JobConfig.cohort_shards``): the tenant axis is
 embarrassingly parallel, so with S > 1 shards the cohort lays its leading
 pipeline axis across the first S local devices as a ``"tenants"`` mesh axis
-(``shard_map`` through the ``utils.jaxcompat`` shim — the same portability
-layer the SPMD engine rides) and every gang program — fit, shared-input
+(``jax.shard_map``) and every gang program — fit, shared-input
 fit, gang predict (forecast serving flushes), flat params, and the guard's
 fused health vector — runs as ONE sharded launch with the per-shard member
 iteration unchanged (``lax.map``/``vmap`` over the shard's local block).
@@ -83,7 +82,6 @@ from omldm_tpu.pipelines.pipeline import (
     _build_impls,
     _param_health,
 )
-from omldm_tpu.utils.jaxcompat import shard_map as _shard_map
 
 # staged batches per member before a launch is forced: bounds the gang input
 # tensor [capacity, T, B, D] when a pipeline has no sync point for a while
@@ -233,19 +231,19 @@ def _build_gang_programs(
         # it in place would nest shard_maps.
         P = jax.sharding.PartitionSpec
         sh, rep = P("tenants"), P()
-        sharded_fit = _shard_map(
+        sharded_fit = jax.shard_map(
             gang_fit, mesh=mesh, in_specs=(sh, sh, sh, sh), out_specs=sh,
             check_vma=False,
         )
-        sharded_shared = _shard_map(
+        sharded_shared = jax.shard_map(
             gang_fit_shared, mesh=mesh, in_specs=(sh, sh, rep, rep, rep),
             out_specs=sh, check_vma=False,
         )
-        sharded_predict = _shard_map(
+        sharded_predict = jax.shard_map(
             gang_predict, mesh=mesh, in_specs=(sh, sh), out_specs=sh,
             check_vma=False,
         )
-        sharded_flat = _shard_map(
+        sharded_flat = jax.shard_map(
             gang_flat, mesh=mesh, in_specs=sh, out_specs=sh,
             check_vma=False,
         )

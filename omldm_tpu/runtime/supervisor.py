@@ -1182,11 +1182,45 @@ class DistributedJobSupervisor:
                 shutil.rmtree(self.run_dir, ignore_errors=True)
 
 
+def refuse_shared_tpu_host(max_workers: int) -> None:
+    """One process for each host's chips. Every worker this launcher
+    starts runs on THIS host, and a jax process on a TPU host claims all
+    of the host's chips: the second worker dies on libtpu's lock file
+    while the first waits out a two-minute topology exchange for it, on
+    every attempt of the restart policy (seen with ``--supervise
+    --processes 2`` on a v5e host). So refuse up front. Workers sent to
+    another platform (``JAX_PLATFORMS`` without ``tpu``) share nothing and
+    are let through."""
+    if max_workers <= 1:
+        return
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return
+    # the PCI scan jax's own platform choice uses; it touches no backend.
+    # It counts the HOST's chips, not those this process may use (4 on a
+    # machine that exposes 1), so the count stays out of the message.
+    from jax._src import hardware_utils
+
+    chips, _ = hardware_utils.num_available_tpu_chips_and_device_id()
+    if chips == 0:
+        return
+    raise SystemExit(
+        f"--supervise would start up to {max_workers} worker processes on "
+        "this host, which has TPU chips: each worker claims every chip it "
+        "can see and a chip belongs to one process. On one host run one "
+        "process over all chips (--processes 1, or python -m omldm_tpu "
+        "--parallelism <local chip count> ...); several processes are for "
+        "one process per host (--coordinator), or for CPU workers "
+        "(JAX_PLATFORMS=cpu)."
+    )
+
+
 def supervise_from_flags(flags: Dict[str, str]) -> int:
     """CLI adapter: ``--supervise`` turns the launcher process into the
-    fleet supervisor (it never imports jax or touches the fabric). All
-    non-supervisor flags pass through to every worker. Returns the exit
-    code for the CLI; exhausted restarts exit with the last worker's code."""
+    fleet supervisor (it never initializes a jax backend or touches the
+    fabric). All non-supervisor flags pass through to every worker.
+    Returns the exit code for the CLI; exhausted restarts exit with the
+    last worker's code."""
     nproc = int(flags.get("processes", "1"))
     worker_args: List[str] = []
     for key, value in flags.items():
@@ -1244,6 +1278,9 @@ def supervise_from_flags(flags: Dict[str, str]) -> int:
             probe_window_s=float(flags.get("probeWindowMs", "10000"))
             / 1000.0,
         )
+    refuse_shared_tpu_host(
+        max(nproc, autoscale.max_processes if autoscale is not None else 1)
+    )
     sup = DistributedJobSupervisor(
         worker_args,
         nproc,

@@ -2,6 +2,7 @@
 (the Job.main analogue, reference Job.scala:110-171)."""
 
 import json
+import os
 
 import numpy as np
 import pytest
@@ -159,32 +160,50 @@ class TestFileReplayJob:
 
 
 class TestCompileCache:
-    def test_compile_cache_flag_configures_jax(self, tmp_path, monkeypatch):
-        """--compileCache <dir> turns on the persistent XLA compilation
-        cache; 'off' leaves it untouched."""
+    """utils/compile_cache.enable_compile_cache: the one place the
+    persistent XLA compilation cache is configured."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        """Record jax.config.update calls instead of applying them (the
+        suite's own jax configuration stays untouched)."""
         import jax
 
-        from omldm_tpu.__main__ import _enable_compile_cache
+        seen = {}
+        monkeypatch.setattr(
+            jax.config, "update", lambda k, v: seen.__setitem__(k, v)
+        )
+        return seen
 
-        before_dir = jax.config.jax_compilation_cache_dir
-        before_min = jax.config.jax_persistent_cache_min_compile_time_secs
-        cache = tmp_path / "xla"
-        try:
-            _enable_compile_cache({"compileCache": str(cache),
-                                   "compileCacheMinSecs": "0.0"})
-            assert jax.config.jax_compilation_cache_dir == str(cache)
-            assert cache.is_dir()
-        finally:
-            jax.config.update("jax_compilation_cache_dir", before_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", before_min
-            )
+    def test_env_placed_cache_sets_no_directory(
+        self, tmp_path, monkeypatch, updates
+    ):
+        from omldm_tpu.utils import compile_cache as cc
 
-    def test_compile_cache_off(self, monkeypatch):
-        import jax
+        monkeypatch.setenv(cc.CACHE_DIR_ENV, str(tmp_path / "placed"))
+        assert cc.enable_compile_cache() == str(tmp_path / "placed")
+        assert updates == {}
 
-        from omldm_tpu.__main__ import _enable_compile_cache
+    def test_default_is_fixed_path_under_checkout(self, monkeypatch, updates):
+        import omldm_tpu
+        from omldm_tpu.utils import compile_cache as cc
 
-        before = jax.config.jax_compilation_cache_dir
-        _enable_compile_cache({"compileCache": "off"})
-        assert jax.config.jax_compilation_cache_dir == before
+        monkeypatch.delenv(cc.CACHE_DIR_ENV, raising=False)
+        checkout = os.path.dirname(os.path.dirname(omldm_tpu.__file__))
+        expected = os.path.join(checkout, ".jax_cache")
+        assert cc.enable_compile_cache() == expected
+        assert updates == {"jax_compilation_cache_dir": expected}
+        assert os.path.isdir(expected)
+
+    def test_off_disables_without_touching_directory(self, updates):
+        from omldm_tpu.utils import compile_cache as cc
+
+        assert cc.enable_compile_cache("off") is None
+        assert updates == {"jax_enable_compilation_cache": False}
+
+    def test_directory_form_is_rejected(self, tmp_path, updates):
+        from omldm_tpu.utils import compile_cache as cc
+
+        with pytest.raises(ValueError, match=cc.CACHE_DIR_ENV):
+            cc.enable_compile_cache(str(tmp_path))
+        assert updates == {}

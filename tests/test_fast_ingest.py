@@ -136,3 +136,31 @@ class TestHashDimsLayout:
         (px, _, _), = list(without.feed(line))
         np.testing.assert_allclose(bx, px)
         np.testing.assert_allclose(bx[0], [1.0, 2.0, 0.0, 0.0])
+
+
+class TestNativeBuildCache:
+    """The library's file name is a hash of the source bytes, the compile
+    flags and the host tag, so an object copied in with the tree (or left
+    from older source) is never loaded for this source."""
+
+    def test_name_follows_source_bytes_and_flags(self, tmp_path, monkeypatch):
+        from omldm_tpu.ops.native import loader
+
+        flags = loader._FLAG_SETS[0]
+        committed = loader._lib_path(flags)
+        assert loader._lib_path(flags) == committed  # a fixed name per triple
+        assert loader._lib_path(loader._FLAG_SETS[1]) != committed
+        edited = tmp_path / "fastparse.cpp"
+        with open(loader._SRC, "rb") as f:
+            edited.write_bytes(f.read() + b"\n// one more line\n")
+        monkeypatch.setattr(loader, "_SRC", str(edited))
+        assert loader._lib_path(flags) != committed
+
+    def test_failed_build_warns_and_falls_back(self, tmp_path, monkeypatch):
+        from omldm_tpu.ops.native import loader
+
+        # no compiler on PATH, and an empty build directory
+        monkeypatch.setenv("PATH", str(tmp_path))
+        monkeypatch.setattr(loader, "_BUILD_DIR", str(tmp_path / "_build"))
+        with pytest.warns(RuntimeWarning, match="native parser did not build"):
+            assert loader._compile() is None

@@ -328,26 +328,24 @@ def test_fuzzed_stream_quarantined_not_silently_dropped(seed):
     )
 
 
-def test_cli_backend_fallback(monkeypatch):
-    """--ensure-backend falls back to CPU when the accelerator cannot
-    initialize instead of crashing the job (__main__._ensure_backend)."""
+def test_cli_never_selects_a_platform(tmp_path, monkeypatch):
+    """main() runs on the platform jax was given (JAX_PLATFORMS, or the
+    accelerator it finds) and announces it; it never switches platforms in
+    code, so a run that lost its chip cannot quietly continue on the CPU."""
     import jax
 
-    from omldm_tpu.__main__ import _ensure_backend
+    from omldm_tpu.__main__ import main
 
-    calls = {"n": 0, "updates": []}
+    updates = []
+    real_update = jax.config.update
 
-    def fake_devices():
-        calls["n"] += 1
-        if calls["n"] == 1:
-            raise RuntimeError("tunnel down")
-        return ["cpu0"]
+    def recording_update(key, value):
+        updates.append(key)
+        return real_update(key, value)
 
-    monkeypatch.setattr(jax, "devices", fake_devices)
-    monkeypatch.setattr(
-        jax.config, "update",
-        lambda k, v: calls["updates"].append((k, v)),
-    )
-    _ensure_backend()
-    assert ("jax_platforms", "cpu") in calls["updates"]
-    assert calls["n"] == 2
+    monkeypatch.setattr(jax.config, "update", recording_update)
+    events = tmp_path / "events.jsonl"
+    events.write_text("")
+    assert main(["--events", str(events), "--performanceOut",
+                 str(tmp_path / "perf.jsonl")]) == 0
+    assert "jax_platforms" not in updates

@@ -23,24 +23,11 @@ def _batch(rng, b, l, vocab):
     )
 
 
-# pre-vma jax: manual grad_sync (jaxcompat) reorders the replicated
-# leaves' gradient reduction, which exceeds this test's 1e-4 equality
-# envelope on most mesh shapes; (1, 2, 8) stays live everywhere and pins
-# the fallback path in tier-1.
-_vma_exact = pytest.mark.skipif(
-    not __import__(
-        "omldm_tpu.utils.jaxcompat", fromlist=["auto_grad_sync"]
-    ).auto_grad_sync(),
-    reason="pre-vma jax: manual grad_sync reorder exceeds the 1e-4 "
-    "equality envelope (the (1,2,8) case still pins the fallback path)",
-)
-
-
 @pytest.mark.parametrize("dp,pp,n_micro", [
-    pytest.param(1, 4, 4, marks=_vma_exact),
-    pytest.param(2, 2, 2, marks=_vma_exact),
+    (1, 4, 4),
+    (2, 2, 2),
     (1, 2, 8),
-    pytest.param(2, 4, 2, marks=_vma_exact),
+    (2, 4, 2),
 ])
 def test_pp_matches_single_device(dp, pp, n_micro):
     rng = np.random.RandomState(0)

@@ -8,7 +8,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from omldm_tpu.ops.attention import mha_reference
 from omldm_tpu.ops.ring_attention import ring_attention, ring_attention_sharded
-from omldm_tpu.utils.jaxcompat import shard_map
 
 
 def _qkv(b=2, l=64, h=2, dh=8, seed=0):
@@ -56,7 +55,7 @@ def test_ring_inside_shard_map_2d_mesh():
     ref = mha_reference(q, k, v, causal=True)
 
     spec = P("dp", "sp", None, None)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda q, k, v: ring_attention(q, k, v, "sp", causal=True),
         mesh=mesh,
         in_specs=(spec, spec, spec),

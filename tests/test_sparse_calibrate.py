@@ -114,10 +114,9 @@ class TestCalibrate:
     def test_tpu_guess_retired(self, tmp_path, monkeypatch):
         """The round-5 ``D >= 2^16 -> mxu`` TPU guess is retired: an
         UNCALIBRATED backend (no table section) resolves to the plain
-        scatter at any D — the guessed crossover was never measured (the
-        committed table's "tpu_status" annotation records the unreachable
-        chip), and a number nobody measured must not steer the dispatch.
-        A real TPU table section, once calibrated, still wins."""
+        scatter at any D — the guessed crossover was never measured, and a
+        number nobody measured must not steer the dispatch. A real TPU
+        table section, once calibrated, still wins."""
         import jax
 
         from omldm_tpu.ops import sparse as sp
@@ -134,10 +133,8 @@ class TestCalibrate:
         })))
         monkeypatch.setenv(cal.ENV_TABLE, str(path))
         assert sp._resolve_impl(1 << 20, 1 << 10) == "mxu"
-        # the committed table records the honest no-chip annotation
+        # the committed table has no tpu section until a chip run writes one
         committed = cal.load_table(cal.DEFAULT_TABLE)
-        status = committed.get("tpu_status")
-        assert status and status["calibrated"] is False
         assert "tpu" not in committed["backends"]
 
 

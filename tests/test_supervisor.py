@@ -1,7 +1,7 @@
 """DistributedJobSupervisor mechanics, isolated from jax.
 
-The supervisor never imports jax (it only spawns/monitors worker
-processes), so its restart policy, health channels, and flag plumbing are
+The supervisor never initializes a jax backend (it only spawns/monitors
+worker processes), so its restart policy, health channels, and flag plumbing are
 testable with trivial stand-in workers — each a tiny ``python -c`` script
 injected via ``worker_cmd``. The full-stack recovery paths (real jax
 workers, checkpoints, source replay) live in test_supervised_recovery.py.
@@ -152,3 +152,26 @@ def test_supervise_from_flags_passthrough_and_exit_code(tmp_path):
         ),
     })
     assert rc == 5
+
+
+def test_launcher_refuses_workers_sharing_a_tpu_host(monkeypatch):
+    # one process per host's chips: N local workers on a TPU host would
+    # each claim every chip, so the launcher refuses instead of hanging
+    from jax._src import hardware_utils
+
+    from omldm_tpu.runtime.supervisor import refuse_shared_tpu_host
+
+    monkeypatch.setattr(
+        hardware_utils, "num_available_tpu_chips_and_device_id",
+        lambda: (4, hardware_utils.TpuVersion.v5e),
+    )
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(SystemExit, match="has TPU chips"):
+        refuse_shared_tpu_host(2)
+    refuse_shared_tpu_host(1)  # one process over all chips is the shape
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    refuse_shared_tpu_host(2)  # CPU workers share nothing
+    # through the CLI adapter, before any worker is spawned
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit, match="one process over all chips"):
+        supervise_from_flags({"supervise": "true", "processes": "2"})

@@ -78,27 +78,11 @@ def test_single_device_training_learns_copy_task():
     assert trainer.fitted == 61 * 8 * 16
 
 
-# pre-vma jax falls back to check_rep=False shard_map with MANUAL gradient
-# psums (jaxcompat.grad_sync): the sharded step then matches the single
-# device only to ~1e-3 (reduction reorder amplified by Adam), not this
-# test's 1e-4 envelope. The classify/ulysses/remat/checkpoint sharded
-# tests pass the tight envelope on the fallback too and stay live, so the
-# compat path's correctness remains pinned in tier-1.
-_vma_exact = pytest.mark.skipif(
-    not __import__(
-        "omldm_tpu.utils.jaxcompat", fromlist=["auto_grad_sync"]
-    ).auto_grad_sync(),
-    reason="pre-vma jax: manual grad_sync reorder exceeds the 1e-4 "
-    "equality envelope (classify/ulysses/remat/ckpt cases still pin "
-    "the fallback path)",
-)
-
-
 @pytest.mark.parametrize("dp,sp,tp", [
-    pytest.param(2, 2, 2, marks=_vma_exact),
-    pytest.param(1, 4, 2, marks=_vma_exact),
-    pytest.param(4, 1, 2, marks=_vma_exact),
-    pytest.param(2, 4, 1, marks=_vma_exact),
+    (2, 2, 2),
+    (1, 4, 2),
+    (4, 1, 2),
+    (2, 4, 1),
 ])
 def test_sharded_step_matches_single_device(dp, sp, tp):
     rng = np.random.RandomState(1)
@@ -321,10 +305,8 @@ def test_moe_dense_applies_capacity_like_ep():
     assert nonzero == 4, f"expected cap=4 kept tokens, got {nonzero}"
 
     # ep=1 EP path == dense path exactly, including the dropped tokens
-    from omldm_tpu.utils.jaxcompat import shard_map as _compat_shard_map
-
     mesh = Mesh(np.array(jax.devices()[:1]), ("ep",))
-    ep_fn = _compat_shard_map(
+    ep_fn = jax.shard_map(
         lambda xx: _moe_block_ep(layer, xx, "ep", cf),
         mesh=mesh,
         in_specs=P(),
