@@ -62,7 +62,7 @@ from omldm_tpu.ops.codec import BYTES_PER_ELEMENT, LEAF_META_BYTES, make_qdq
 from omldm_tpu.preprocessors.registry import make_preprocessor
 from omldm_tpu.parallel.mesh import make_mesh
 from omldm_tpu.runtime.codec import comm_codec_name
-from omldm_tpu.utils import batch_valid_counts
+from omldm_tpu.utils import batch_valid_counts, tracing
 
 
 SPMD_PROTOCOLS = (
@@ -174,22 +174,27 @@ class SPMDTrainer:
             prep_dims.append(d)
         self.learner_dim = d
 
-        # template params -> flat layout shared by every replica
-        template = self.learner.init(d, jax.random.PRNGKey(seed))
-        flat0, self._unravel = jax.flatten_util.ravel_pytree(template)
-        self.n_params = int(flat0.size)
-        self.pad = (-self.n_params) % self.hub
-        self.flat_size = self.n_params + self.pad
-        self.shard_size = self.flat_size // self.hub
+        with tracing.span("build_state"):
+            # template params -> flat layout shared by every replica
+            template = self.learner.init(d, jax.random.PRNGKey(seed))
+            flat0, self._unravel = jax.flatten_util.ravel_pytree(template)
+            self.n_params = int(flat0.size)
+            self.pad = (-self.n_params) % self.hub
+            self.flat_size = self.n_params + self.pad
+            self.shard_size = self.flat_size // self.hub
 
-        state_host = self._init_state(seed, prep_dims, template)
-        spec = NamedSharding(self.mesh, P("dp", "hub"))
-        self.state = jax.tree_util.tree_map(
-            lambda leaf: jax.device_put(jnp.asarray(leaf), spec), state_host
-        )
-        self._state_specs = jax.tree_util.tree_map(
-            lambda _: P("dp", "hub"), state_host
-        )
+            with tracing.span("init_state_host"):
+                state_host = self._init_state(seed, prep_dims, template)
+            spec = NamedSharding(self.mesh, P("dp", "hub"))
+            # ends when device_put returns: the copies may still be in flight
+            with tracing.span("place_state"):
+                self.state = jax.tree_util.tree_map(
+                    lambda leaf: jax.device_put(jnp.asarray(leaf), spec),
+                    state_host,
+                )
+            self._state_specs = jax.tree_util.tree_map(
+                lambda _: P("dp", "hub"), state_host
+            )
 
         # the static signature every compiled program of this trainer is
         # a pure function of: trainers agreeing on it share executables

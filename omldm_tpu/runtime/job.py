@@ -33,6 +33,7 @@ from omldm_tpu.runtime.responses import ResponseMerger
 from omldm_tpu.runtime.spoke import Spoke, _PauseBuffer
 from omldm_tpu.runtime.stats import StatisticsCollector
 from omldm_tpu.runtime.vectorizer import Vectorizer
+from omldm_tpu.utils import tracing
 
 # event stream names (the reference's Kafka topics, README.md:21-26)
 TRAINING_STREAM = "trainingData"
@@ -192,6 +193,8 @@ class StreamJob:
         # pipelines deployed on the SPMD collective engine instead of the
         # host plane (trainingConfiguration {"engine": "spmd"})
         self.spmd_bridges: Dict[int, Any] = {}
+        # phase_table() reports their fused route's spans from here on
+        self._spans_since = tracing.RECORDER.mark()
         # opt-in periodic checkpointing (Job.scala:120, Checkpointing.scala)
         self.checkpoint_manager = None
         if self.config.checkpointing:
@@ -522,13 +525,20 @@ class StreamJob:
         clocked elsewhere — fit (spoke flush StepTimers), serve (serving
         StepTimers) and ship (transport-codec seconds). With ``e2e_s``,
         each row carries its share of the measured end-to-end wall and
-        ``_coverage`` is the attributed fraction."""
+        ``_coverage`` is the attributed fraction. A job with ``engine:
+        spmd`` pipelines also reports the spans their fused route has noted
+        since the job was built (``utils.tracing.RECORDER`` is always on,
+        so that part needs no armed telemetry plane; it is process-wide,
+        so jobs running side by side in one process see each other's)."""
         from omldm_tpu.runtime.telemetry import PhaseProfile
 
         tel = self.telemetry
-        profile = (
-            tel.phases if tel is not None and tel.phases is not None
-            else PhaseProfile()
+        profile = tel.phases if tel is not None else None
+        if profile is None:
+            profile = PhaseProfile()
+        fused_route = (
+            PhaseProfile(tracing.RECORDER, since=self._spans_since)
+            if self.spmd_bridges else None
         )
         enc, dec = self.codec_seconds()
         extra = {
@@ -537,7 +547,8 @@ class StreamJob:
             "ship": enc + dec,
         }
         return profile.table(
-            e2e_s, extra={k: v for k, v in extra.items() if v > 0.0}
+            e2e_s, extra={k: v for k, v in extra.items() if v > 0.0},
+            also=fused_route,
         )
 
     def heartbeat_statistics(self) -> list:

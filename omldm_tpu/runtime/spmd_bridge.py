@@ -16,6 +16,7 @@ bytesShipped/modelsShipped accounting from the collective call sites.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, List, Optional, Tuple
 
 import jax
@@ -32,6 +33,7 @@ from omldm_tpu.parallel.spmd import SPMD_PROTOCOLS, SPMDTrainer
 from omldm_tpu.runtime.databuffers import ArrayHoldout
 from omldm_tpu.runtime.spoke import PREDICT_BATCH
 from omldm_tpu.runtime.vectorizer import F32_MAX, Vectorizer
+from omldm_tpu.utils import tracing
 
 
 # flush remainders pad to this sub-batch instead of a full dp*B group
@@ -254,19 +256,23 @@ def _line_aligned_chunks(path: str, chunk_bytes: int, start_offset: int = 0):
     ingest routes so the subtle carry logic exists once. ``start_offset``
     resumes mid-file at a known line-aligned byte position (checkpoint
     cursors record one)."""
-    buf = bytearray(chunk_bytes)
+    with tracing.span("read"):
+        # zero-filled: the first touch of every page of the buffer
+        buf = bytearray(chunk_bytes)
+        f = open(path, "rb")
     carry = 0
-    with open(path, "rb") as f:
+    with f:
         if start_offset:
             f.seek(start_offset)
         while True:
-            if carry >= len(buf):  # one line longer than the buffer
-                buf.extend(bytes(len(buf)))
-            n = f.readinto(memoryview(buf)[carry:])
+            with tracing.span("read"):
+                if carry >= len(buf):  # one line longer than the buffer
+                    buf.extend(bytes(len(buf)))
+                n = f.readinto(memoryview(buf)[carry:])
+                end = carry + n
+                cut = buf.rfind(b"\n", 0, end) if n else -1
             if not n:
                 break
-            end = carry + n
-            cut = buf.rfind(b"\n", 0, end)
             if cut < 0:
                 carry = end
                 continue
@@ -277,6 +283,23 @@ def _line_aligned_chunks(path: str, chunk_bytes: int, start_offset: int = 0):
         if carry:
             buf[carry : carry + 1] = b"\n"
             yield buf, carry + 1
+
+
+def _in_ingest_file_span(ingest):
+    """Run a bridge's overlapped file route inside the ``ingest_file`` span
+    of its file, counting the training rows (fitted or held out) the file
+    brought."""
+
+    @functools.wraps(ingest)
+    def traced(self, *args, **kwargs):
+        rows = self.holdout_count
+        with tracing.span("ingest_file") as span:
+            try:
+                return ingest(self, *args, **kwargs)
+            finally:
+                span.add(rows=self.holdout_count - rows)
+
+    return traced
 
 
 class _OverlapDispatcher:
@@ -293,31 +316,38 @@ class _OverlapDispatcher:
         import queue
         import threading
 
-        self.pool: "queue.Queue" = queue.Queue()
-        for _ in range(max(depth, 1)):
-            self.pool.put(make_set())
-        self.work: "queue.Queue" = queue.Queue()
-        self.errors: List[BaseException] = []
-        self._train = train
+        # what this dispatcher works for (a file's ``ingest_file`` span):
+        # the dispatch thread's spans name it as their parent
+        cause = tracing.current()
+        with tracing.span("dispatcher_open"):
+            self.pool: "queue.Queue" = queue.Queue()
+            for _ in range(max(depth, 1)):
+                self.pool.put(make_set())
+            self.work: "queue.Queue" = queue.Queue()
+            self.errors: List[BaseException] = []
+            self._train = train
 
-        def worker():
-            while True:
-                item = self.work.get()
-                try:
-                    if item is None:
-                        return
-                    stage_set, n = item
-                    if not self.errors:
-                        self._train(stage_set, n)
-                except BaseException as exc:  # surfaced to the producer
-                    self.errors.append(exc)
-                finally:
-                    if item is not None:
-                        self.pool.put(item[0])
-                    self.work.task_done()
+            def worker():
+                tracing.adopt(cause)
+                while True:
+                    item = self.work.get()
+                    try:
+                        if item is None:
+                            return
+                        stage_set, n = item
+                        if not self.errors:
+                            self._train(stage_set, n)
+                    except BaseException as exc:  # surfaced to the producer
+                        self.errors.append(exc)
+                    finally:
+                        if item is not None:
+                            self.pool.put(item[0])
+                        self.work.task_done()
 
-        self._thread = threading.Thread(target=worker, daemon=True)
-        self._thread.start()
+            self._thread = threading.Thread(
+                target=worker, daemon=True, name="omldm-dispatch"
+            )
+            self._thread.start()
 
     def submit(self, stage_set, n: int):
         """Queue a filled set, return a fresh one from the pool. Raises
@@ -326,18 +356,21 @@ class _OverlapDispatcher:
         if self.errors:
             raise self.errors[0]
         self.work.put((stage_set, n))
-        return self.pool.get()
+        with tracing.span("pool_wait"):
+            return self.pool.get()
 
     def quiesce(self) -> None:
         """Drain the queue (producer-side trainer access needs the worker
         idle); re-raise any worker error."""
-        self.work.join()
+        with tracing.span("quiesce"):
+            self.work.join()
         if self.errors:
             raise self.errors[0]
 
     def close(self) -> None:
-        self.work.put(None)
-        self._thread.join()
+        with tracing.span("dispatcher_close"):
+            self.work.put(None)
+            self._thread.join()
 
     def raise_pending(self) -> None:
         if self.errors:
@@ -749,6 +782,7 @@ class SPMDBridge:
             and flag != "false"
         )
 
+    @_in_ingest_file_span
     def ingest_file_overlapped(
         self, path: str, chunk_bytes: int = 1 << 22, on_chunk=None,
         depth: int = 2, train_fn=None,
@@ -1128,6 +1162,7 @@ class SparseSPMDBridge(SPMDBridge):
             reuse_buffers=True,
         )
 
+    @_in_ingest_file_span
     def ingest_file_overlapped(
         self, path: str, chunk_bytes: int = SPARSE_CHUNK_BYTES, on_chunk=None,
         depth: int = 2, train_fn=None,
@@ -1265,10 +1300,12 @@ class SparseSPMDBridge(SPMDBridge):
     # --- data path ---
 
     def handle_data(self, inst: DataInstance) -> None:
-        idx, val = self.vectorizer.vectorize(inst)
         if inst.operation == FORECASTING:
+            with tracing.span("decode"):
+                idx, val = self.vectorizer.vectorize(inst)
             self._emit_forecast(idx, val, inst)
             return
+        idx, val = self.vectorizer.vectorize(inst)
         y = (
             0.0 if inst.target is None
             else min(max(float(inst.target), -F32_MAX), F32_MAX)
@@ -1282,10 +1319,14 @@ class SparseSPMDBridge(SPMDBridge):
         bv = np.zeros((PREDICT_BATCH, self.max_nnz), np.float32)
         bi[0] = idx
         bv[0] = val
-        preds = self.trainer.predict((bi, bv))
-        self._emit_prediction(
-            Prediction(self.request.id, inst, float(preds[0]))
-        )
+        # the padded batch up, the wait behind every step still queued on
+        # the device, the predict program, the answer down
+        with tracing.span("serve"):
+            preds = self.trainer.predict((bi, bv))
+        with tracing.span("emit"):
+            self._emit_prediction(
+                Prediction(self.request.id, inst, float(preds[0]))
+            )
 
     def handle_batch(self, x, y, op) -> None:
         """Dense packed rows (the C ingest path) re-enter as COO — rare for
@@ -1395,50 +1436,66 @@ class SparseSPMDBridge(SPMDBridge):
         may alias numpy argument buffers zero-copy (observed on CPU),
         while both the serial stage and the pooled sets are reused as
         soon as this returns; SSP requeue also re-enters these buffers."""
-        si = si[:n].copy()
-        sv = sv[:n].copy()
-        sy = sy[:n].copy()
-        b = self.config.batch_size
-        group = self.dp * b
-        done = 0
-        while n - done >= group:
-            ig = si[done : done + group].reshape(self.dp, b, self.max_nnz)
-            vg = sv[done : done + group].reshape(self.dp, b, self.max_nnz)
-            yg = sy[done : done + group].reshape(self.dp, b)
-            mg = np.ones((self.dp, b), np.float32)
-            self.trainer.step((ig, vg), yg, mg, valid_count=group)
-            self._requeue_refused_sparse(ig, vg, yg, mg)
-            done += group
-        tail_b = min(b, TAIL_BATCH)
-        tail_group = self.dp * tail_b
-        while n - done > 0:
-            rem = min(n - done, tail_group)
-            ti = np.zeros((tail_group, self.max_nnz), np.int32)
-            tv = np.zeros((tail_group, self.max_nnz), np.float32)
-            ty = np.zeros((tail_group,), np.float32)
-            tm = np.zeros((tail_group,), np.float32)
-            ti[:rem] = si[done : done + rem]
-            tv[:rem] = sv[done : done + rem]
-            ty[:rem] = sy[done : done + rem]
-            tm[:rem] = 1.0
-            # stripe rows across workers; SSP maps slots slowest-first so
-            # every tail pass is guaranteed progress (dense-bridge rule)
-            ig = np.ascontiguousarray(
-                ti.reshape(tail_b, self.dp, self.max_nnz).transpose(1, 0, 2)
-            )
-            vg = np.ascontiguousarray(
-                tv.reshape(tail_b, self.dp, self.max_nnz).transpose(1, 0, 2)
-            )
-            yg = np.ascontiguousarray(ty.reshape(tail_b, self.dp).T)
-            mg = np.ascontiguousarray(tm.reshape(tail_b, self.dp).T)
-            if self._paced:
-                order = np.argsort(self.trainer.worker_clocks(), kind="stable")
-                inv = np.empty_like(order)
-                inv[order] = np.arange(self.dp)
-                ig, vg, yg, mg = ig[inv], vg[inv], yg[inv], mg[inv]
-            self.trainer.step((ig, vg), yg, mg, valid_count=rem)
-            self._requeue_refused_sparse(ig, vg, yg, mg)
-            done += rem
+        with tracing.span("launch"):
+            with tracing.span("copy_stage"):
+                si = si[:n].copy()
+                sv = sv[:n].copy()
+                sy = sy[:n].copy()
+            b = self.config.batch_size
+            group = self.dp * b
+            done = 0
+            while n - done >= group:
+                ig = si[done : done + group].reshape(self.dp, b, self.max_nnz)
+                vg = sv[done : done + group].reshape(self.dp, b, self.max_nnz)
+                yg = sy[done : done + group].reshape(self.dp, b)
+                mg = np.ones((self.dp, b), np.float32)
+                with self._fit_span(group, group, tail=False):
+                    self.trainer.step((ig, vg), yg, mg, valid_count=group)
+                self._requeue_refused_sparse(ig, vg, yg, mg)
+                done += group
+            tail_b = min(b, TAIL_BATCH)
+            tail_group = self.dp * tail_b
+            while n - done > 0:
+                rem = min(n - done, tail_group)
+                ti = np.zeros((tail_group, self.max_nnz), np.int32)
+                tv = np.zeros((tail_group, self.max_nnz), np.float32)
+                ty = np.zeros((tail_group,), np.float32)
+                tm = np.zeros((tail_group,), np.float32)
+                ti[:rem] = si[done : done + rem]
+                tv[:rem] = sv[done : done + rem]
+                ty[:rem] = sy[done : done + rem]
+                tm[:rem] = 1.0
+                # stripe rows across workers; SSP maps slots slowest-first so
+                # every tail pass is guaranteed progress (dense-bridge rule)
+                ig = np.ascontiguousarray(
+                    ti.reshape(tail_b, self.dp, self.max_nnz).transpose(1, 0, 2)
+                )
+                vg = np.ascontiguousarray(
+                    tv.reshape(tail_b, self.dp, self.max_nnz).transpose(1, 0, 2)
+                )
+                yg = np.ascontiguousarray(ty.reshape(tail_b, self.dp).T)
+                mg = np.ascontiguousarray(tm.reshape(tail_b, self.dp).T)
+                if self._paced:
+                    order = np.argsort(self.trainer.worker_clocks(), kind="stable")
+                    inv = np.empty_like(order)
+                    inv[order] = np.arange(self.dp)
+                    ig, vg, yg, mg = ig[inv], vg[inv], yg[inv], mg[inv]
+                with self._fit_span(rem, tail_group, tail=True):
+                    self.trainer.step((ig, vg), yg, mg, valid_count=rem)
+                self._requeue_refused_sparse(ig, vg, yg, mg)
+                done += rem
+
+    def _fit_span(self, rows: int, rows_padded: int, tail: bool):
+        """The ``fit`` span of one step program's dispatch, keyed by the
+        trainer's step ordinal (the k-th ``fit`` is the k-th execution of
+        the step program on the device). ``rows_padded`` is the batch the
+        program runs over, padding included."""
+        span = tracing.span(
+            "fit", key=self.trainer._steps_host, rows=rows,
+            rows_padded=rows_padded,
+        )
+        span.set(tail=tail)
+        return span
 
     def _requeue_refused_sparse(self, ig, vg, yg, mg) -> None:
         if not self._paced:
@@ -1548,13 +1605,15 @@ class SparseSPMDBridge(SPMDBridge):
         to its length."""
         if stop is None:
             stop = len(buf)
-        if isinstance(buf, (bytes, memoryview)):
-            block = bytes(buf[:stop])
-            idx, val, y, op, valid = parser.parse(block)
-        else:
-            block = None  # materialized lazily, only for special lines
-            idx, val, y, op, valid = parser.parse_range(buf, 0, stop)
-        n = idx.shape[0]
+        with tracing.span("parse") as parse_span:
+            if isinstance(buf, (bytes, memoryview)):
+                block = bytes(buf[:stop])
+                idx, val, y, op, valid = parser.parse(block)
+            else:
+                block = None  # materialized lazily, only for special lines
+                idx, val, y, op, valid = parser.parse_range(buf, 0, stop)
+            n = idx.shape[0]
+            parse_span.add(rows=n)
         if n == 0:
             return
         # specials (codec fallbacks, forecasts, drops) break the bulk run
@@ -1562,9 +1621,11 @@ class SparseSPMDBridge(SPMDBridge):
         special = np.nonzero((valid != 1) | (op != 0))[0]
         lines = None
         if special.size:
-            if block is None:
-                block = bytes(memoryview(buf)[:stop])
-            lines = block.split(b"\n")
+            # one special line costs a copy and a split of the whole block
+            with tracing.span("split_lines"):
+                if block is None:
+                    block = bytes(memoryview(buf)[:stop])
+                lines = block.split(b"\n")
         # bulk runs of parsed training rows: holdout + stage in C when the
         # fused path is on (same per-record semantics either way)
         stage_bulk = (
@@ -1575,22 +1636,30 @@ class SparseSPMDBridge(SPMDBridge):
         for s in special:
             s = int(s)
             if s > prev:
-                stage_bulk(idx[prev:s], val[prev:s], y[prev:s])
-            inst = DataInstance.from_json(
-                lines[s].decode("utf-8", errors="replace")
-            )
-            if inst is not None:
-                if getattr(self, "_coo_quiesce", None) is not None:
-                    # specials may touch the trainer from this (producer)
-                    # thread (forecasts serve a prediction): drain queued
-                    # collective steps first — including any enqueued by
-                    # the staging right above — so two threads never race
-                    # on trainer state
-                    self._coo_quiesce()
-                self.handle_data(inst)
+                with tracing.span("stage"):
+                    stage_bulk(idx[prev:s], val[prev:s], y[prev:s])
+            # a line the C parser read as a forecast, or one it left to
+            # the Python codec (which may still find a forecast in it)
+            is_forecast = valid[s] == 1 and op[s] != 0
+            with tracing.span("forecast" if is_forecast else "fallback") as sp:
+                with tracing.span("decode"):
+                    inst = DataInstance.from_json(
+                        lines[s].decode("utf-8", errors="replace")
+                    )
+                if inst is not None:
+                    sp.key = inst.id
+                    if getattr(self, "_coo_quiesce", None) is not None:
+                        # specials may touch the trainer from this
+                        # (producer) thread (forecasts serve a prediction):
+                        # drain queued collective steps first — including
+                        # any enqueued by the staging right above — so two
+                        # threads never race on trainer state
+                        self._coo_quiesce()
+                    self.handle_data(inst)
             prev = s + 1
         if prev < n:
-            stage_bulk(idx[prev:], val[prev:], y[prev:])
+            with tracing.span("stage"):
+                stage_bulk(idx[prev:], val[prev:], y[prev:])
 
     def _stage_parsed_rows(self, idx, val, y) -> None:
         """Holdout + stage a run of C-PARSED COO rows through the C stager

@@ -50,6 +50,8 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from omldm_tpu.utils.tracing import Mark, Recorder, Ring, Span
+
 # canonical hot-loop phase names (the bench.py breakdown table's rows);
 # PhaseProfile accepts any name — these are the ones the runtime wires
 # NOTE: the sharded ingest plane (runtime/ingest_shard.py) folds its
@@ -67,11 +69,27 @@ PHASES = (
     "device_wait", # blocking on device results (SPMD drain; 0 on host CPU)
     "serve",       # forecast predict dispatch (the serve StepTimer path)
     "ship",        # transport codec encode+decode (wire prep)
+    # the fused SPMD route (runtime/spmd_bridge.py; spans of the
+    # process-wide utils.tracing.RECORDER). Its read/parse/stage/fit/serve
+    # are the rows above; holdout runs inside the C stager, under "stage"
+    "ingest_file",       # one file through ingest_file_overlapped
+    "dispatcher_open",   # stage sets allocated, dispatch thread started
+    "dispatcher_close",  # join on everything still queued
+    "pool_wait",         # producer blocked: every stage set queued/in flight
+    "split_lines",       # a block with a special line: copied and split
+    "launch",            # dispatch thread: one stage set (copy + its fits)
+    "copy_stage",        # ... its rows copied off the reused stage set
+    "forecast",          # one forecast inside a file (children below)
+    "fallback",          # a line left to the Python codec (same children)
+    "decode",            # forecast line -> DataInstance -> COO row
+    "quiesce",           # wait for the dispatch queue to drain
+    "emit",              # the prediction sink
+    "build_state",       # SPMDTrainer state: template, host build, placement
+    "init_state_host",   # ... the leaves built on the host
+    "place_state",       # ... device_put of every leaf
+    "compile",           # a program traced, lowered or compiled (jax.monitoring)
 )
 
-# bounded per-phase / per-histogram sample window (percentiles summarize
-# the most recent window; totals stay exact)
-RING_CAP = 4096
 SPAN_RING_CAP = 4096
 
 DEFAULT_STATS_EVERY = 10_000
@@ -201,42 +219,6 @@ def validate_telemetry(tc) -> Optional[str]:
     return None
 
 
-class _Ring:
-    """Bounded float sample ring (the ServeStats layout) with an EXACT
-    running total — percentiles summarize the retained window, sums and
-    counts stay true for the whole stream."""
-
-    __slots__ = ("count", "total", "_ring", "_n", "_i")
-
-    def __init__(self, cap: int = RING_CAP):
-        self.count = 0
-        self.total = 0.0
-        self._ring = np.zeros((cap,), np.float64)
-        self._n = 0
-        self._i = 0
-
-    def note(self, value: float) -> None:
-        self.count += 1
-        self.total += value
-        self._ring[self._i] = value
-        self._i = (self._i + 1) % self._ring.shape[0]
-        self._n = min(self._n + 1, self._ring.shape[0])
-
-    def percentiles(self, qs=(50.0, 99.0)) -> Tuple[float, ...]:
-        if self._n == 0:
-            return tuple(0.0 for _ in qs)
-        p = np.percentile(self._ring[: self._n], qs)
-        return tuple(float(v) for v in np.atleast_1d(p))
-
-    def merge(self, other: "_Ring") -> None:
-        self.count += other.count
-        self.total += other.total
-        for v in other._ring[: other._n]:
-            self._ring[self._i] = v
-            self._i = (self._i + 1) % self._ring.shape[0]
-            self._n = min(self._n + 1, self._ring.shape[0])
-
-
 class MetricsRegistry:
     """The unified pull point: counters, gauges, histograms, probes.
 
@@ -257,7 +239,7 @@ class MetricsRegistry:
         self.counters: Dict[str, float] = {}
         self.gauges: Dict[str, float] = {}
         self._max_gauges: set = set()
-        self.histograms: Dict[str, _Ring] = {}
+        self.histograms: Dict[str, Ring] = {}
         self._probes: Dict[str, Callable[[], float]] = {}
 
     # --- writes ----------------------------------------------------------
@@ -276,7 +258,7 @@ class MetricsRegistry:
     def observe(self, name: str, value: float) -> None:
         ring = self.histograms.get(name)
         if ring is None:
-            ring = self.histograms[name] = _Ring()
+            ring = self.histograms[name] = Ring()
         ring.note(value)
 
     def probe(self, name: str, fn: Callable[[], float]) -> None:
@@ -329,82 +311,82 @@ class MetricsRegistry:
         for k, ring in other.histograms.items():
             mine = self.histograms.get(k)
             if mine is None:
-                mine = self.histograms[k] = _Ring()
+                mine = self.histograms[k] = Ring()
             mine.merge(ring)
 
 
-class _PhaseCtx:
-    """Reusable context manager for ``PhaseProfile.phase`` (a stack, so
-    one profile survives nested phases — inner time is attributed to the
-    inner phase only by the caller's discipline; the runtime's hooks never
-    nest)."""
-
-    __slots__ = ("_profile", "_name", "_starts")
-
-    def __init__(self, profile: "PhaseProfile", name: str):
-        self._profile = profile
-        self._name = name
-        self._starts: List[float] = []
-
-    def __enter__(self):
-        self._starts.append(time.perf_counter())
-        return self
-
-    def __exit__(self, *exc):
-        self._profile.note(
-            self._name, time.perf_counter() - self._starts.pop()
-        )
-        return False
-
-
 class PhaseProfile:
-    """Per-phase wall-clock attribution: exact total seconds + counts +
-    bounded sample rings per phase. ``table(e2e_s)`` is the breakdown the
-    benchmarks print; ``share`` sums to the measured attribution
-    fraction."""
+    """The phase table over a span recorder: per-phase SELF seconds (a
+    span's duration minus what its children on the same thread cover,
+    exact), counts, counters and windowed percentiles. ``phase(name)`` is
+    ``utils.tracing``'s span -- the package's one timed block -- so every
+    phase also lies in a profiler trace as ``omldm.<name>``.
+    ``table(e2e_s)`` is the breakdown the benchmarks print; ``share`` sums
+    to the measured attribution fraction. A profile notes into a recorder
+    of its own unless handed one: ``tracing.RECORDER`` for the fused SPMD
+    route's process-wide spans, with ``since`` (a ``Recorder.mark()``) to
+    cover only what came after it."""
 
-    def __init__(self):
-        self._rings: Dict[str, _Ring] = {}
-        self._ctxs: Dict[str, _PhaseCtx] = {}
+    def __init__(self, recorder: Optional[Recorder] = None,
+                 since: Optional[Mark] = None):
+        self.recorder = recorder if recorder is not None else Recorder()
+        self.since = since
 
     def note(self, name: str, seconds: float) -> None:
-        ring = self._rings.get(name)
-        if ring is None:
-            ring = self._rings[name] = _Ring()
-        ring.note(seconds)
+        self.recorder.note_seconds(name, seconds)
 
-    def phase(self, name: str) -> _PhaseCtx:
-        ctx = self._ctxs.get(name)
-        if ctx is None:
-            ctx = self._ctxs[name] = _PhaseCtx(self, name)
-        return ctx
+    def phase(self, name: str) -> Span:
+        return self.recorder.span(name)
 
     def seconds(self, name: str) -> float:
-        ring = self._rings.get(name)
-        return ring.total if ring is not None else 0.0
+        return self.recorder.summary(name, self.since)[1]
 
     def total_seconds(self) -> float:
-        return sum(r.total for r in self._rings.values())
+        return sum(self.seconds(n) for n in self.recorder.names())
 
     def table(self, e2e_s: Optional[float] = None,
-              extra: Optional[Dict[str, float]] = None) -> dict:
+              extra: Optional[Dict[str, float]] = None,
+              also: Optional["PhaseProfile"] = None) -> dict:
         """{phase: {seconds, count, p50_ms, p99_ms, share}} + a
         ``_coverage`` row when ``e2e_s`` is given: the fraction of the
         measured end-to-end wall the attributed phases account for.
+        Seconds are self time, so nested phases count once (``compile``
+        is no span: its seconds are also in the self time of the span that
+        called the program). A phase with counters carries them in its
+        row (``rows``, ``rows_padded``). Phases noted from more than one
+        thread (the fused route's producer and dispatch threads) overlap
+        in wall time. ``also`` adds another profile's phases, row by row;
         ``extra`` folds in phase totals tracked elsewhere (StepTimer
         total_ms, codec seconds) as {phase: seconds} without sample
         rings."""
+        phases: Dict[str, list] = {}
+        for profile in (self, also) if also is not None else (self,):
+            for name in profile.recorder.names():
+                count, seconds, counts, samples = profile.recorder.summary(
+                    name, profile.since
+                )
+                if not count:
+                    continue
+                into = phases.setdefault(name, [0, 0.0, {}, []])
+                into[0] += count
+                into[1] += seconds
+                for k, v in counts.items():
+                    into[2][k] = into[2].get(k, 0) + v
+                into[3] += samples
         out: dict = {}
         total = 0.0
-        for name, ring in self._rings.items():
-            p50, p99 = ring.percentiles()
+        for name, (count, seconds, counts, samples) in phases.items():
+            p50, p99 = (
+                np.percentile(samples, (50.0, 99.0)) if samples else (0.0, 0.0)
+            )
             out[name] = {
-                "seconds": round(ring.total, 4),
-                "count": ring.count,
-                "p50_ms": round(p50 * 1000.0, 4),
-                "p99_ms": round(p99 * 1000.0, 4),
+                "seconds": round(seconds, 4),
+                "count": count,
+                "p50_ms": round(float(p50) * 1000.0, 4),
+                "p99_ms": round(float(p99) * 1000.0, 4),
+                **counts,
             }
-            total += ring.total
+            total += seconds
         for name, secs in (extra or {}).items():
             row = out.setdefault(
                 name, {"seconds": 0.0, "count": 0, "p50_ms": 0.0,
@@ -419,11 +401,7 @@ class PhaseProfile:
         return out
 
     def merge(self, other: "PhaseProfile") -> None:
-        for name, ring in other._rings.items():
-            mine = self._rings.get(name)
-            if mine is None:
-                mine = self._rings[name] = _Ring()
-            mine.merge(ring)
+        self.recorder.merge(other.recorder)
 
 
 class SpanLog:
