@@ -31,6 +31,7 @@ import contextlib
 import json
 import math
 import os
+import re
 import sys
 import tempfile
 import time
@@ -332,7 +333,164 @@ def leg_stream_sparse(workdir: str, n_chips: int, *, n_num: int = 13,
     out = _check_spmd_run(run, rows, n_chips, batch, launches,
                           chance_loss=1.0)
     out["model_width"] = n_num + hash_space
+    [bridge] = run[0].spmd_bridges.values()
+    out.update(_check_state_view_is_free(bridge.trainer, batch, max_nnz))
     return out
+
+
+# --- the static guard on the sparse step's state view ------------------------
+
+# the opcodes a relayout of a vector leaf compiles to (PERF.md section 5,
+# PR 26: a reduce over two unit axes, a constant fill, a one-trip while
+# around a dynamic-slice, a copy). Moving a small vector into faster memory
+# (copy-start, slice-start), which the compiler chooses at this leg's 1 MB,
+# is not among them.
+_HLO_PASSES = ("reduce", "broadcast", "copy", "while", "dynamic-slice")
+
+
+def hlo_computations(text: str) -> Dict[str, List[Tuple[str, str, str, str]]]:
+    """Compiled HLO text -> {computation: [(name, opcode, shape, line)]}.
+    The entry computation is filed under ``"ENTRY"`` as well."""
+    head = re.compile(r"^(ENTRY )?%?([\w.\-]+) \(.*\) -> .*\{$")
+    inst = re.compile(
+        r"^\s+(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) ([\w\-]+)\("
+    )
+    comps: Dict[str, List[Tuple[str, str, str, str]]] = {}
+    cur = None
+    for line in text.splitlines():
+        m = head.match(line)
+        if m:
+            cur = comps.setdefault(m.group(2), [])
+            if m.group(1):
+                comps["ENTRY"] = cur
+            continue
+        m = inst.match(line)
+        if m and cur is not None:
+            cur.append((m.group(1), m.group(3), m.group(2), line))
+    return comps
+
+
+def _hlo_wide(shape: str, n: int) -> bool:
+    return any(
+        math.prod(int(d) for d in dims.split(",") if d) >= n
+        for dims in re.findall(r"[a-z]+\d+\[([\d,]*)\]", shape)
+    )
+
+
+def _hlo_called(line: str) -> List[str]:
+    names: List[str] = []
+    for group in re.findall(
+        r"(?:calls|to_apply|body|condition|true_computation|"
+        r"false_computation|branch_computations)=\{?([^}\s]+(?:, [^}\s]+)*)\}?",
+        line,
+    ):
+        names += [g.strip().lstrip("%").rstrip(",") for g in group.split(",")]
+    return names
+
+
+def hlo_wide_passes(text: str, n: int) -> List[Tuple[str, str, str]]:
+    """The instructions of a compiled program whose result holds ``n`` or
+    more elements and that are a relayout's (``_HLO_PASSES``) or a fusion
+    without the scatter in it, outside the branch a conditional takes when
+    its predicate holds (the protocol's sync): (computation, name, opcode).
+    The false branch, which every step runs, is walked."""
+    comps = hlo_computations(text)
+    found: List[Tuple[str, str, str]] = []
+    seen = set()
+
+    def holds_scatter(comp: str) -> bool:
+        return any(
+            op == "scatter" or any(holds_scatter(c) for c in _hlo_called(line))
+            for _, op, _, line in comps.get(comp, ())
+        )
+
+    def walk(comp: str) -> None:
+        if comp in seen:
+            return
+        seen.add(comp)
+        for name, op, shape, line in comps.get(comp, ()):
+            called = _hlo_called(line)
+            if op == "conditional":
+                m = re.search(r"false_computation=%?([\w.\-]+)", line)
+                # index form: branch 0 is the one taken when the predicate
+                # is false
+                walk(m.group(1) if m else called[0])
+                continue
+            if op == "fusion":
+                if _hlo_wide(shape, n) and not any(
+                    holds_scatter(c) for c in called
+                ):
+                    found.append((comp, name, op))
+                continue
+            for c in called:
+                walk(c)
+            if op in _HLO_PASSES and _hlo_wide(shape, n):
+                found.append((comp, name, op))
+
+    walk("ENTRY")
+    return found
+
+
+def hlo_aliased_parameters(text: str) -> List[int]:
+    """Parameter numbers the module's ``input_output_alias`` donates."""
+    head = next(
+        (l for l in text.splitlines() if "input_output_alias=" in l), ""
+    )
+    return sorted(
+        int(k) for k in re.findall(r"\((\d+), \{\}, \w+-alias\)", head)
+    )
+
+
+def _check_state_view_is_free(trainer, batch: int, max_nnz: int) -> dict:
+    """From the compiled step and predict programs themselves: entering the
+    step, leaving it and serving from it move no vector leaf. Outside the
+    sync branch the only pass over the model's width is the scatter, the
+    donated vector leaves are updated in place, and the predict program makes
+    no such pass at all."""
+    import jax
+    import jax.numpy as jnp
+
+    n = trainer.flat_size
+    dp = trainer.dp
+    shapes = jax.tree_util.tree_map(
+        lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype, sharding=l.sharding),
+        trainer.state,
+    )
+    x = (
+        jax.ShapeDtypeStruct((dp, batch, max_nnz), jnp.int32),
+        jax.ShapeDtypeStruct((dp, batch, max_nnz), jnp.float32),
+    )
+    y = jax.ShapeDtypeStruct((dp, batch), jnp.float32)
+    step_text = trainer._step.lower(shapes, x, y, y).compile().as_text()
+    passes = hlo_wide_passes(step_text, n)
+    _require(
+        not passes,
+        f"outside the sync branch no op of the step but the scatter has a "
+        f"result of {n} or more elements, found {passes}",
+    )
+    entry = hlo_computations(step_text)["ENTRY"]
+    vectors = sorted(
+        int(line.split("parameter(")[1].split(")")[0])
+        for _, op, shape, line in entry
+        if op == "parameter" and _hlo_wide(shape, n)
+    )
+    aliased = hlo_aliased_parameters(step_text)
+    _require(
+        len(vectors) >= 3 and set(vectors) <= set(aliased),
+        f"the step's vector leaves (parameters {vectors}) are aliased input "
+        f"to output, aliased are {aliased}",
+    )
+    predict_fn, _ = trainer._serve_fns()
+    xp = tuple(jax.ShapeDtypeStruct((16, max_nnz), a.dtype) for a in x)
+    passes = hlo_wide_passes(
+        predict_fn.lower(shapes, xp).compile().as_text(), n
+    )
+    _require(
+        not passes,
+        f"the predict program has no result of {n} or more elements, found "
+        f"{passes}",
+    )
+    return {"wide_passes": 0, "vector_leaves_aliased": len(vectors)}
 
 
 def leg_stream_mixed(workdir: str, n_chips: int, *, tenants: int = 8,

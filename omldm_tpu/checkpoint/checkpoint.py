@@ -418,15 +418,20 @@ class CheckpointManager:
         if bridge is None:
             return
         from omldm_tpu.parallel.ckpt import place_tree
+        from omldm_tpu.parallel.spmd import stacked, stored
 
         t = bridge.trainer
-        fleet = bd["fleet"]
+        # the saved leaves in their [dp, hub, ...] view (a snapshot from
+        # before vector leaves were stored flat already is)
+        fleet = jax.tree_util.tree_map(
+            lambda l: stacked(np.asarray(l), *bd["mesh"]), bd["fleet"]
+        )
         if (t.dp, t.hub) == tuple(bd["mesh"]):
-            t.state = place_tree(fleet, t._state_specs, t.mesh)
+            new_state = fleet
         else:
 
             def tile(leaf):
-                l = np.asarray(leaf)[0, 0]
+                l = leaf[0, 0]
                 return np.broadcast_to(
                     l, (t.dp, t.hub) + l.shape
                 ).copy()
@@ -435,8 +440,7 @@ class CheckpointManager:
                 # model-bearing leaves: mean over the dp replicas (hub
                 # shard 0 — hub replicas agree by construction) so
                 # mid-round divergence is merged, not discarded
-                l = np.asarray(leaf)
-                m = l[:, 0].mean(axis=0).astype(l.dtype)
+                m = leaf[:, 0].mean(axis=0).astype(leaf.dtype)
                 return np.broadcast_to(m, (t.dp, t.hub) + m.shape).copy()
 
             new_state = {
@@ -458,7 +462,9 @@ class CheckpointManager:
             for key, val in fleet.items():
                 if key not in new_state:
                     new_state[key] = tile(val)
-            t.state = place_tree(new_state, t._state_specs, t.mesh)
+        t.state = place_tree(
+            jax.tree_util.tree_map(stored, new_state), t._state_specs, t.mesh
+        )
         t._fitted_host = bd["fitted"]
         t._steps_host = bd["steps"]
         bridge.holdout_count = bd["holdout_count"]

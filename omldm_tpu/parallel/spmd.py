@@ -93,22 +93,63 @@ def _program(key: tuple, build):
     return fn
 
 
-def _sq(leaf):
-    """Strip the [1, 1] (dp, hub) leading stacking dims of a per-shard leaf."""
-    return leaf[0, 0]
+# --- the stored form of a state leaf, and the one view of it ---
+#
+# Every mesh shard (w, h) holds one value of every state leaf. A leaf whose
+# per-shard value is a VECTOR (a rank-1 ``params`` leaf, ``est``, ``center``,
+# ``ef``) is stored flat, ``[dp * hub * n]`` sharded over ``("dp", "hub")``
+# together, so the block a shard holds IS the vector the step computes on:
+# entering the step, leaving it and serving from it move nothing, and the
+# donated buffer is updated in place. (Stored ``[dp, hub, n]``, the block is
+# ``f32[1, 1, n]``, which the TPU tiles (1, 128) where ``f32[n]`` is tiled
+# (1024): stripping and restoring the two 1s then copies the whole vector,
+# three passes over ``n`` a step.) Every other leaf (scalars, matrices) is
+# stored stacked ``[dp, hub, ...]``: its tiling survives the leading 1s.
+# The stored form tells which: rank 1 is a vector leaf. Nothing outside
+# the functions below knows it.
 
 
-def _unsq(leaf):
-    return leaf[None, None]
+def shard_value(leaf, dp=1, hub=1):
+    """The value shard ``(0, 0)`` of a ``(dp, hub)`` mesh holds of a stored
+    leaf. With the defaults the array at hand is one shard's own block (the
+    step, inside ``shard_map``), and a vector leaf passes through untouched
+    (a slice that covers the whole emits nothing)."""
+    if leaf.ndim != 1:
+        return leaf[0, 0]
+    return leaf[: leaf.shape[0] // (dp * hub)]
+
+
+def shard_block(value):
+    """Inverse of :func:`shard_value` at one shard: the block a shard stores
+    for ``value``."""
+    return value if value.ndim == 1 else value[None, None]
+
+
+def stacked(leaf, dp, hub):
+    """Whole stored leaf -> ``[dp, hub, ...]`` (a reshape: no copy on the
+    host)."""
+    return leaf.reshape(dp, hub, -1) if leaf.ndim == 1 else leaf
+
+
+def stored(leaf):
+    """Inverse of :func:`stacked`: ``[dp, hub, ...]`` -> the stored form."""
+    return leaf.reshape(-1) if leaf.ndim == 3 else leaf
+
+
+def stored_spec(leaf) -> P:
+    """PartitionSpec of a stored leaf: one block per mesh shard."""
+    return P(("dp", "hub")) if leaf.ndim == 1 else P("dp", "hub")
 
 
 class SPMDTrainer:
     """One pipeline trained data-parallel across a ("dp", "hub") mesh.
 
-    State leaves are stacked ``[dp, hub, ...]`` and sharded one slot per mesh
-    shard; micro-batches arrive stacked ``[dp, B, D]`` (one batch per
-    worker). ``step`` runs one jitted, donated training step for the whole
-    fleet."""
+    Every mesh shard holds one value of every state leaf, in the stored form
+    :func:`stored` gives (vector leaves flat ``[dp * hub * n]``, the others
+    stacked ``[dp, hub, ...]``); readers go through :func:`shard_value` and
+    :func:`stacked`. Micro-batches arrive stacked ``[dp, B, D]`` (one batch
+    per worker). ``step`` runs one jitted, donated training step for the
+    whole fleet."""
 
     def __init__(
         self,
@@ -177,7 +218,8 @@ class SPMDTrainer:
         with tracing.span("build_state"):
             # template params -> flat layout shared by every replica
             template = self.learner.init(d, jax.random.PRNGKey(seed))
-            flat0, self._unravel = jax.flatten_util.ravel_pytree(template)
+            flat0, _ = jax.flatten_util.ravel_pytree(template)
+            self._template = jax.eval_shape(lambda: template)
             self.n_params = int(flat0.size)
             self.pad = (-self.n_params) % self.hub
             self.flat_size = self.n_params + self.pad
@@ -185,16 +227,15 @@ class SPMDTrainer:
 
             with tracing.span("init_state_host"):
                 state_host = self._init_state(seed, prep_dims, template)
-            spec = NamedSharding(self.mesh, P("dp", "hub"))
+            self._state_specs = jax.tree_util.tree_map(stored_spec, state_host)
             # ends when device_put returns: the copies may still be in flight
             with tracing.span("place_state"):
                 self.state = jax.tree_util.tree_map(
-                    lambda leaf: jax.device_put(jnp.asarray(leaf), spec),
-                    state_host,
+                    lambda leaf, spec: jax.device_put(
+                        jnp.asarray(leaf), NamedSharding(self.mesh, spec)
+                    ),
+                    state_host, self._state_specs,
                 )
-            self._state_specs = jax.tree_util.tree_map(
-                lambda _: P("dp", "hub"), state_host
-            )
 
         # the static signature every compiled program of this trainer is
         # a pure function of: trainers agreeing on it share executables
@@ -294,7 +335,7 @@ class SPMDTrainer:
             # a codec is configured, so codec-none state trees — and their
             # checkpoints — are unchanged.
             state["ef"] = stack(np.zeros((self.dp, self.flat_size), np.float32))
-        return state
+        return jax.tree_util.tree_map(stored, state)
 
     # --- the per-shard step ---
 
@@ -305,7 +346,17 @@ class SPMDTrainer:
         return flat
 
     def _unflat(self, flat):
-        return self._unravel(flat[: self.n_params])
+        """Inverse of :meth:`_flat`. Where the model is one vector the flat
+        form IS that vector: slices and reshapes that cover the whole emit
+        nothing (``ravel_pytree``'s own unravel emits a ``split``)."""
+        leaves, treedef = jax.tree_util.tree_flatten(self._template)
+        out, k = [], 0
+        for leaf in leaves:
+            out.append(
+                flat[k : k + leaf.size].reshape(leaf.shape).astype(leaf.dtype)
+            )
+            k += leaf.size
+        return jax.tree_util.tree_unflatten(treedef, out)
 
     def _ps_allreduce(self, flat):
         """pmean over workers, decomposed through the hub-sharded PS:
@@ -334,7 +385,8 @@ class SPMDTrainer:
         qdq = self._qdq  # transport codec QDQ kernel (None = raw fp32)
 
         def step_fn(state, x, y, mask):
-            # per-shard views: state leaves [1,1,...]; batch [1,B,D] dense
+            # per-shard views: state leaves as one shard stores them
+            # (shard_value); batch [1,B,D] dense
             # or ([1,B,K] idx, [1,B,K] val) padded-COO. Inputs may arrive
             # in a narrow feed dtype (float16 staging halves host->device
             # bytes); compute is always f32.
@@ -349,16 +401,18 @@ class SPMDTrainer:
                 x = jax.lax.pcast(x[0].astype(f32), "hub", to="varying")
             y = jax.lax.pcast(y[0].astype(f32), "hub", to="varying")
             mask = jax.lax.pcast(mask[0].astype(f32), "hub", to="varying")
-            params = jax.tree_util.tree_map(_sq, state["params"])
-            prep_states = [jax.tree_util.tree_map(_sq, s) for s in state["preps"]]
-            est = _sq(state["est"])
-            center = _sq(state["center"])
-            step_i = _sq(state["step"])
-            syncs = _sq(state["syncs"])
-            cum_loss = _sq(state["cum_loss"])
-            clock = _sq(state["clock"])
-            fold_rounds = _sq(state["fold_rounds"])
-            ef = _sq(state["ef"]) if qdq is not None else None
+            params = jax.tree_util.tree_map(shard_value, state["params"])
+            prep_states = [
+                jax.tree_util.tree_map(shard_value, s) for s in state["preps"]
+            ]
+            est = shard_value(state["est"])
+            center = shard_value(state["center"])
+            step_i = shard_value(state["step"])
+            syncs = shard_value(state["syncs"])
+            cum_loss = shard_value(state["cum_loss"])
+            clock = shard_value(state["clock"])
+            fold_rounds = shard_value(state["fold_rounds"])
+            ef = shard_value(state["ef"]) if qdq is not None else None
 
             old_params = params
             old_preps = prep_states
@@ -550,22 +604,22 @@ class SPMDTrainer:
             cum_loss = cum_loss + loss * n
 
             new_state = {
-                "params": jax.tree_util.tree_map(_unsq, params),
+                "params": jax.tree_util.tree_map(shard_block, params),
                 "preps": [
-                    jax.tree_util.tree_map(_unsq, s) for s in new_preps
+                    jax.tree_util.tree_map(shard_block, s) for s in new_preps
                 ],
-                "est": _unsq(est),
-                "center": _unsq(center),
-                "step": _unsq(step_i),
-                "syncs": _unsq(syncs),
-                "cum_loss": _unsq(cum_loss),
-                "clock": _unsq(clock),
-                "accepted": _unsq(accepted),
-                "fold_rounds": _unsq(fold_rounds),
+                "est": shard_block(est),
+                "center": shard_block(center),
+                "step": shard_block(step_i),
+                "syncs": shard_block(syncs),
+                "cum_loss": shard_block(cum_loss),
+                "clock": shard_block(clock),
+                "accepted": shard_block(accepted),
+                "fold_rounds": shard_block(fold_rounds),
             }
             if qdq is not None:
-                new_state["ef"] = _unsq(ef)
-            return new_state, _unsq(loss)
+                new_state["ef"] = shard_block(ef)
+            return new_state, shard_block(loss)
 
         return step_fn
 
@@ -667,16 +721,28 @@ class SPMDTrainer:
     def fitted(self) -> int:
         return self._fitted_host
 
+    def shard0(self, tree):
+        """Shard (0, 0)'s value of every leaf of a stored state (sub)tree:
+        the model that queries and evaluations read (post-sync replicas
+        agree). Works on host copies and under ``jit`` alike."""
+        return jax.tree_util.tree_map(
+            lambda l: shard_value(l, self.dp, self.hub), tree
+        )
+
+    def host_stacked(self, leaf) -> np.ndarray:
+        """Host copy of a state leaf, viewed ``[dp, hub, ...]``."""
+        return stacked(np.asarray(jax.device_get(leaf)), self.dp, self.hub)
+
     def worker_clocks(self) -> np.ndarray:
         """Per-worker progress clocks [dp] (ticks with data consumed)."""
-        return np.asarray(jax.device_get(self.state["clock"]))[:, 0]
+        return self.host_stacked(self.state["clock"])[:, 0]
 
     def last_accepted(self) -> np.ndarray:
         """Bool [dp]: whether each worker CONSUMED its batch on the latest
         step. Under SSP a worker at the staleness bound refuses its batch;
         the host must requeue it (and call :meth:`note_requeued` so fitted
         counts only consumed rows)."""
-        return np.asarray(jax.device_get(self.state["accepted"]))[:, 0] > 0.0
+        return self.host_stacked(self.state["accepted"])[:, 0] > 0.0
 
     def release_stragglers(self) -> None:
         """Termination-time SSP release — the collective analogue of the
@@ -690,7 +756,9 @@ class SPMDTrainer:
             ("release_clock", id(self.mesh)),
             lambda: jax.jit(
                 lambda c: jnp.full_like(c, c.max()),
-                out_shardings=NamedSharding(self.mesh, P("dp", "hub")),
+                out_shardings=NamedSharding(
+                    self.mesh, self._state_specs["clock"]
+                ),
             ),
         )(self.state["clock"])
         self.state = {**self.state, "clock": new_clock}
@@ -717,7 +785,7 @@ class SPMDTrainer:
     def sync_count(self) -> int:
         """Total parameter synchronizations executed (summed over workers for
         staggered protocols; rounds for the others)."""
-        syncs = np.asarray(jax.device_get(self.state["syncs"]))
+        syncs = self.host_stacked(self.state["syncs"])
         if self.protocol in ("Asynchronous", "SSP"):
             return int(syncs[:, 0].sum())
         return int(syncs[0, 0])
@@ -768,8 +836,8 @@ class SPMDTrainer:
           This is the traffic the communication-skipping protocols pay
           even in silent rounds.
         """
-        syncs = np.asarray(jax.device_get(self.state["syncs"]))
-        steps = int(np.asarray(jax.device_get(self.state["step"]))[0, 0])
+        syncs = self.host_stacked(self.state["syncs"])
+        steps = int(self.host_stacked(self.state["step"])[0, 0])
         _, total = self.protocol_traffic_bytes(
             self.protocol, self.dp, self.flat_size,
             int(syncs[:, 0].sum()), int(syncs[0, 0]), steps,
@@ -782,8 +850,8 @@ class SPMDTrainer:
         inter-host links carry the quantized representation (the values
         crossing the collective are already codec-representable via the
         in-step QDQ). Equal to :meth:`bytes_shipped` with codec ``none``."""
-        syncs = np.asarray(jax.device_get(self.state["syncs"]))
-        steps = int(np.asarray(jax.device_get(self.state["step"]))[0, 0])
+        syncs = self.host_stacked(self.state["syncs"])
+        steps = int(self.host_stacked(self.state["step"])[0, 0])
         _, total = self.protocol_traffic_bytes(
             self.protocol, self.dp, self.flat_size,
             int(syncs[:, 0].sum()), int(syncs[0, 0]), steps,
@@ -802,10 +870,8 @@ class SPMDTrainer:
         by the scalar control traffic."""
         param_bytes = 2 * self.flat_size * 4
         if self.protocol in ("Asynchronous", "SSP"):
-            steps = int(np.asarray(jax.device_get(self.state["step"]))[0, 0])
-            rounds = int(
-                np.asarray(jax.device_get(self.state["fold_rounds"]))[0, 0]
-            )
+            steps = int(self.host_stacked(self.state["step"])[0, 0])
+            rounds = int(self.host_stacked(self.state["fold_rounds"])[0, 0])
             channels = 2 if self.protocol == "SSP" else 1
             return (
                 rounds * self.dp * param_bytes
@@ -814,22 +880,21 @@ class SPMDTrainer:
         return self.bytes_shipped()
 
     def global_flat_params(self) -> np.ndarray:
-        """Model of worker 0 / hub 0 (post-sync replicas agree)."""
-        flat, _ = jax.flatten_util.ravel_pytree(
-            jax.tree_util.tree_map(lambda l: jax.device_get(l)[0, 0], self.state["params"])
+        """Model of worker 0 / hub 0 (post-sync replicas agree), flattened
+        on the host in ``ravel_pytree``'s leaf order (raveling the host copy
+        with jax would put the whole model on the device twice more)."""
+        leaves = jax.tree_util.tree_leaves(
+            self.shard0(jax.device_get(self.state["params"]))
         )
-        return np.asarray(flat)
+        return np.concatenate([np.ravel(l) for l in leaves])
 
     def shard_params(self):
         """Per-worker params pytree list (host copies)."""
-        out = []
-        for w in range(self.dp):
-            out.append(
-                jax.tree_util.tree_map(
-                    lambda l: jax.device_get(l)[w, 0], self.state["params"]
-                )
-            )
-        return out
+        host = jax.tree_util.tree_map(self.host_stacked, self.state["params"])
+        return [
+            jax.tree_util.tree_map(lambda l: l[w, 0], host)
+            for w in range(self.dp)
+        ]
 
     def save(self, directory: str) -> None:
         """Orbax snapshot of the full fleet state (SURVEY.md section 7 step 8)."""
@@ -838,35 +903,35 @@ class SPMDTrainer:
         save_tree(directory, self.state)
 
     def load(self, directory: str) -> None:
-        """Restore fleet state saved by :meth:`save` (same mesh shape)."""
+        """Restore fleet state saved by :meth:`save` (same mesh shape). A
+        snapshot whose vector leaves were saved ``[dp, hub, n]`` loads too:
+        :func:`stored` brings either form to the stored one."""
         from omldm_tpu.parallel.ckpt import load_tree, place_tree
 
-        host_state = load_tree(directory)
+        host_state = jax.tree_util.tree_map(stored, load_tree(directory))
         self.state = place_tree(host_state, self._state_specs, self.mesh)
 
     def _serve_fns(self):
         """Jitted worker-0 serving programs, compiled once and cached: the
-        whole (slice shard 0 -> preprocess -> predict/eval) chain runs on
-        device — the previous implementation device_get the ENTIRE model
-        pytree per call, which put a full fleet-state transfer on the
-        per-forecast serving hot path."""
+        whole (shard (0, 0)'s value of each leaf -> preprocess ->
+        predict/eval) chain runs on device — the previous implementation
+        device_get the ENTIRE model pytree per call, which put a full
+        fleet-state transfer on the per-forecast serving hot path. On one
+        chip the value of a vector leaf is the stored leaf itself."""
         if getattr(self, "_serve_cache", None) is None:
-
-            def w0(tree):
-                return jax.tree_util.tree_map(lambda l: l[0, 0], tree)
 
             def transform(state, z):
                 for prep, s in zip(self.preps, state["preps"]):
-                    z = prep.transform(w0(s), z)
+                    z = prep.transform(self.shard0(s), z)
                 return z
 
             def predict_fn(state, x):
                 z = transform(state, x)
-                return self.learner.predict(w0(state["params"]), z)
+                return self.learner.predict(self.shard0(state["params"]), z)
 
             def eval_fn(state, x, y, mask):
                 z = transform(state, x)
-                params = w0(state["params"])
+                params = self.shard0(state["params"])
                 return (
                     self.learner.loss(params, z, y, mask),
                     self.learner.score(params, z, y, mask),

@@ -1080,11 +1080,16 @@ class DistributedStreamJob:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if p._accepted_jit is None:
+            from omldm_tpu.parallel.spmd import stacked
+
             rep = NamedSharding(self.mesh, P())
             p._accepted_jit = self._shared_jit(
                 p, "accepted",
                 lambda: jax.jit(
-                    lambda s: s["accepted"][:, 0] > 0.0, out_shardings=rep
+                    lambda s: stacked(
+                        s["accepted"], self.dp_global, p.trainer.hub
+                    )[:, 0] > 0.0,
+                    out_shardings=rep,
                 ),
             )
         acc = self._fetch_replicated(p._accepted_jit(p.trainer.state))
@@ -1123,9 +1128,7 @@ class DistributedStreamJob:
         if p._predict_jit is None:
             t = p.trainer
             rep = NamedSharding(self.mesh, P())
-
-            def w0(tree):
-                return jax.tree_util.tree_map(lambda l: l[0, 0], tree)
+            w0 = t.shard0
 
             if p.sparse:
 
@@ -1249,8 +1252,9 @@ class DistributedStreamJob:
             rep = NamedSharding(self.mesh, P())
 
             def gather_fn(state):
-                w0 = jax.tree_util.tree_map(lambda l: l[0, 0], state["params"])
-                flat, _ = jax.flatten_util.ravel_pytree(w0)
+                flat, _ = jax.flatten_util.ravel_pytree(
+                    p.trainer.shard0(state["params"])
+                )
                 return flat
 
             p._gather_params_jit = self._shared_jit(
@@ -1349,9 +1353,7 @@ class DistributedStreamJob:
         if p._eval_jit is None:
             t = p.trainer
             rep = NamedSharding(self.mesh, P())
-
-            def w0(tree):
-                return jax.tree_util.tree_map(lambda l: l[0, 0], tree)
+            w0 = t.shard0
 
             if p.sparse:
 
@@ -1402,14 +1404,20 @@ class DistributedStreamJob:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         if p._counters_jit is None:
+            from omldm_tpu.parallel.spmd import stacked
+
             rep = NamedSharding(self.mesh, P())
+
+            def view(leaf):
+                return stacked(leaf, self.dp_global, p.trainer.hub)
+
             p._counters_jit = self._shared_jit(
                 p, "counters",
                 lambda: jax.jit(
                     lambda s: (
-                        s["syncs"][:, 0].sum(),
-                        s["syncs"][0, 0],
-                        s["step"][0, 0],
+                        view(s["syncs"])[:, 0].sum(),
+                        view(s["syncs"])[0, 0],
+                        view(s["step"])[0, 0],
                     ),
                     out_shardings=(rep, rep, rep),
                 ),
@@ -1924,9 +1932,8 @@ class DistributedStreamJob:
             if request.request == RequestType.UPDATE:
                 request = _dc.replace(request, request=RequestType.CREATE)
             self._deploy(request, line)
-        from jax.sharding import PartitionSpec as P
-
         from omldm_tpu.parallel.multihost import host_local_array
+        from omldm_tpu.parallel.spmd import stacked, stored, stored_spec
 
         # shards this process merges (exactly [pid] when the count is
         # unchanged; the retiring shards' union on shrink; empty for a
@@ -1965,14 +1972,20 @@ class DistributedStreamJob:
                 p.trainer.state
             )
             placed = []
-            for i, (path, _) in enumerate(paths_leaves):
+            for i, (path, live) in enumerate(paths_leaves):
                 key = str(getattr(path[0], "key", path[0]))
+                # saved stored; redistributed by worker row in the
+                # [dp, hub, ...] view; placed stored again. The stored
+                # leading axis is proportional to the fleet's worker rows.
+                saved = stored(fleet[f"leaf_{i}"])
+                dp_saved = saved.shape[0] * self.dp_global // live.shape[0]
                 full = _rescale_fleet_leaf(
-                    fleet[f"leaf_{i}"], key, self.dp_global
+                    stacked(saved, dp_saved, p.trainer.hub),
+                    key, self.dp_global,
                 )
-                local = full[lo : lo + self.dp_local]
+                local = stored(full[lo : lo + self.dp_local])
                 placed.append(
-                    host_local_array(local, self.mesh, P("dp", "hub"))
+                    host_local_array(local, self.mesh, stored_spec(local))
                 )
             p.trainer.state = jax.tree_util.tree_unflatten(treedef, placed)
             pms = [m["pipelines"][str(net_id)] for m in metas]

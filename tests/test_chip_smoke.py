@@ -23,11 +23,14 @@ def test_stream_fused_tiny(tmp_path):
 
 
 def test_stream_sparse_tiny(tmp_path):
+    # the model is wider than a batch (64 x 41 entries), so that the leg's
+    # static guard can tell a pass over the model from one over the batch
     out = chip_smoke.leg_stream_sparse(
-        str(tmp_path), 1, hash_space=1 << 10, batch=64, launches=3,
+        str(tmp_path), 1, hash_space=1 << 13, batch=64, launches=3,
         test_set=16,
     )
-    assert out["model_width"] == 13 + (1 << 10)
+    assert out["model_width"] == 13 + (1 << 13)
+    assert out["wide_passes"] == 0 and out["vector_leaves_aliased"] == 3
 
 
 def test_stream_mixed_tiny(tmp_path):

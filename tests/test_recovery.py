@@ -14,6 +14,7 @@ import pytest
 
 from omldm_tpu.checkpoint import CheckpointManager
 from omldm_tpu.config import JobConfig
+from omldm_tpu.parallel.spmd import stacked
 from omldm_tpu.runtime import StreamJob
 from omldm_tpu.runtime.job import REQUEST_STREAM, TRAINING_STREAM
 from omldm_tpu.runtime.recovery import (
@@ -275,15 +276,16 @@ class TestSPMDBridgeCheckpoint:
             snapshot = pickle.load(f)
         fleet = snapshot["bridges"][0]["fleet"]
         leaves = jax.tree_util.tree_leaves(fleet["params"])
-        saved = np.asarray(leaves[0])  # [dp, hub, ...]
+        saved = stacked(  # [dp, hub, ...]
+            np.asarray(leaves[0]), *snapshot["bridges"][0]["mesh"]
+        )
         assert saved.shape[0] == 2
         # the premise: replicas actually diverged mid-round
         assert not np.allclose(saved[0, 0], saved[1, 0])
         restored = mgr.restore(parallelism=1)
-        rleaves = jax.tree_util.tree_leaves(
-            restored.spmd_bridges[0].trainer.state["params"]
-        )
-        got = np.asarray(rleaves[0])
+        trainer = restored.spmd_bridges[0].trainer
+        rleaves = jax.tree_util.tree_leaves(trainer.state["params"])
+        got = trainer.host_stacked(rleaves[0])
         expect = saved[:, 0].mean(axis=0)
         np.testing.assert_allclose(got[0, 0], expect, rtol=1e-6, atol=1e-7)
 

@@ -109,7 +109,7 @@ class TestSPMDProtocols:
     def test_async_staggered_syncs(self):
         trainer, _, _ = run_trainer("Asynchronous")
         # every worker folded at least once over 40 steps at cadence 2
-        syncs = np.asarray(jax.device_get(trainer.state["syncs"]))[:, 0]
+        syncs = trainer.host_stacked(trainer.state["syncs"])[:, 0]
         assert (syncs > 0).all()
 
 
@@ -183,7 +183,7 @@ class TestAsyncSharedGlobal:
         )
         # the center / shared global itself must be bit-identical on every
         # worker — its updates are pure collectives from an identical seed
-        centers = np.asarray(jax.device_get(trainer.state["center"]))
+        centers = trainer.host_stacked(trainer.state["center"])
         assert float(np.abs(centers - centers[:1]).max()) == 0.0
         shards = trainer.shard_params()
         flats = [
