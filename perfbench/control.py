@@ -1,10 +1,10 @@
 """``python3 perfbench/control.py --workload <cell> --seeds 1,2,3 [--scale tiny]``
 
 The control and the planted faults at the cell's own size: the plain
-reference, put in the program's place, in bfloat16 (the precision below the
-float32 the configuration states) and with each fault a one-chip cell can
-have. Host-only numpy: it needs no chip and runs no window, and a benchmark
-run never runs it. One JSON line per seed and stand-in, every number compared
+reference, put in the program's place, in the precision below the one the
+configuration states and with each fault a cell of its kind can have (the
+kind's ``STAND_INS``). It runs no window, and a benchmark run never runs
+it. One JSON line per seed and stand-in, every number compared
 beside its limit; the upper readings in PERF.md come from here.
 """
 
@@ -15,11 +15,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-STAND_INS = [("bfloat16", None), ("float32", "state_unchanged"),
-             ("float32", "half_batch"), ("float32", "answer_altered"),
-             ("float32", "wrong_bucket")]
-TINY = {"hash_space": 1 << 10, "rows": 1 << 15}
-
 
 def main() -> int:
     from perfbench import harness
@@ -29,9 +24,10 @@ def main() -> int:
     ap.add_argument("--seeds", required=True)
     ap.add_argument("--scale", choices=("cell", "tiny"), default="cell")
     args = ap.parse_args()
+    kind = harness.load_cell(args.workload)["kind"]
     for seed in (int(s) for s in args.seeds.split(",")):
-        run = harness.probe_only_run(args.workload, seed, TINY if args.scale == "tiny" else None)
-        for precision, fault in STAND_INS:
+        run = harness.probe_only_run(args.workload, seed, kind.TINY if args.scale == "tiny" else None)
+        for precision, fault in kind.STAND_INS:
             checks = run.control(precision, fault)
             print(json.dumps({
                 "workload": args.workload, "seed": seed, "precision": precision, "fault": fault,

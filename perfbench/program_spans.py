@@ -5,12 +5,13 @@ record (name, start, end, thread, parent, key, self time, attributes) in a
 process-wide recorder, ``omldm_tpu.utils.tracing.RECORDER``, on
 ``time.perf_counter``: the clock of the harness's own window (``ctx.t0``,
 ``ctx.t1``). The recorder lives in the harness's process and outlives the job,
-so the readers find it after the run. ``trace_reduce.load`` keeps only the
-harness's ``perfbench.*`` spans of the profiler trace, so nothing here reads
-the program's spans from the trace; where a reader needs the device trace
-beside them, the two ends of the window (the start of ``perfbench.window``
-against ``ctx.t0``, the end of ``perfbench.drain`` against ``ctx.t1``) map one
-clock onto the other.
+so the readers find it after the run. The records carry what the profiler
+trace's ``omldm.*`` spans do not (parent, key, counters, self time), so the
+readers here take the program's spans from the recorder and not from the
+trace (``trace_reduce.load`` keeps those only to name ``breakdown.idle_gaps``);
+where a reader needs the device trace beside them, the two ends of the window
+(the start of ``perfbench.window`` against ``ctx.t0``, the end of
+``perfbench.drain`` against ``ctx.t1``) map one clock onto the other.
 
 Everything returns None where there is nothing sound to read, and the reader
 then returns None: a program without the recorder (the parent of PR 25), a

@@ -1,7 +1,7 @@
 """``python3 perfbench/selfcheck.py``: the benchmark checked on the CPU, no chip.
 
-- the harness end to end, both cells, at hash space 2^10 on the CPU backend:
-  paths, the shape of the last line, ``correct`` true;
+- the harness end to end, every cell, at its kind's tiny scale on the CPU
+  backend: paths, the shape of the last line, ``correct`` true;
 - the byte-count function behind ``sparse_step_hbm_roofline`` on hand-worked
   shapes;
 - the trace reduction on the recorded TPU trace against numbers worked out
@@ -26,7 +26,6 @@ import zlib
 
 import numpy as np
 
-SCALE = {"hash_space": 1 << 10, "rows": 1 << 15, "traffic": {"part_rows": 8192}}
 RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
 
 
@@ -76,16 +75,18 @@ def check_trace_reduction() -> None:
 
 def check_generator_and_reference() -> None:
     from perfbench import generator as gen
+    from perfbench import harness
     from perfbench.reference import pa2
 
     with open(os.path.join(HERE, "configs", "criteo_pa_2e28.json")) as f:
         config = json.load(f)
-    schema = gen.Schema.from_config(config)
+    kind = harness.load_kind(config)
+    schema = kind.Schema.from_config(config)
     big_seed = 2**31 + 1234567
-    rows = gen.draw_rows(gen.rng_for(big_seed, gen.STREAM_PROBE), 3000, schema)
-    again = gen.draw_rows(gen.rng_for(big_seed, gen.STREAM_PROBE), 3000, schema)
+    rows = kind.draw_rows(gen.rng_for(big_seed, gen.STREAM_PROBE), 3000, schema)
+    again = kind.draw_rows(gen.rng_for(big_seed, gen.STREAM_PROBE), 3000, schema)
     need(np.array_equal(rows.cats, again.cats) and np.array_equal(rows.nums, again.nums), "the same seed gives the same rows")
-    lines = gen.render(rows)
+    lines = kind.render(rows)
     for i in (0, 1, 1499, 2999):
         d = json.loads(bytes(lines.span(i, i + 1)))
         ok = (d["numericalFeatures"] == rows.nums[i].tolist()
@@ -115,9 +116,10 @@ def check_harness() -> None:
     with open(os.path.join(os.path.dirname(HERE), "BENCHMARK.json")) as f:
         bench = json.load(f)
     for k, cell in enumerate(w["name"] for w in bench["workloads"]):
+        tiny = harness.load_cell(cell)["kind"].TINY
         for trace in (False, True):
             result = harness.run_cell(cell, 2**31 + 17 + k, 1.5, trace, time.perf_counter(),
-                                      need_chip=False, scale=SCALE)
+                                      need_chip=False, scale=tiny)
             line = json.loads(json.dumps(result))
             need(RESULT_KEYS <= set(line) and list(line)[-1] == "checks", f"{cell} trace={int(trace)}: the line has its keys, checks last")
             need(line["correct"] is True and line["failed"] == 0, f"{cell} trace={int(trace)}: correct, nothing failed")

@@ -3,8 +3,8 @@ run of a cell on the chip, then what the result line's ``breakdown`` cannot
 hold, from the program's own spans (``program_spans.py``):
 
 - the device-idle seconds inside ``perfbench.handover`` by the innermost
-  program span covering each gap, beside the breakdown's own figure for the
-  whole span;
+  program span covering each gap on the producer thread, beside the
+  breakdown's own list for the whole window;
 - count, total, self time and median of every span that starts in the window;
 - the completion lag of the step programs: device end of the k-th step minus
   the host start of its ``fit`` span.
@@ -28,22 +28,12 @@ from perfbench import trace_reduce
 
 def traced_run(workload: str, seed: int, seconds: float, **kwargs):
     """``harness.run_cell`` with the trace on; the result and the run (what
-    the readers are handed as ``ctx``), which ``run_cell`` keeps to itself.
-    The swap of ``harness.Run`` stands only until a ``benchmark`` PR gives
-    the harness a report hook (``PERF.md`` section 7, item 1)."""
-    runs = []
-
-    class KeptRun(harness.Run):
-        def __init__(self, *args):
-            super().__init__(*args)
-            runs.append(self)
-
-    plain, harness.Run = harness.Run, KeptRun
-    try:
-        result = harness.run_cell(workload, seed, seconds, True, T_PROCESS, **kwargs)
-    finally:
-        harness.Run = plain
-    return result, runs[-1]
+    the readers are handed as ``ctx``), which the ``finished`` hook is
+    handed."""
+    kept = []
+    hooks = dict(kwargs.pop("hooks", None) or {}, finished=lambda run, result: kept.append(run))
+    result = harness.run_cell(workload, seed, seconds, True, T_PROCESS, hooks=hooks, **kwargs)
+    return result, kept[-1]
 
 
 def report(ctx, result: dict) -> dict:
@@ -70,7 +60,7 @@ def report(ctx, result: dict) -> dict:
         "step_programs": len(trace_reduce.modules_in(ctx.trace, *ctx.window_ns, ps.STEP_PROGRAM)),
         "handover_idle_s": ps.by_label(gaps) if gaps is not None else None,
         "handover_idle_total_s": sum(s for s, _, _ in gaps) if gaps is not None else None,
-        "handover_idle_breakdown_s": dict(map(tuple, result["breakdown"]["idle_gaps"])).get(ps.HANDOVER_SPAN),
+        "breakdown_idle_gaps_s": dict(map(tuple, result["breakdown"]["idle_gaps"])),
         "spans": dict(sorted(spans.items(), key=lambda kv: -kv[1]["self_s"])),
         "completion_lag_ms": lags and {
             "steps": len(lags), "p50": pct(lags, 50), "p95": pct(lags, 95), "max": max(lags),
@@ -86,7 +76,10 @@ def print_report(out: dict) -> None:
     print("device-idle seconds inside perfbench.handover, by the program span covering each gap:")
     for label, s in (out["handover_idle_s"] or {}).items():
         print(f"  {label:32s} {s:9.4f}")
-    print(f"  {'sum':32s} {out['handover_idle_total_s']}   (breakdown: {out['handover_idle_breakdown_s']})")
+    print(f"  {'sum':32s} {out['handover_idle_total_s']}")
+    print("the result line's breakdown.idle_gaps (whole window, innermost span of either thread):")
+    for label, s in out["breakdown_idle_gaps_s"].items():
+        print(f"  {label:32s} {s:9.4f}")
     print(f"{'span':18s} {'count':>7s} {'total_s':>9s} {'self_s':>9s} {'median_ms':>10s}")
     for name, row in out["spans"].items():
         print(f"{name:18s} {row['count']:7d} {row['total_s']:9.4f} {row['self_s']:9.4f} {row['median_ms']:10.3f}")

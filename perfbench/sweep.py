@@ -38,7 +38,7 @@ def main() -> int:
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     spec = harness.load_cell(args.workload)
     devices = harness.require_chip(spec["chips"])
-    job = bridge = None
+    system = stamps = None
     for fc in forecasts:
         for rate in rates:
             cell = json.loads(json.dumps(spec["cell"]))
@@ -48,19 +48,14 @@ def main() -> int:
             run = harness.Run({**spec, "cell": cell}, args.seed, args.seconds, False)
             run.make_probe()
             run.make_files()
-            if job is None:
-                job, bridge = harness.build_job(run.config, run.stamps)
-                run.drive_probe(job, bridge)  # compiles every shape once
-                run.probe_w = []
-            job.set_sinks(on_prediction=run.stamps)
-            n_before = len(run.stamps.rows)
-            run.window_open_loop(job, bridge)
-            answered = {fid: t for fid, _v, t in run.stamps.rows[n_before:]}
-            lat = []
-            for plan in run.window_plans:
-                for i in (plan.kind == 1).nonzero()[0]:
-                    t = answered.get(int(plan.index[i]), run.t1)
-                    lat.append((t - run.t0 - float(plan.created[i])) * 1e3)
+            if system is None:
+                stamps = run.stamps
+                system = run.kind.build(stamps)
+                run.drive_probe(system)  # compiles every shape once
+            # one job, one sink: this run's answers are those after the mark
+            run.stamps, run.n_probe_answers = stamps, len(stamps.rows)
+            run.window_open_loop(system)
+            lat = run.latencies_ms(stamps.rows)
             late = [max(x, 0.0) * 1e3 for x in run.late_s]
             tail = late[-max(len(late) // 4, 1):]
             poll_ms = float(cell["traffic"]["poll_ms"])

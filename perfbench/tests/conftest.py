@@ -8,5 +8,7 @@ import sys
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))))
 
-SCALE = {"hash_space": 1 << 10, "rows": 1 << 15, "traffic": {"part_rows": 8192}}
+from perfbench import harness  # noqa: E402
+
 CELLS = ("criteo_pa_2e28.train_sat", "criteo_pa_2e28.serve_paced")
+SCALE = harness.load_cell(CELLS[0])["kind"].TINY

@@ -30,9 +30,10 @@ def test_sound_run_is_correct(cell):
     assert list(result)[-1] == "checks"
 
 
-def state_unchanged(job, bridge):
+def state_unchanged(system):
     import jax
 
+    bridge = system.bridge
     real = bridge.trainer._step
 
     def step(state, x, y, mask):
@@ -44,7 +45,8 @@ def state_unchanged(job, bridge):
     bridge.trainer._step = step
 
 
-def half_batch(job, bridge):
+def half_batch(system):
+    bridge = system.bridge
     real = bridge.trainer.step
 
     def step(x, y, mask, valid_count=None):
@@ -55,7 +57,8 @@ def half_batch(job, bridge):
     bridge.trainer.step = step
 
 
-def answer_altered(job, bridge):
+def answer_altered(system):
+    bridge = system.bridge
     real = bridge._emit_prediction
 
     def emit(pred):

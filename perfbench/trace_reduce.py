@@ -8,8 +8,9 @@ trace, and the self-check reduces it to numbers worked out beside it):
   execution, named ``jit_<function>(<fingerprint>)``; line ``XLA Ops`` has the
   operations inside them (nested ones overlap their parents, so busy time is
   the UNION of intervals, never a sum);
-- plane ``/host:CPU``: ``jax.profiler.TraceAnnotation`` spans written by the
-  harness appear under their own names (``perfbench.*``), on the same clock.
+- plane ``/host:CPU``: ``jax.profiler.TraceAnnotation`` spans appear under
+  their own names, on the same clock: the harness's (``perfbench.*``) and,
+  since PR 25, the program's (``omldm.<span>``, ``omldm_tpu/utils/tracing``).
 
 Times are nanoseconds from the start of the trace.
 """
@@ -24,14 +25,15 @@ from typing import Dict, List, Optional, Sequence, Tuple
 Event = Tuple[str, float, float]  # name, start_ns, duration_ns
 
 WINDOW_SPAN = "perfbench.window"
-SPAN_PREFIX = "perfbench."
+SPAN_PREFIX = "perfbench."  # the harness's own spans
+PROGRAM_PREFIX = "omldm."  # the program's, kept beside them to name idle gaps
 
 
 @dataclass
 class Trace:
     ops: Dict[str, List[Event]] = field(default_factory=dict)  # device plane -> XLA Ops
     modules: Dict[str, List[Event]] = field(default_factory=dict)  # -> XLA Modules
-    spans: List[Event] = field(default_factory=list)  # perfbench.* host spans
+    spans: List[Event] = field(default_factory=list)  # perfbench.* and omldm.* host spans
 
     @property
     def devices(self) -> List[str]:
@@ -60,7 +62,7 @@ def load(path: str) -> Trace:
         elif plane.name == "/host:CPU":
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith(SPAN_PREFIX):
+                    if e.name.startswith((SPAN_PREFIX, PROGRAM_PREFIX)):
                         trace.spans.append((e.name, e.start_ns, e.duration_ns))
     for evs in list(trace.ops.values()) + list(trace.modules.values()):
         evs.sort(key=lambda e: e[1])
@@ -74,10 +76,10 @@ def window_of(trace: Trace) -> Tuple[float, float]:
     for name, start, dur in trace.spans:
         if name == WINDOW_SPAN:
             return start, start + dur
-    if not trace.spans:
+    own = [(s, s + d) for name, s, d in trace.spans if name.startswith(SPAN_PREFIX)]
+    if not own:
         raise ValueError("the trace holds no perfbench span to take the window from")
-    return (min(s for _, s, _ in trace.spans),
-            max(s + d for _, s, d in trace.spans))
+    return min(a for a, _ in own), max(b for _, b in own)
 
 
 def _clip(events: Sequence[Event], lo: float, hi: float) -> List[Tuple[float, float]]:
@@ -165,15 +167,30 @@ def idle_gaps(trace: Trace, lo: float, hi: float) -> List[Tuple[float, float]]:
     return gaps
 
 
+def host_activities(trace: Trace, times: Sequence[float]) -> List[str]:
+    """What the host was doing at each of ``times``: the innermost span that
+    covers it, the program's where one does and the harness's otherwise (never
+    the window span itself). Innermost is shortest: spans of one thread nest,
+    and of two threads' spans the shorter one names the narrower thing. (A
+    saturated window has some 10^4 gaps and, with the program's, as many
+    spans: one vector pass a gap, not a Python loop over the spans.)"""
+    import numpy as np
+
+    spans = [e for e in trace.spans if e[0] != WINDOW_SPAN]
+    starts = np.array([e[1] for e in spans], np.float64)
+    durs = np.array([e[2] for e in spans], np.float64)
+    out = []
+    for t in times:
+        covering = np.nonzero((starts <= t) & (t < starts + durs))[0]
+        if len(covering):
+            out.append(spans[covering[np.argmin(durs[covering])]][0])
+        else:
+            out.append("host.outside_harness_spans")
+    return out
+
+
 def host_activity(trace: Trace, t: float) -> str:
-    """What the harness was doing at time ``t``: the innermost of its spans
-    that covers it (the window span itself only when nothing else does)."""
-    best, best_dur = "host.outside_harness_spans", None
-    for name, start, dur in trace.spans:
-        if start <= t < start + dur and name != WINDOW_SPAN:
-            if best_dur is None or dur < best_dur:
-                best, best_dur = name, dur
-    return best
+    return host_activities(trace, [t])[0]
 
 
 def breakdown(trace: Trace, lo: float, hi: float, top: int = 10) -> dict:
@@ -189,8 +206,8 @@ def breakdown(trace: Trace, lo: float, hi: float, top: int = 10) -> dict:
                 key = name.split(" = ")[0][:48] + " " + _opcode(name)
                 ops[key] = ops.get(key, 0.0) + (b - a) / 1e9
     gaps: Dict[str, float] = {}
-    for a, b in idle_gaps(trace, lo, hi):
-        key = host_activity(trace, 0.5 * (a + b))
+    idle = idle_gaps(trace, lo, hi)
+    for (a, b), key in zip(idle, host_activities(trace, [0.5 * (a + b) for a, b in idle])):
         gaps[key] = gaps.get(key, 0.0) + (b - a) / 1e9
     rank = lambda d: [[k, v] for k, v in sorted(d.items(), key=lambda kv: -kv[1])[:top]]
     return {"device_ops": rank(ops), "idle_gaps": rank(gaps)}
