@@ -29,9 +29,9 @@ from omldm_tpu.ops.sparse import (
     append_bias_sparse,
     sparse_matmat,
     sparse_matvec,
-    sparse_scatter_add_auto,
     sparse_scatter_add_outer,
     sparse_sq_norm,
+    sparse_update,
 )
 
 
@@ -48,18 +48,6 @@ class SparseLinear(Learner):
         idx, val = x
         return append_bias_sparse(idx, val, params["w"].shape[0] - 1)
 
-    def _margins(self, params, x):
-        idx, val = self._with_bias(params, x)
-        return sparse_matvec(params["w"], idx, val), (idx, val)
-
-    def _scatter(self, w, idx, coef, val):
-        """Calibrated scatter dispatch; ``dataStructure.scatterImpl`` pins
-        a kernel per pipeline (the config twin of OMLDM_SPARSE_SCATTER —
-        see ops/sparse._resolve_impl for the precedence chain)."""
-        return sparse_scatter_add_auto(
-            w, idx, coef, val, impl=self.ds.get("scatterImpl")
-        )
-
     def update_per_record(self, params, x, y, mask):
         """Exact per-record online pass over a sparse batch (the base-class
         default slices dense rows; COO batches slice per leaf)."""
@@ -75,7 +63,35 @@ class SparseLinear(Learner):
         return params, jnp.sum(losses * mask) / total
 
 
-class SparsePAClassifier(SparseLinear):
+class SparseVectorLinear(SparseLinear):
+    """The learners whose model is ONE weight vector ``w[D+1]``: margins by
+    gather-dot, updates by scatter-add, both through ``ops.sparse``."""
+
+    def _margins(self, params, x):
+        return sparse_matvec(params["w"], *self._with_bias(params, x))
+
+    def _touch(self, params, x):
+        """What an update needs of the weights: the margins, ``val`` with
+        the bias slot, the function that adds ``coef[b] * val[b, k]`` back,
+        and the formulation's counters (``ops.sparse.sparse_update``: one
+        formulation for both halves, from the calibration table;
+        ``dataStructure.scatterImpl`` pins it per pipeline, the config twin
+        of OMLDM_SPARSE_SCATTER)."""
+        idx, val = self._with_bias(params, x)
+        margins, add, counters = sparse_update(
+            params["w"], idx, val, impl=self.ds.get("scatterImpl")
+        )
+        return margins, val, add, counters
+
+    def update(self, params, x, y, mask) -> Tuple[Params, jnp.ndarray]:
+        """``update_counting`` (what each learner below defines: the update
+        plus the counters of the formulation it ran, which the SPMD step
+        carries out beside the loss) without the counters."""
+        params, loss, _ = self.update_counting(params, x, y, mask)
+        return params, loss
+
+
+class SparsePAClassifier(SparseVectorLinear):
     """Passive-Aggressive classifier on sparse inputs (PA / PA-I / PA-II,
     mirroring learners.linear.PAClassifier)."""
 
@@ -83,57 +99,55 @@ class SparsePAClassifier(SparseLinear):
     task = "classification"
 
     def predict(self, params, x):
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         return jnp.where(margins >= 0, 1.0, -1.0)
 
     def loss(self, params, x, y, mask):
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         hinge = jnp.maximum(0.0, 1.0 - sign_labels(y) * margins)
         return masked_mean(hinge, mask)
 
-    def update(self, params, x, y, mask) -> Tuple[Params, jnp.ndarray]:
+    def update_counting(self, params, x, y, mask):
         variant = str(self.hp.get("variant", "PA-I"))
         C = float(self.hp.get("C", 0.01))
-        margins, (idx, val) = self._margins(params, x)
+        margins, val, add, counters = self._touch(params, x)
         ys = sign_labels(y)
         hinge = jnp.maximum(0.0, 1.0 - ys * margins)
         tau = _pa_tau(hinge, sparse_sq_norm(val), variant, C)
         denom = jnp.maximum(jnp.sum(mask), 1.0)
-        coef = tau * ys * mask / denom
-        w = self._scatter(params["w"], idx, coef, val)
-        return {"w": w}, masked_mean(hinge, mask)
+        w = add(params["w"], tau * ys * mask / denom)
+        return {"w": w}, masked_mean(hinge, mask), counters
 
 
-class SparsePARegressor(SparseLinear):
+class SparsePARegressor(SparseVectorLinear):
     """Epsilon-insensitive PA regressor on sparse inputs (RegressorPA)."""
 
     name = "RegressorPA"
     task = "regression"
 
     def predict(self, params, x):
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         return margins
 
     def loss(self, params, x, y, mask):
         eps = float(self.hp.get("epsilon", 0.1))
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         return masked_mean(jnp.maximum(0.0, jnp.abs(margins - y) - eps), mask)
 
-    def update(self, params, x, y, mask) -> Tuple[Params, jnp.ndarray]:
+    def update_counting(self, params, x, y, mask):
         variant = str(self.hp.get("variant", "PA-I"))
         C = float(self.hp.get("C", 0.01))
         eps = float(self.hp.get("epsilon", 0.1))
-        margins, (idx, val) = self._margins(params, x)
+        margins, val, add, counters = self._touch(params, x)
         err = margins - y
         l = jnp.maximum(0.0, jnp.abs(err) - eps)
         tau = _pa_tau(l, sparse_sq_norm(val), variant, C)
         denom = jnp.maximum(jnp.sum(mask), 1.0)
-        coef = -jnp.sign(err) * tau * mask / denom
-        w = self._scatter(params["w"], idx, coef, val)
-        return {"w": w}, masked_mean(l, mask)
+        w = add(params["w"], -jnp.sign(err) * tau * mask / denom)
+        return {"w": w}, masked_mean(l, mask), counters
 
 
-class SparseSVM(SparseLinear):
+class SparseSVM(SparseVectorLinear):
     """Pegasos SVM on raw sparse features (the dense twin lifts through RFF;
     random Fourier features densify by construction, so the sparse variant
     is the standard linear pegasos on the hashed space)."""
@@ -149,29 +163,29 @@ class SparseSVM(SparseLinear):
         }
 
     def predict(self, params, x):
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         return jnp.where(margins >= 0, 1.0, -1.0)
 
     def loss(self, params, x, y, mask):
-        margins, _ = self._margins(params, x)
+        margins = self._margins(params, x)
         hinge = jnp.maximum(0.0, 1.0 - sign_labels(y) * margins)
         return masked_mean(hinge, mask)
 
-    def update(self, params, x, y, mask) -> Tuple[Params, jnp.ndarray]:
+    def update_counting(self, params, x, y, mask):
         """Mini-batch pegasos: eta = 1/(lambda*t); w <- (1-eta*lambda)w +
         eta * mean_violators(y x). The decay is the only O(D) op."""
         lam = float(self.hp.get("lambda", 1e-4))
-        margins, (idx, val) = self._margins(params, x)
+        margins, _, add, counters = self._touch(params, x)
         ys = sign_labels(y)
         hinge = jnp.maximum(0.0, 1.0 - ys * margins)
         viol = (hinge > 0).astype(jnp.float32) * mask
         eta = 1.0 / (lam * params["t"])
         denom = jnp.maximum(jnp.sum(mask), 1.0)
-        w = params["w"] * (1.0 - eta * lam)
-        w = self._scatter(w, idx, eta * ys * viol / denom, val)
+        w = add(params["w"] * (1.0 - eta * lam), eta * ys * viol / denom)
         return (
             {"w": w, "t": params["t"] + 1.0},
             masked_mean(hinge, mask),
+            counters,
         )
 
 

@@ -12,13 +12,15 @@ TPU-first layout: a batch is ``(idx[B, K] int32, val[B, K] float32)`` with
 FIXED K (max nnz per record, padded with idx=0/val=0 — a zero value
 contributes nothing to either the gather-dot or the scatter-add, so pad
 slots are harmless without sentinel bookkeeping). Static shapes keep XLA
-happy; gathers/scatters lower to efficient dynamic-(update-)slice loops on
-TPU and the surrounding elementwise work fuses.
+happy and the surrounding elementwise work fuses; at the weight vector the
+TPU pays by the slot (gather) and by the distinct address or a pass over
+the whole operand (scatter), which is what the index plan below is for.
 """
 
 from __future__ import annotations
 
 import os
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -112,131 +114,279 @@ def sparse_scatter_add_mxu(
     return w + (flat[:d] if r * c != d else flat)
 
 
-def sparse_scatter_add_segsum(
-    w: jnp.ndarray, idx: jnp.ndarray, coef: jnp.ndarray, val: jnp.ndarray
-) -> jnp.ndarray:
-    """The SAME scatter-add with duplicate indices PRE-COMBINED by a sort +
-    segmented sum before the scatter touches ``w``.
+class IndexPlan(NamedTuple):
+    """What one launch knows about its indices: built once by
+    :func:`index_plan`, shared by the margin's gather and the update's
+    scatter, so that the weight vector is addressed once per DISTINCT index.
+    ``n`` slots hold ``U`` distinct addresses."""
 
-    Hashed categorical batches are duplicate-heavy: popular category values
-    repeat across most records of a batch, so the B*K raw updates collapse
-    onto far fewer distinct rows. XLA's TPU scatter serializes per update
-    row; this formulation moves the duplicate work into a bitonic sort and
-    a segment sum (both fully vectorized on TPU), leaving the scatter with
-    one combined update per distinct index and inert (idx 0, val 0) pads
-    for the rest — the module's standard padding convention.
+    idx: jnp.ndarray          # the indices as given
+    sidx: jnp.ndarray         # [n] the addresses, ascending
+    perm: jnp.ndarray         # [n] slot (row-major in idx) at each sorted place
+    is_start: jnp.ndarray     # [n] bool: first of its run of equal addresses
+    distinct: jnp.ndarray     # [] int32, U
+    overflow: jnp.ndarray     # [] bool, U > plan_capacity(n)
 
-    Shapes stay static: with R <= n distinct indices, run totals land
-    compactly in the first R slots of an [n] array via sorted segment ids,
-    and slots >= R scatter a zero onto row 0. Numerics: per-row totals are
-    plain f32 sums of the row's updates (no prefix-difference
-    cancellation); only the accumulation ORDER differs from the direct
-    scatter, the same 2e-5 envelope as the MXU twin
-    (tests/test_sparse.py).
 
-    Reference counterpart: SparseVector updates applied element-by-element
-    on the JVM (DataPointParser.scala:4,20-47); this is the dedup-first
-    TPU-native form.
-    """
-    n = idx.size
-    flat_idx = idx.reshape(n)
-    u = (coef[:, None] * val).reshape(n).astype(jnp.float32)
-    si, su = jax.lax.sort_key_val(flat_idx, u)
-    is_start = jnp.concatenate(
-        [jnp.ones((1,), bool), si[1:] != si[:-1]]
+def plan_capacity(n: int) -> int:
+    """Distinct addresses up to which a launch of ``n`` slots goes through
+    the plan: a quarter of its slots. Hashed click logs repeat (the
+    numerics, the bias and the small vocabularies in every row, Zipf on the
+    large ones): the benchmark's launches hold 0.19 n distinct addresses
+    (PERF.md section 6, PR 30), so a quarter leaves a third of headroom and
+    still quarters the slots at the weight vector. A launch with more
+    (overflow) runs the plain pair on its indices as given, behind one
+    ``lax.cond`` in each half: exact, and one sort dearer than the plain
+    pair alone."""
+    return max(n // 4, 1)
+
+
+def plan_fits(d: int, n: int, dtype=jnp.float32) -> bool:
+    """The spare addresses ``d + j`` have to stay int32, and the copy down
+    a run (:func:`plan_gather`) moves the weights as int32 bits."""
+    return (
+        d + n <= jnp.iinfo(jnp.int32).max and jnp.dtype(dtype).itemsize == 4
     )
-    seg = jnp.cumsum(is_start.astype(jnp.int32)) - 1       # run id, sorted
-    run_total = jax.ops.segment_sum(
-        su, seg, num_segments=n, indices_are_sorted=True
-    )                                                      # [n], first R real
+
+
+def _permute(keys: jnp.ndarray, *payloads):
+    """Sort by keys that are all DISTINCT, payloads riding along: the way
+    the plan moves data (a sort of 167,936 pairs costs 0.17 ms on the chip,
+    a gather or scatter of as many slots 1.2 ms or more). With no ties a
+    stable sort is the same permutation, and asking for one makes the TPU's
+    compiler carry one more operand through the sort (0.235 against
+    0.172 ms; PERF.md section 6, PR 30)."""
+    return jax.lax.sort((keys,) + payloads, num_keys=1, is_stable=False)
+
+
+def index_plan(idx: jnp.ndarray) -> IndexPlan:
+    """Sort the launch's addresses once. ``idx`` holds addresses in
+    ``[0, d)`` (what the vectorizers emit; a pad is address 0)."""
+    n = idx.size
+    sidx, perm = jax.lax.sort(
+        (idx.reshape(n).astype(jnp.int32), jnp.arange(n, dtype=jnp.int32)),
+        num_keys=1,
+    )
+    is_start = jnp.concatenate(
+        [jnp.ones((1,), bool), sidx[1:] != sidx[:-1]]
+    )
+    distinct = jnp.sum(is_start.astype(jnp.int32))
+    return IndexPlan(
+        idx, sidx, perm, is_start, distinct, distinct > plan_capacity(n)
+    )
+
+
+def _compact(keep: jnp.ndarray, sidx: jnp.ndarray, d: int, payload):
+    """The addresses ``sidx[keep]`` to the front, ascending, ``payload``
+    riding along; behind them distinct out-of-range addresses ``d + j``, so
+    that the whole is sorted and unique and the spare ones are inert under
+    ``mode="fill"`` / ``mode="drop"``."""
+    pos = jnp.arange(sidx.shape[0], dtype=jnp.int32)
+    return _permute(jnp.where(keep, sidx, d + pos), payload)
+
+
+def _run_sums(values: jnp.ndarray, is_start: jnp.ndarray) -> jnp.ndarray:
+    """Inclusive sums that restart at every run start (the total of a run
+    stands at its end): log2(n) shifted adds, each one elementwise pass.
+    Plain f32 sums of a run's own values, no prefix differences."""
+    n, step = values.shape[0], 1
+    while step < n:
+        shifted = jnp.concatenate(
+            [jnp.zeros((step,), values.dtype), values[:-step]]
+        )
+        reached = jnp.concatenate([jnp.ones((step,), bool), is_start[:-step]])
+        values = jnp.where(is_start, values, values + shifted)
+        is_start = is_start | reached
+        step *= 2
+    return values
+
+
+def plan_gather(w: jnp.ndarray, plan: IndexPlan):
+    """``jnp.take(w, idx)`` in idx's shape, bit for bit, with ``w`` addressed
+    once per distinct index: a gather at the distinct addresses (``cap``
+    slots), then two sorts and a copy down each run bring every value to
+    its slots. Also returns the sorted place of every slot (the inverse of
+    ``perm``), which the second sort yields for nothing and
+    :func:`plan_scatter_add` needs. An overflowing launch takes
+    ``jnp.take`` itself."""
+    n = plan.sidx.shape[0]
+    cap = plan_capacity(n)
     pos = jnp.arange(n, dtype=jnp.int32)
-    # run start positions compacted to the front (pads sort to the tail)
-    start_pos = jnp.sort(jnp.where(is_start, pos, n))
-    real = start_pos < n
-    run_idx = jnp.where(real, si[jnp.minimum(start_pos, n - 1)], 0)
-    return w.at[run_idx].add(jnp.where(real, run_total, 0.0))
+
+    def combined(w):
+        # upos: the sorted place of each run's start, in uidx's order (the
+        # spare slots hold the other places)
+        uidx, upos = _compact(plan.is_start, plan.sidx, w.shape[0], pos)
+        head = w.at[uidx[:cap]].get(
+            mode="fill", fill_value=0, indices_are_sorted=True,
+            unique_indices=True,
+        )
+        # the copy down a run is a cumulative sum of int32 differences
+        # between consecutive distinct values' BITS, set at the run starts:
+        # integer sums wrap, so every slot gets its value's exact bits (and
+        # a cumsum is one cheap window reduction where a segmented scan is
+        # 2 log2(n) passes)
+        bits = jax.lax.bitcast_convert_type(head, jnp.int32)
+        before = jnp.concatenate([jnp.zeros((1,), jnp.int32), bits[:-1]])
+        diff = jnp.where(pos[:cap] < plan.distinct, bits - before, 0)
+        _, diff_sorted = _permute(
+            upos, jnp.concatenate([diff, jnp.zeros((n - cap,), jnp.int32)])
+        )
+        g_sorted = jax.lax.bitcast_convert_type(
+            jnp.cumsum(diff_sorted), w.dtype
+        )
+        _, g, place = _permute(plan.perm, g_sorted, pos)
+        return g, place
+
+    def plain(w):
+        # no place to hand on: zeros that carry perm's type (inside
+        # shard_map: its varying axes)
+        return jnp.take(w, plan.idx.reshape(n), axis=0), plan.perm * 0
+
+    g, place = jax.lax.cond(plan.overflow, plain, combined, w)
+    return g.reshape(plan.idx.shape), place
+
+
+def plan_scatter_add(
+    w: jnp.ndarray, plan: IndexPlan, upd: jnp.ndarray, place: jnp.ndarray
+) -> jnp.ndarray:
+    """``w.at[idx].add(upd)`` with each address's updates summed first (in
+    sorted order, :func:`_run_sums`) and ``w`` addressed once per distinct
+    index, in place. Only the order in which one address's duplicates are
+    summed differs from the plain scatter, which an overflowing launch
+    takes itself. ``place`` is what :func:`plan_gather` returned beside the
+    values."""
+    n = plan.sidx.shape[0]
+    cap = plan_capacity(n)
+    upd = upd.reshape(n)
+
+    def combined(v):
+        _, upd_sorted = _permute(place, upd)
+        total = _run_sums(upd_sorted, plan.is_start)
+        # a run's total stands at its END
+        is_end = jnp.concatenate([plan.is_start[1:], jnp.ones((1,), bool)])
+        uidx, tot = _compact(is_end, plan.sidx, v.shape[0], total)
+        # told "sorted" only: told "unique" too, the TPU's compiler copies
+        # the whole operand (3.2 ms at 2^28 weights, whatever the number of
+        # updates; PERF.md section 6, PR 30)
+        return v.at[uidx[:cap]].add(
+            tot[:cap], mode="drop", indices_are_sorted=True
+        )
+
+    return jax.lax.cond(
+        plan.overflow,
+        lambda v: v.at[plan.idx.reshape(n)].add(upd),
+        combined,
+        w,
+    )
 
 
 # ---------------------------------------------------------------------------
-# scatter dispatch: calibration table + env/config override
+# dispatch: calibration table + env/config override
 # ---------------------------------------------------------------------------
 
-SCATTER_IMPLS = {
-    "scatter": sparse_scatter_add,
-    "mxu": sparse_scatter_add_mxu,
-    "segsum": sparse_scatter_add_segsum,
-}
+# the update's formulations by name: two scatters beside the plain gather,
+# and the plan, which is both halves (sparse_update)
+_SCATTERS = {"scatter": sparse_scatter_add, "mxu": sparse_scatter_add_mxu}
+IMPLS = (*_SCATTERS, "plan")
+# exact in f32 up to the order of one address's sums: what a calibration
+# may name a winner. ``mxu`` rounds every update's low half to bf16
+# (about 2^-17 relative), so it is timed for the record and reached by
+# explicit config or the env knob only (ROADMAP C4).
+EXACT_IMPLS = ("scatter", "plan")
 
-# env knob: OMLDM_SPARSE_SCATTER = scatter | mxu | segsum | auto ("auto" or
+# env knob: OMLDM_SPARSE_SCATTER = scatter | mxu | plan | auto ("auto" or
 # unset reads the calibration table); config twin: dataStructure
 # {"scatterImpl": "..."} on the sparse learner spec (learners pass impl=).
 _ENV_KNOB = "OMLDM_SPARSE_SCATTER"
 
 
-def _resolve_impl(d: int, n_updates: int, impl=None) -> str:
+def _resolve_impl(d: int, n_updates: int, impl=None, dtype=jnp.float32) -> str:
     """Trace-time dispatch decision, in precedence order: explicit config
     (``impl`` argument, from dataStructure.scatterImpl), the
     OMLDM_SPARSE_SCATTER env var, the persisted calibration table
     (ops/sparse_dispatch.json, nearest (D, updates) grid point for this
     backend), and only then the uncalibrated fallback: ``scatter``.
 
-    The round-5 ``D >= 2^16 -> mxu`` TPU guess is RETIRED: the crossover
-    was never measured, and ops/sparse_dispatch.json has no ``tpu``
-    section. An uncalibrated backend gets the plain scatter, the only
-    formulation with a measured record on every backend we have touched;
-    the first ``python -m omldm_tpu.ops.sparse_calibrate`` run on the chip
-    writes the table section that makes the mxu/segsum formulations
-    eligible there. The physics behind the old guess still stands as a
-    hypothesis (XLA's TPU scatter serializing randomly-indexed updates
-    regardless of D, while the MXU reformulation costs ~2*2*D FLOPs per
-    update; no rate has been measured on the present machine), but a
-    hypothesis is what the calibration table exists to test, not to
-    hardcode. On
-    CPU the committed table measures the plain scatter fastest through
-    D = 2^18 (12-17M updates/s); at D = 2^20 the scatter drops to ~8M as
-    the target array falls out of cache and the segsum pre-combine
-    (~10M, D-independent) wins 3 of 4 grid points; the MXU formulation
-    never wins off-TPU.
+    The table holds what ``python -m omldm_tpu.ops.sparse_calibrate``
+    measured of the whole update (margin and scatter of one formulation
+    together) on that backend, and names a winner among the exact
+    formulations (``EXACT_IMPLS``); anything else it names, and a backend
+    or a table without a section, gets the plain pair, the only
+    formulation with a record everywhere. On the TPU (PR 30's section,
+    measured at the benchmark's shapes) the plan wins at a launch of
+    4096 x 41 slots over 2^28 weights and the plain pair at 256 x 41 and
+    16 x 41; on the CPU the plain pair wins the whole grid (a sort costs
+    more than the scatter there). ``mxu`` is never the default: where it
+    timed fastest (1024-row launches at D <= 2^16 on the TPU, by 4 to
+    21%) the table still names the exact runner-up.
     """
     if impl:
         name = str(impl)
-        if name not in SCATTER_IMPLS:
+        if name not in IMPLS:
             raise ValueError(
                 f"unknown sparse scatter impl {name!r}; "
-                f"expected one of {sorted(SCATTER_IMPLS)} "
+                f"expected one of {sorted(IMPLS)} "
+            )
+    else:
+        name = os.environ.get(_ENV_KNOB, "").strip().lower()
+        if name == "auto":
+            name = ""
+        if name and name not in IMPLS:
+            raise ValueError(
+                f"{_ENV_KNOB}={name!r}: expected {sorted(IMPLS) + ['auto']}"
+            )
+    if name:
+        if name == "plan" and not plan_fits(d, n_updates, dtype):
+            raise ValueError(
+                f"sparse impl 'plan' needs d + updates < 2^31 and 4-byte "
+                f"weights, got {d} + {n_updates} of {jnp.dtype(dtype).name}"
             )
         return name
-    env = os.environ.get(_ENV_KNOB, "").strip().lower()
-    if env and env != "auto":
-        if env not in SCATTER_IMPLS:
-            raise ValueError(
-                f"{_ENV_KNOB}={env!r}: expected "
-                f"{sorted(SCATTER_IMPLS) + ['auto']}"
-            )
-        return env
     from omldm_tpu.ops.sparse_calibrate import lookup_winner
 
     winner = lookup_winner(jax.default_backend(), d, n_updates)
-    if winner is not None:
-        return winner
-    # uncalibrated backend: plain scatter until a real calibration run
-    # writes this backend's table section (the round-5 D>=2^16 mxu guess
-    # is retired — see the docstring)
+    if winner == "plan" and plan_fits(d, n_updates, dtype):
+        return "plan"
+    # an uncalibrated backend, a plan that does not fit, a name that is not
+    # exact: the plain pair
     return "scatter"
 
 
-def sparse_scatter_add_auto(
-    w: jnp.ndarray,
-    idx: jnp.ndarray,
-    coef: jnp.ndarray,
-    val: jnp.ndarray,
-    impl: str = None,
-) -> jnp.ndarray:
-    """Calibrated dispatch (resolved at trace time) between the three
-    scatter formulations; see :func:`_resolve_impl` for the precedence
-    chain and the measured record behind the fallback guess."""
-    name = _resolve_impl(int(w.shape[0]), int(idx.size), impl)
-    return SCATTER_IMPLS[name](w, idx, coef, val)
+def sparse_update(
+    w: jnp.ndarray, idx: jnp.ndarray, val: jnp.ndarray, impl: str = None
+):
+    """Both halves of a linear learner's update in ONE formulation,
+    resolved at trace time (:func:`_resolve_impl`): the margins
+    ``sum_k w[idx[b, k]] * val[b, k]``, a function ``add(w2, coef)`` giving
+    ``w2[idx[b, k]] += coef[b] * val[b, k]`` (``w2`` is ``w`` or a vector
+    derived from it, as pegasos' decayed one), and the launch's counters:
+    ``[distinct addresses, 1 if the launch overflowed, slots]`` (int32)
+    under the plan, ``None`` under the plain pair. The plan's margins are
+    those of :func:`sparse_matvec` over the same gathered bits."""
+    d, n = int(w.shape[0]), int(idx.size)
+    name = _resolve_impl(d, n, impl, w.dtype)
+    if name != "plan":
+        scatter = _SCATTERS[name]
+        return (
+            sparse_matvec(w, idx, val),
+            lambda w2, coef: scatter(w2, idx, coef, val),
+            None,
+        )
+    plan = index_plan(idx)
+    g, place = plan_gather(w, plan)
+    counters = jnp.stack([
+        plan.distinct,
+        plan.overflow.astype(jnp.int32),
+        jnp.full((), n, jnp.int32),
+    ])
+    return (
+        jnp.sum(g * val, axis=1),
+        lambda w2, coef: plan_scatter_add(
+            w2, plan, coef[:, None] * val, place
+        ),
+        counters,
+    )
 
 
 def sparse_scatter_add_outer(

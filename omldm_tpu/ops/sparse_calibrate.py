@@ -1,34 +1,43 @@
-"""Calibration harness for the sparse scatter dispatch.
+"""Calibration harness for the sparse update's dispatch.
 
-`sparse_scatter_add_auto` (ops/sparse.py) has three formulations of the
-same w[idx] += coef*val update with very different cost models:
+A sparse linear learner's update is a gather (the margins) and a scatter-add
+(the update) over the same indices; ``ops.sparse.sparse_update`` has three
+formulations of the pair with very different cost models:
 
-- ``scatter``: XLA's native scatter-add — serializes per update row on TPU
-  but is the natural form everywhere else;
-- ``mxu``: the kron-factored one-hot matmul (ops/sparse.py:52) — trades
-  ~2*2*D FLOPs per update for the serialization, wins only where the chip's
-  matmul rate beats the scatter element rate times D;
-- ``segsum``: sort + segmented pre-combine — collapses duplicate hashed
-  indices before the scatter, wins when the duplicate factor is high enough
-  that the (vectorized) sort costs less than the serialized duplicate adds.
+- ``scatter``: ``jnp.take`` and XLA's native scatter-add, slot by slot: the
+  natural form everywhere, and on the TPU a cost per slot (gather) and per
+  distinct address (scatter) whatever the addresses;
+- ``mxu``: the same gather with the kron-factored one-hot matmul scatter
+  (``sparse_scatter_add_mxu``): ~2*2*D FLOPs per update, fastest only where
+  the chip's matmul rate beats the scatter's element rate times D. It
+  rounds every update's low half to bfloat16, so it is timed for the
+  record and never named a winner: the table decides a default, and a
+  default stays exact in float32;
+- ``plan``: one sort of the launch's indices shared by both halves
+  (``index_plan``): duplicates combined, the weight vector addressed once
+  per distinct index, everything else by sorts and scans. Wins where a
+  launch is large and duplicate-heavy enough that its six sorts cost less
+  than the slots they save at the weight vector; a launch with more
+  distinct addresses than a quarter of its slots runs the plain pair
+  behind the plan's first sort.
 
-Round 5 shipped the mxu dispatch on a GUESSED ``D >= 2^16`` threshold with
-no measured crossover. This module replaces the guess:
-it measures all three kernels over a (D, batch, nnz) grid with a
+This module measures all three over a (D, batch, nnz) grid with a
 hashed-categorical duplicate profile (each COO slot draws from a ~1k-value
-vocabulary, the Criteo/Avazu shape the sparse path exists for), persists
-the per-backend crossover table next to this file
-(``sparse_dispatch.json``), and `sparse_scatter_add_auto` dispatches from
-the table at trace time (nearest grid point in log2 space). Re-run on new
-hardware:
+vocabulary, the Criteo/Avazu shape the sparse path exists for), timing the
+update AS THE LEARNER RUNS IT (margins, a coefficient that depends on
+them, the scatter), persists the per-backend table next to this file
+(``sparse_dispatch.json``), and ``sparse_update`` dispatches from the table
+at trace time (nearest grid point in log2 space). Re-run on new hardware:
 
     python -m omldm_tpu.ops.sparse_calibrate            # full grid
     python -m omldm_tpu.ops.sparse_calibrate --smoke    # CI-sized grid
 
-Writes merge per backend, so a TPU calibration does not clobber the CPU
-section. ``OMLDM_SPARSE_SCATTER_TABLE`` points the lookup (and the writer)
-at an alternate table path; ``OMLDM_SPARSE_SCATTER`` bypasses the table
-entirely (ops/sparse.py).
+Off the CPU the full grid also holds the shapes the benchmark's cells run
+(``CELL_GRID``: 2^28 + 14 weights; a 1 GiB vector is not for a shared CPU
+host). Writes merge per backend, so a TPU calibration does not clobber the
+CPU section. ``OMLDM_SPARSE_SCATTER_TABLE`` points the lookup (and the
+writer) at an alternate table path; ``OMLDM_SPARSE_SCATTER`` bypasses the
+table entirely (ops/sparse.py).
 """
 
 from __future__ import annotations
@@ -112,7 +121,7 @@ def _gen_updates(d: int, batch: int, nnz: int, seed: int = 0):
     """Hashed-categorical update profile: each COO slot draws from its own
     ~1k-value vocabulary inside [0, d) — the duplicate structure of the
     Criteo/Avazu streams (benchmarks/run_benchmarks.py stream gen), which
-    is exactly what the segsum pre-combine exists to exploit."""
+    is exactly what the index plan exists to exploit."""
     rng = np.random.RandomState(seed)
     vocab_n = min(1000, max(d // nnz, 2))
     idx = np.empty((batch, nnz), np.int32)
@@ -124,49 +133,55 @@ def _gen_updates(d: int, batch: int, nnz: int, seed: int = 0):
     return idx, val, coef
 
 
-def _measure_kernel(fn, d: int, idx, val, coef, steps: int,
+def _measure_kernel(name: str, d: int, idx, val, coef, steps: int,
                     repeats: int = 3) -> float:
-    """Updates/sec for one kernel: ``steps`` applications chained in ONE
-    jitted scan (so per-dispatch overhead does not dominate), w donated,
-    best-of-``repeats``."""
+    """Updates/sec of one formulation of the whole update: ``steps``
+    applications chained in ONE jitted scan (so per-dispatch overhead does
+    not dominate), w donated, best-of-``repeats``."""
     import jax
     import jax.numpy as jnp
 
-    @jax.jit
+    from omldm_tpu.ops.sparse import sparse_update
+
     def chain(w, ii, vv, cc):
         def body(ww, _):
-            return fn(ww, ii, cc, vv), None
+            margins, add, _ = sparse_update(ww, ii, vv, impl=name)
+            # the coefficient hangs on the margins, as a learner's does:
+            # the gather cannot be dropped or moved behind the scatter
+            return add(ww, cc - 1e-3 * margins), None
 
         w, _ = jax.lax.scan(body, w, None, length=steps)
         return w
 
+    chain = jax.jit(chain, donate_argnums=0)
     w = jnp.zeros((d,), jnp.float32)
     ii, vv, cc = jnp.asarray(idx), jnp.asarray(val), jnp.asarray(coef)
-    chain(w, ii, vv, cc).block_until_ready()  # compile
+    w = chain(w, ii, vv, cc).block_until_ready()  # compile
     best = float("inf")
     for _ in range(repeats):
         t0 = time.perf_counter()
-        chain(w, ii, vv, cc).block_until_ready()
+        w = chain(w, ii, vv, cc).block_until_ready()
         best = min(best, time.perf_counter() - t0)
     return steps * idx.size / best
 
 
 def measure_entry(d: int, batch: int, nnz: int, steps: int) -> dict:
-    from omldm_tpu.ops.sparse import MXU_LANES, SCATTER_IMPLS
+    from omldm_tpu.ops.sparse import EXACT_IMPLS, IMPLS, MXU_LANES
 
     idx, val, coef = _gen_updates(d, batch, nnz)
     n = idx.size
     rates: Dict[str, Optional[float]] = {}
-    for name, fn in SCATTER_IMPLS.items():
+    for name in IMPLS:
         if name == "mxu":
             r = -(-d // MXU_LANES)
             est = 2 * (2 * n) * (r + MXU_LANES)  # bf16 one-hot operands
             if est > MXU_BYTES_CAP:
                 rates[name] = None
                 continue
-        rates[name] = round(_measure_kernel(fn, d, idx, val, coef, steps), 1)
-    measured = {k: v for k, v in rates.items() if v is not None}
-    winner = max(measured, key=measured.get)  # type: ignore[arg-type]
+        rates[name] = round(_measure_kernel(name, d, idx, val, coef, steps), 1)
+    # the winner is what sparse_update runs by DEFAULT there, so it is
+    # chosen among the exact formulations; mxu's rate is for the record
+    winner = max(EXACT_IMPLS, key=rates.__getitem__)
     dup = n / max(len(np.unique(idx)), 1)
     return {
         "d": d,
@@ -187,6 +202,10 @@ FULL_GRID = [
 ]
 # CI-sized: covers both sides of the guessed 2^16 crossover in seconds
 SMOKE_GRID = [(1 << 12, 256, 8), (1 << 16, 256, 8), (1 << 18, 256, 8)]
+# what the benchmark's cells run (BENCHMARK.json, criteo_pa_2e28): 2^28 + 14
+# weights; a launch of 4096 rows, a tail step of 256, a forecast's padded
+# batch of 16, each of maxNnz 40 plus the bias slot
+CELL_GRID = [((1 << 28) + 14, batch, 41) for batch in (4096, 256, 16)]
 
 
 def calibrate(grid: List[tuple], steps: int, out: Optional[str] = None,
@@ -206,16 +225,13 @@ def calibrate(grid: List[tuple], steps: int, out: Optional[str] = None,
             f"{e['rates_updates_per_sec']}"
         )
     out = out or table_path()
-    table = load_table(out) or {
-        "version": 1,
-        "note": (
-            "sparse scatter dispatch crossover table — generated by "
-            "python -m omldm_tpu.ops.sparse_calibrate; "
-            "sparse_scatter_add_auto (ops/sparse.py) reads the nearest "
-            "(d, updates) entry for the active backend at trace time"
-        ),
-        "backends": {},
-    }
+    table = load_table(out) or {"version": 1, "backends": {}}
+    table["note"] = (
+        "sparse update dispatch crossover table — generated by "
+        "python -m omldm_tpu.ops.sparse_calibrate; "
+        "sparse_update (ops/sparse.py) reads the nearest "
+        "(d, updates) entry for the active backend at trace time"
+    )
     table["backends"][backend] = {
         "generated_by": (
             f"python -m omldm_tpu.ops.sparse_calibrate {tag}".strip()
@@ -245,7 +261,11 @@ def main(argv=None) -> None:
     ap.add_argument("--steps", type=int, default=None,
                     help="chained kernel applications per timing sample")
     args = ap.parse_args(argv)
+    import jax
+
     grid = SMOKE_GRID if args.smoke else FULL_GRID
+    if not args.smoke and jax.default_backend() != "cpu":
+        grid = grid + CELL_GRID
     steps = args.steps or (4 if args.smoke else 16)
     calibrate(grid, steps, out=args.out,
               tag="--smoke" if args.smoke else "")

@@ -272,6 +272,9 @@ class SPMDTrainer:
         self._fitted_host = 0
         self._steps_host = 0
         self._curve: List[Tuple[Any, int]] = []
+        # lazy [.., dp, hub, 3] counters of the launches whose update
+        # counted (see step_fn), until plan_counts() reads them
+        self._counted: List[Any] = []
 
     # --- state construction ---
 
@@ -425,8 +428,21 @@ class SPMDTrainer:
                 new_preps.append(s)
                 z = prep.transform(s, z)
 
-            update = learner.update_per_record if per_record else learner.update
-            params, loss = update(params, z, y, mask)
+            # a learner that counts what its update did (the sparse index
+            # plan: ops.sparse.sparse_update) hands the launch's counters
+            # out beside the loss: one more small output, read where the
+            # losses are (plan_counts)
+            counting = None if per_record else getattr(
+                learner, "update_counting", None
+            )
+            counters = None
+            if counting is not None:
+                params, loss, counters = counting(params, z, y, mask)
+            else:
+                update = (
+                    learner.update_per_record if per_record else learner.update
+                )
+                params, loss = update(params, z, y, mask)
 
             flat = self._flat(params)
             step_i = step_i + 1
@@ -619,7 +635,8 @@ class SPMDTrainer:
             }
             if qdq is not None:
                 new_state["ef"] = shard_block(ef)
-            return new_state, shard_block(loss)
+            counted = () if counters is None else (counters[None, None],)
+            return new_state, (shard_block(loss), counted)
 
         return step_fn
 
@@ -631,7 +648,8 @@ class SPMDTrainer:
         valid rows) when ``mask`` is device-resident — otherwise the
         counting ``np.asarray(mask)`` forces a device->host copy."""
         n = int(valid_count) if valid_count is not None else int(np.asarray(mask).sum())
-        self.state, loss = self._step(self.state, x, y, mask)
+        self.state, (loss, counted) = self._step(self.state, x, y, mask)
+        self._counted += counted
         self._fitted_host += n
         self._steps_host += 1
         self._curve.append((loss, self._fitted_host))
@@ -667,7 +685,10 @@ class SPMDTrainer:
                 ),
             )
         counts = batch_valid_counts(masks, valid_counts)
-        self.state, losses = self._step_many(self.state, xs, ys, masks)
+        self.state, (losses, counted) = self._step_many(
+            self.state, xs, ys, masks
+        )
+        self._counted += counted
         fitted_after = []
         for c in counts:
             self._fitted_host += c
@@ -708,7 +729,10 @@ class SPMDTrainer:
                 ),
             )
         t, dp, b = xs.shape[0], xs.shape[1], xs.shape[2]
-        self.state, losses = self._step_many_dense(self.state, xs, ys)
+        self.state, (losses, counted) = self._step_many_dense(
+            self.state, xs, ys
+        )
+        self._counted += counted
         fitted_after = []
         for _ in range(t):
             self._fitted_host += dp * b
@@ -781,6 +805,26 @@ class SPMDTrainer:
             else:
                 out.append((float(np.asarray(losses).mean()), int(fitted)))
         return out
+
+    def plan_counts(self) -> Dict[str, int]:
+        """What the launches since the last call report of their sparse
+        index plan (``ops.sparse.sparse_update``), summed over launches and
+        workers: ``slots``, the ``slots_distinct`` addresses among them and
+        the ``overflow_launches`` that held more distinct addresses than
+        the plan's capacity and ran the plain pair. Empty where no launch ran the
+        plan. Waits for the device, like :meth:`curve_slice`."""
+        fresh, self._counted = self._counted, []
+        if not fresh:
+            return {}
+        # hub replicas of a worker agree: take hub shard 0
+        per = np.concatenate(
+            [np.asarray(c).reshape(-1, self.hub, 3)[:, 0] for c in fresh]
+        ).astype(np.int64)
+        return {
+            "slots": int(per[:, 2].sum()),
+            "slots_distinct": int(per[:, 0].sum()),
+            "overflow_launches": int(per[:, 1].sum()),
+        }
 
     def sync_count(self) -> int:
         """Total parameter synchronizations executed (summed over workers for

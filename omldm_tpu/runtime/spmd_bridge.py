@@ -1005,6 +1005,9 @@ class SPMDBridge:
         """Protocol statistics with the collective-call-site accounting
         (bytesShipped parity, FlinkHub.scala:118-127)."""
         curve = self.trainer.curve_slice()
+        # the launches' device counters, read here because the curve's
+        # losses just waited for the same launches
+        tracing.RECORDER.add_counts("fit", **self.trainer.plan_counts())
         _, score = self._evaluate()
         return Statistics(
             pipeline=self.request.id,

@@ -353,8 +353,9 @@ class PhaseProfile:
         Seconds are self time, so nested phases count once (``compile``
         is no span: its seconds are also in the self time of the span that
         called the program). A phase with counters carries them in its
-        row (``rows``, ``rows_padded``). Phases noted from more than one
-        thread (the fused route's producer and dispatch threads) overlap
+        row (``rows``, ``rows_padded``; ``fit`` also ``overflow_launches``
+        and ``distinct_share`` where launches ran the sparse index plan).
+        Phases noted from more than one thread (the fused route's producer and dispatch threads) overlap
         in wall time. ``also`` adds another profile's phases, row by row;
         ``extra`` folds in phase totals tracked elsewhere (StepTimer
         total_ms, codec seconds) as {phase: seconds} without sample
@@ -386,6 +387,12 @@ class PhaseProfile:
                 "p99_ms": round(float(p99) * 1000.0, 4),
                 **counts,
             }
+            if counts.get("slots"):
+                # the sparse index plan's launches: distinct addresses over
+                # slots (ops.sparse; 1.0 = nothing to combine)
+                out[name]["distinct_share"] = round(
+                    counts["slots_distinct"] / counts["slots"], 4
+                )
             total += seconds
         for name, secs in (extra or {}).items():
             row = out.setdefault(

@@ -16,7 +16,8 @@ observability is CountableSerial byte accounting). Four things live here:
   the recorder keeps exact ``count``/``total`` and a bounded ring of the
   newest records; ``**counts`` add to the name's counters at the same
   boundary (``rows``, ``rows_padded``: the phase table prints them beside
-  the seconds). :data:`RECORDER` is the process-wide recorder ``span``
+  the seconds); :meth:`Recorder.add_counts` adds what is known only later
+  (a launch's device counters). :data:`RECORDER` is the process-wide recorder ``span``
   writes to; it is always on (two clock reads, one inactive annotation and
   one ring slot a span -- measured in ``PERF.md``).
   ``runtime.telemetry.PhaseProfile`` is the table over a recorder, so the
@@ -256,6 +257,16 @@ class Recorder:
             stats = self._stats_for(name)
             stats.ring.note(seconds)
             stats.total += seconds
+
+    def add_counts(self, name: str, **counts) -> None:
+        """Add to the counters of ``name`` outside any span of it: what a
+        launch counted on the device is known only where its outputs are
+        read (``slots``, ``slots_distinct``, ``overflow_launches`` of
+        ``fit``)."""
+        with self._lock:
+            into = self._stats_for(name).counts
+            for k, v in counts.items():
+                into[k] = into.get(k, 0) + v
 
     def _stats_for(self, name: str) -> _SpanStats:
         stats = self._stats.get(name)

@@ -34,6 +34,60 @@ def dense_to_coo(x: np.ndarray, k: int):
     return idx, val
 
 
+def _dup_heavy(d, vocab, seed=11):
+    """Every slot draws from a tiny vocabulary; pad slots (idx 0, val 0) and
+    a whole-record duplicate on top."""
+    def make():
+        rng = np.random.RandomState(seed)
+        b, k = 32, 9
+        idx = rng.choice(
+            rng.randint(0, d, size=vocab), size=(b, k)
+        ).astype(np.int32)
+        idx[:, -2:] = 0
+        val = rng.randn(b, k).astype(np.float32)
+        val[:, -2:] = 0.0
+        idx[3] = idx[2]
+        return d, idx, val
+    return make
+
+
+def _with_distinct(n_distinct, b=8, k=5, d=1000):
+    """[b, k] slots over exactly ``n_distinct`` addresses."""
+    def make():
+        rng = np.random.RandomState(n_distinct)
+        addr = rng.choice(d, size=n_distinct, replace=False)
+        flat = np.concatenate(
+            [addr, rng.choice(addr, size=b * k - n_distinct)]
+        )
+        idx = rng.permutation(flat).reshape(b, k).astype(np.int32)
+        return d, idx, rng.randn(b, k).astype(np.float32)
+    return make
+
+
+def _bias_in_every_row():
+    d, idx, val = _dup_heavy(4096, 50, seed=3)()
+    idx[:, 0] = d - 1
+    val[:, 0] = 1.0
+    return d, idx, val
+
+
+# 8 x 5 = 40 slots: the plan's capacity is a quarter of them, 10
+_PLAN_CASES = {
+    "dup_heavy_d37_vocab5": _dup_heavy(37, 5),
+    "dup_heavy_d4096_vocab3": _dup_heavy(4096, 3),
+    "dup_heavy_d4096_vocab500": _dup_heavy(4096, 500),
+    "dup_heavy_d32768_vocab7": _dup_heavy(1 << 15, 7),
+    "all_duplicate": _with_distinct(1),
+    "all_distinct": _with_distinct(40),
+    "u_eq_cap": _with_distinct(10),
+    "u_eq_cap_plus_1": _with_distinct(11),
+    "pads_only": lambda: (
+        64, np.zeros((8, 5), np.int32), np.zeros((8, 5), np.float32)
+    ),
+    "bias_address": _bias_in_every_row,
+}
+
+
 class TestSparseOps:
     def test_matvec_matches_dense(self):
         rng = np.random.RandomState(0)
@@ -97,13 +151,11 @@ class TestSparseOps:
                 err_msg=f"mxu scatter diverged at D={d}",
             )
 
-    def test_auto_dispatch_matches_scatter_under_jit(self):
-        """sparse_scatter_add_auto resolves at trace time and must be
-        jittable; with the explicit scatter impl pinned it is the plain
-        scatter bit-for-bit."""
-        import jax
-
-        from omldm_tpu.ops.sparse import sparse_scatter_add_auto
+    def test_update_dispatch_matches_plain_pair_under_jit(self):
+        """sparse_update resolves at trace time and must be jittable; with
+        the explicit scatter impl pinned it is the plain pair bit-for-bit
+        and counts nothing."""
+        from omldm_tpu.ops.sparse import sparse_update
 
         rng = np.random.RandomState(8)
         d, b, k = 300, 8, 5
@@ -111,52 +163,79 @@ class TestSparseOps:
         idx = rng.randint(0, d, size=(b, k)).astype(np.int32)
         val = rng.randn(b, k).astype(np.float32)
         coef = rng.randn(b).astype(np.float32)
-        out = jax.jit(
-            lambda *a: sparse_scatter_add_auto(*a, impl="scatter")
-        )(
-            jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-            jnp.asarray(val),
-        )
-        ref = sparse_scatter_add(
-            jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-            jnp.asarray(val),
-        )
-        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    def test_segsum_scatter_matches_xla_scatter(self):
-        """The sort + segmented pre-combine reformulation
-        (sparse_scatter_add_segsum) is the same scatter-add up to f32
-        accumulation order (per-run totals are exact segment sums, no
-        prefix-difference cancellation). Covers DUPLICATE-HEAVY index
-        streams — the hashed-categorical case the pre-combine exists for —
-        plus pad slots and whole-record duplicates."""
-        from omldm_tpu.ops.sparse import sparse_scatter_add_segsum
+        def update(w, idx, val, coef):
+            margins, add, counters = sparse_update(w, idx, val, impl="scatter")
+            assert counters is None
+            return margins, add(w, coef)
 
-        rng = np.random.RandomState(11)
-        for d, vocab in ((37, 5), (4096, 3), (4096, 500), (1 << 15, 7)):
-            b, k = 32, 9
-            w = rng.randn(d).astype(np.float32)
-            # duplicate-heavy: every slot draws from a tiny vocabulary
-            idx = rng.choice(
-                rng.randint(0, d, size=vocab), size=(b, k)
-            ).astype(np.int32)
-            idx[:, -2:] = 0  # pad slots (val 0)
-            val = rng.randn(b, k).astype(np.float32)
-            val[:, -2:] = 0.0
-            idx[3] = idx[2]  # whole-record duplicate pattern
-            coef = rng.randn(b).astype(np.float32)
-            ref = sparse_scatter_add(
-                jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-                jnp.asarray(val),
-            )
-            out = sparse_scatter_add_segsum(
-                jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-                jnp.asarray(val),
-            )
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-                err_msg=f"segsum scatter diverged at D={d} vocab={vocab}",
-            )
+        args = [jnp.asarray(a) for a in (w, idx, val, coef)]
+        margins, out = jax.jit(update)(*args)
+        np.testing.assert_array_equal(
+            np.asarray(margins), np.asarray(jax.jit(sparse_matvec)(*args[:3]))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(out),
+            np.asarray(sparse_scatter_add(args[0], args[1], args[3], args[2])),
+        )
+
+    @pytest.mark.parametrize("case", sorted(_PLAN_CASES))
+    def test_plan_matches_plain_pair(self, case):
+        """The index plan (one sort of the launch's indices, duplicates
+        combined, w addressed once per distinct index) against the plain
+        pair: gathered weights bit for bit, weights up to the f32 order in which one
+        address's duplicates are summed (per-run totals are plain sums of
+        the run's own updates, no prefix differences). The first four cases
+        are the duplicate-heavy streams the pre-combine exists for, with
+        pad slots and whole-record duplicates; the others its edges: one
+        address, none repeated, exactly the plan's capacity, one more (an
+        overflowing launch runs the plain pair itself, so its weights are
+        the plain scatter's bit for bit), nothing but pads, the bias
+        address in every row."""
+        from omldm_tpu.ops.sparse import (
+            index_plan, plan_capacity, plan_gather, sparse_update,
+        )
+
+        d, idx, val = _PLAN_CASES[case]()
+        b = idx.shape[0]
+        rng = np.random.RandomState(5)
+        w = rng.randn(d).astype(np.float32)
+        coef = rng.randn(b).astype(np.float32)
+        args = [jnp.asarray(a) for a in (w, idx, val, coef)]
+
+        def update(w, idx, val, coef):
+            margins, add, counters = sparse_update(w, idx, val, impl="plan")
+            return margins, add(w, coef), counters
+
+        margins, out, counters = jax.jit(update)(*args)
+        # what the plan hands the margin is w[idx] bit for bit, and the
+        # margin is the same sum(g * val, axis=1) over it; once the gather
+        # no longer sits inside that reduction's fusion the CPU's compiler
+        # may contract it differently, so the sums agree to the last bits
+        g, _ = jax.jit(lambda w, i: plan_gather(w, index_plan(i)))(*args[:2])
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(jnp.take(args[0], args[1], axis=0))
+        )
+        np.testing.assert_allclose(
+            np.asarray(margins), np.asarray(sparse_matvec(*args[:3])),
+            rtol=1e-6, atol=1e-6,
+        )
+        ref = sparse_scatter_add(args[0], args[1], args[3], args[2])
+        np.testing.assert_allclose(
+            np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
+            err_msg=f"plan diverged from the plain scatter: {case}",
+        )
+        distinct = len(np.unique(idx))
+        cap = plan_capacity(idx.size)
+        assert list(np.asarray(counters)) == [
+            distinct, int(distinct > cap), idx.size
+        ]
+        if case == "u_eq_cap":
+            assert distinct == cap
+        if case == "u_eq_cap_plus_1":
+            assert distinct == cap + 1
+        if distinct > cap:
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
     def test_dispatch_precedence_env_and_config(self, monkeypatch):
         """_resolve_impl precedence: explicit config impl > env knob >
@@ -164,7 +243,7 @@ class TestSparseOps:
         from omldm_tpu.ops import sparse as sp
 
         monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
-        assert sp._resolve_impl(300, 40, impl="segsum") == "segsum"
+        assert sp._resolve_impl(300, 40, impl="plan") == "plan"
         monkeypatch.setenv("OMLDM_SPARSE_SCATTER", "mxu")
         assert sp._resolve_impl(300, 40) == "mxu"
         assert sp._resolve_impl(300, 40, impl="scatter") == "scatter"
@@ -173,6 +252,105 @@ class TestSparseOps:
             sp._resolve_impl(300, 40)
         with pytest.raises(ValueError, match="unknown sparse scatter"):
             sp._resolve_impl(300, 40, impl="bogus")
+        # the plan's spare addresses d + j must stay int32: pinned where
+        # they cannot, it says so
+        with pytest.raises(ValueError, match="2\\^31"):
+            sp._resolve_impl(2 ** 31 - 8, 40, impl="plan")
+        # nor can it move weights that are not 4 bytes wide as int32 bits
+        with pytest.raises(ValueError, match="4-byte"):
+            sp._resolve_impl(300, 40, impl="plan", dtype=jnp.bfloat16)
+
+
+class TestPlanInTheSPMDStep:
+    """The index plan inside the collective engine's step program."""
+
+    def _trainer(self, impl, dp, hub, d, batch, nnz):
+        from omldm_tpu.api.requests import TrainingConfiguration
+        from omldm_tpu.parallel.mesh import make_mesh
+        from omldm_tpu.parallel.spmd import SPMDTrainer
+
+        spec = LearnerSpec(
+            "PA", hyper_parameters={"C": 0.1, "variant": "PA-II"},
+            data_structure={
+                "sparse": True, "nFeatures": d - 1, "maxNnz": nnz,
+                "scatterImpl": impl,
+            },
+        )
+        return SPMDTrainer(
+            spec, dim=d - 1, protocol="Synchronous",
+            mesh=make_mesh(dp=dp, hub=hub), batch_size=batch,
+            training_configuration=TrainingConfiguration(
+                protocol="Synchronous", extra={"syncEvery": 2}
+            ),
+        )
+
+    def test_plan_step_under_shard_map_2x2(self):
+        """dp x hub = 2 x 2: each worker plans its own batch inside
+        ``shard_map``; the fleet's model stays in the plain pair's envelope
+        and every launch hands its counters out."""
+        d, batch, nnz, steps = 4096 + 14, 32, 8, 5
+        rng = np.random.RandomState(2)
+        vocab = rng.randint(0, d - 1, size=40)
+        batches = [
+            (
+                (
+                    rng.choice(vocab, size=(2, batch, nnz)).astype(np.int32),
+                    rng.randn(2, batch, nnz).astype(np.float32),
+                ),
+                (rng.rand(2, batch) > 0.5).astype(np.float32),
+                np.ones((2, batch), np.float32),
+            )
+            for _ in range(steps)
+        ]
+        flats = {}
+        for impl in ("scatter", "plan"):
+            tr = self._trainer(impl, 2, 2, d, batch, nnz)
+            for x, y, m in batches:
+                tr.step(x, y, m)
+            flats[impl] = np.asarray(tr.global_flat_params())
+            counts = tr.plan_counts()
+            if impl == "scatter":
+                assert counts == {}
+                continue
+            n = batch * (nnz + 1)
+            assert counts["slots"] == steps * 2 * n
+            assert counts["slots_distinct"] == sum(
+                len(np.unique(np.append(x[0][w], d - 1)))
+                for x, _, _ in batches for w in range(2)
+            )
+            # 41 addresses at most against a capacity of 72
+            assert counts["overflow_launches"] == 0
+            assert tr.plan_counts() == {}  # read once
+        assert np.abs(flats["scatter"]).max() > 0
+        np.testing.assert_allclose(
+            flats["plan"], flats["scatter"], rtol=2e-5, atol=2e-5
+        )
+
+    def test_donated_plan_step_leaves_no_second_copy_of_w(self):
+        """The compiled step (this backend's compiler; ``chip_smoke.py``'s
+        ``stream_sparse`` leg reads the chip's, ``tests/test_tpu_compile.py``
+        the TPU compiler's at 2^28 + 14 weights): the vector leaves are
+        donated, and outside the sync branch and an overflowing launch's
+        plain pair nothing is as wide as the model but the in-place scatter
+        and, on the CPU alone, one copy right before it: the CPU's compiler
+        copies the operand of a conditional whose branches both scatter into
+        it, where the TPU's updates it in place."""
+        import chip_smoke
+
+        d, batch, nnz = 2 ** 16 + 14, 64, 8
+        tr = self._trainer("plan", 1, 1, d, batch, nnz)
+        idx = np.zeros((1, batch, nnz), np.int32)
+        val = np.zeros((1, batch, nnz), np.float32)
+        y = np.zeros((1, batch), np.float32)
+        text = tr._step.lower(tr.state, (idx, val), y, y).compile().as_text()
+        passes = chip_smoke.hlo_wide_passes(text, d)
+        in_branch = [p for p in passes if p[2] == "copy" and p[0] != "ENTRY"]
+        assert [p for p in passes if p not in in_branch] == []
+        assert len(in_branch) <= (jax.default_backend() == "cpu")
+        n_vector_leaves = sum(
+            leaf.ndim == 1 for leaf in jax.tree_util.tree_leaves(tr.state)
+        )
+        assert len(chip_smoke.hlo_aliased_parameters(text)) >= n_vector_leaves
 
 
 class TestSparseLearnerTwinEquality:

@@ -1,7 +1,7 @@
-"""Sparse scatter calibration: table format, lookup, dispatch wiring.
+"""Sparse update calibration: table format, lookup, dispatch wiring.
 
 The crossover table (ops/sparse_dispatch.json) replaces round 5's guessed
-``D >= 2^16`` TPU threshold: `sparse_scatter_add_auto` resolves its kernel
+``D >= 2^16`` TPU threshold: `sparse_update` resolves its formulation
 from the nearest measured (D, updates) grid point for the active backend.
 These tests pin the table format the CI smoke run
 (``python -m omldm_tpu.ops.sparse_calibrate --smoke``) regenerates, the
@@ -10,11 +10,17 @@ precedence (env/config overrides beat the table)."""
 
 import json
 
+import jax
 import numpy as np
 import pytest
 
 from omldm_tpu.ops import sparse_calibrate as cal
-from omldm_tpu.ops.sparse import SCATTER_IMPLS, _resolve_impl
+from omldm_tpu.ops.sparse import EXACT_IMPLS, IMPLS, _resolve_impl
+
+
+# the chip's verdict at the cells' shapes (rows of a launch, winner): what
+# ops/sparse_dispatch.json's tpu section holds (my chip run, PR 30)
+CELL_WINNERS = [(4096, "plan"), (256, "scatter"), (16, "scatter")]
 
 
 def _table(backends):
@@ -25,7 +31,7 @@ def _entry(d, updates, winner):
     return {
         "d": d, "batch": 32, "nnz": 4, "updates": updates,
         "duplicate_factor": 1.0,
-        "rates_updates_per_sec": {"scatter": 1.0, "mxu": 1.0, "segsum": 1.0},
+        "rates_updates_per_sec": {"scatter": 1.0, "mxu": 1.0, "plan": 1.0},
         "winner": winner,
     }
 
@@ -36,14 +42,14 @@ class TestLookup:
         path.write_text(json.dumps(_table({
             "cpu": {"entries": [
                 _entry(1 << 12, 1 << 10, "scatter"),
-                _entry(1 << 18, 1 << 10, "segsum"),
+                _entry(1 << 18, 1 << 10, "plan"),
             ]},
         })))
         monkeypatch.setenv(cal.ENV_TABLE, str(path))
         assert cal.lookup_winner("cpu", 1 << 12, 1 << 10) == "scatter"
-        assert cal.lookup_winner("cpu", 1 << 19, 2048) == "segsum"
-        # log2-nearest: D=2^15 ties split by first-wins, D=2^16 -> segsum
-        assert cal.lookup_winner("cpu", 1 << 16, 1 << 10) == "segsum"
+        assert cal.lookup_winner("cpu", 1 << 19, 2048) == "plan"
+        # log2-nearest: D=2^15 ties split by first-wins, D=2^16 -> plan
+        assert cal.lookup_winner("cpu", 1 << 16, 1 << 10) == "plan"
         # unmeasured backend: None (callers fall back to the guess)
         assert cal.lookup_winner("tpu", 1 << 18, 1 << 10) is None
 
@@ -56,18 +62,23 @@ class TestLookup:
         assert cal.lookup_winner("cpu", 1 << 18, 1 << 10) is None
 
     def test_auto_dispatch_reads_table(self, tmp_path, monkeypatch):
-        """sparse_scatter_add_auto's trace-time resolution follows the
-        committed table for the active backend."""
+        """sparse_update's trace-time resolution follows the table for the
+        active backend."""
         import jax
 
         backend = jax.default_backend()
         path = tmp_path / "table.json"
         path.write_text(json.dumps(_table({
-            backend: {"entries": [_entry(1 << 10, 256, "segsum")]},
+            backend: {"entries": [_entry(1 << 10, 256, "plan")]},
         })))
         monkeypatch.setenv(cal.ENV_TABLE, str(path))
         monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
-        assert _resolve_impl(1 << 10, 256) == "segsum"
+        assert _resolve_impl(1 << 10, 256) == "plan"
+        # where the plan's spare addresses would leave int32, or the
+        # weights are not 4 bytes wide, the table's choice gives way to the
+        # plain pair
+        assert _resolve_impl(2 ** 31 - 8, 256) == "scatter"
+        assert _resolve_impl(1 << 10, 256, dtype="bfloat16") == "scatter"
         # env knob beats the table
         monkeypatch.setenv("OMLDM_SPARSE_SCATTER", "scatter")
         assert _resolve_impl(1 << 10, 256) == "scatter"
@@ -76,8 +87,8 @@ class TestLookup:
 class TestCalibrate:
     def test_measure_entry_covers_all_kernels(self):
         e = cal.measure_entry(256, 16, 4, steps=2)
-        assert set(e["rates_updates_per_sec"]) == set(SCATTER_IMPLS)
-        assert e["winner"] in SCATTER_IMPLS
+        assert set(e["rates_updates_per_sec"]) == set(IMPLS)
+        assert e["winner"] in EXACT_IMPLS
         assert e["updates"] == 16 * 4
         assert e["duplicate_factor"] >= 1.0
 
@@ -97,26 +108,60 @@ class TestCalibrate:
         on_disk = json.loads(path.read_text())
         assert set(on_disk["backends"]) == set(table["backends"])
         [e] = on_disk["backends"][jax.default_backend()]["entries"]
-        assert e["winner"] in SCATTER_IMPLS
+        assert e["winner"] in EXACT_IMPLS
 
-    def test_committed_table_has_cpu_section(self):
-        """The repo ships a calibrated CPU section so the dispatch never
-        falls back to the guess on the tier-1 host; the smoke CI run
-        regenerates the same shape."""
+    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
+    def test_committed_table_has_section(self, backend):
+        """The repo ships a calibrated section for the tier-1 host and one
+        for the chip (PR 30), so the dispatch falls back to the plain pair
+        on neither; the smoke CI run regenerates the same shape."""
         table = cal.load_table(cal.DEFAULT_TABLE)
         assert table is not None, "ops/sparse_dispatch.json missing/corrupt"
-        cpu = table["backends"].get("cpu")
-        assert cpu and cpu["entries"], "no CPU section in committed table"
-        for e in cpu["entries"]:
-            assert e["winner"] in SCATTER_IMPLS
-            assert set(e["rates_updates_per_sec"]) == set(SCATTER_IMPLS)
+        section = table["backends"].get(backend)
+        assert section and section["entries"], f"no {backend} section"
+        for e in section["entries"]:
+            # a winner is a default, and a default is exact in float32:
+            # mxu's rate is on record, mxu is never named
+            rates = e["rates_updates_per_sec"]
+            assert set(rates) == set(IMPLS)
+            assert e["winner"] == max(EXACT_IMPLS, key=rates.__getitem__)
+            assert e["updates"] == e["batch"] * e["nnz"]
+
+    @pytest.mark.parametrize("batch,winner", CELL_WINNERS)
+    def test_committed_tpu_section_at_the_cells_shapes(
+        self, batch, winner, monkeypatch
+    ):
+        """What the benchmark's cells run (2^28 + 14 weights, maxNnz 40 plus
+        the bias slot) is IN the chip's section, so the nearest point is the
+        shape itself: a launch of 4096 rows, a tail step of 256, a forecast's
+        padded 16. The same shapes on a backend without a section, and any
+        shape with no table at all, get the plain pair."""
+        from omldm_tpu.ops import sparse as sp
+
+        monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
+        monkeypatch.delenv(cal.ENV_TABLE, raising=False)
+        d, n = (1 << 28) + 14, batch * 41
+        [entry] = [
+            e for e in cal.load_table(cal.DEFAULT_TABLE)["backends"]["tpu"][
+                "entries"
+            ] if (e["d"], e["updates"]) == (d, n)
+        ]
+        assert entry["winner"] == winner
+        assert cal.lookup_winner("tpu", d, n) == winner
+        assert cal.lookup_winner("gpu", d, n) is None
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert sp._resolve_impl(d, n) == winner
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        assert sp._resolve_impl(d, n) == "scatter"
 
     def test_tpu_guess_retired(self, tmp_path, monkeypatch):
         """The round-5 ``D >= 2^16 -> mxu`` TPU guess is retired: an
         UNCALIBRATED backend (no table section) resolves to the plain
         scatter at any D — the guessed crossover was never measured, and a
-        number nobody measured must not steer the dispatch. A real TPU
-        table section, once calibrated, still wins."""
+        number nobody measured must not steer the dispatch. Nor does a
+        table that names ``mxu`` (an older calibration's; this one names
+        exact formulations only): ``mxu`` rounds the updates' low halves to
+        bfloat16 and is reached by explicit config or the env knob alone."""
         import jax
 
         from omldm_tpu.ops import sparse as sp
@@ -126,21 +171,18 @@ class TestCalibrate:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert sp._resolve_impl(1 << 20, 1 << 10) == "scatter"
         assert sp._resolve_impl(1 << 10, 1 << 10) == "scatter"
-        # a measured tpu section reinstates mxu where it actually won
         path = tmp_path / "table.json"
         path.write_text(json.dumps(_table({
             "tpu": {"entries": [_entry(1 << 20, 1 << 10, "mxu")]},
         })))
         monkeypatch.setenv(cal.ENV_TABLE, str(path))
-        assert sp._resolve_impl(1 << 20, 1 << 10) == "mxu"
-        # the committed table has no tpu section until a chip run writes one
-        committed = cal.load_table(cal.DEFAULT_TABLE)
-        assert "tpu" not in committed["backends"]
+        assert sp._resolve_impl(1 << 20, 1 << 10) == "scatter"
+        assert sp._resolve_impl(1 << 20, 1 << 10, impl="mxu") == "mxu"
 
 
 class TestLearnerWiring:
     def test_sparse_pa_update_honors_scatter_override(self, monkeypatch):
-        """The learner hot path reaches sparse_scatter_add_auto; pinning
+        """The learner hot path reaches sparse_update; pinning
         the impl via dataStructure.scatterImpl (config twin of the env
         knob) stays numerically inside the twin envelope."""
         import jax.numpy as jnp
@@ -155,7 +197,7 @@ class TestLearnerWiring:
         y = (rng.randn(b) > 0).astype(np.float32)
         mask = np.ones(b, np.float32)
         params = {}
-        for impl in ("scatter", "segsum"):
+        for impl in ("scatter", "plan"):
             learner = make_learner(LearnerSpec(
                 "PA", hyper_parameters={"C": 0.5, "variant": "PA-II"},
                 data_structure={"sparse": True, "scatterImpl": impl},
@@ -167,5 +209,5 @@ class TestLearnerWiring:
             )
             params[impl] = np.asarray(p["w"])
         np.testing.assert_allclose(
-            params["segsum"], params["scatter"], rtol=2e-5, atol=2e-5
+            params["plan"], params["scatter"], rtol=2e-5, atol=2e-5
         )

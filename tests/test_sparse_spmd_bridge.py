@@ -207,7 +207,7 @@ class TestFusedSparseStaging:
     overlapped route rides the same contract (≤ bit-identical, pinned
     exactly). Streams include forecasts, escaped-category fallbacks and
     DUPLICATE-HEAVY categoricals (tiny vocabularies, the hashed-collision
-    case the segsum pre-combine targets)."""
+    case the index plan's pre-combine targets)."""
 
     def _dup_heavy_lines(self, n, seed=7):
         """Categoricals drawn from 3-value vocabularies: most batch rows
@@ -301,15 +301,16 @@ class TestFusedSparseStaging:
             b.flush()
             self._assert_identical(b, ref, p, ref_p, label)
 
-    def test_segsum_pipeline_stays_in_twin_envelope(self, tmp_path):
-        """A sparse pipeline trained with the segsum pre-combine pinned
-        (dataStructure.scatterImpl) diverges from the plain-scatter run by
+    def test_plan_pipeline_stays_in_twin_envelope(self, tmp_path):
+        """A sparse pipeline trained with the index plan pinned
+        (dataStructure.scatterImpl) diverges from the plain-pair run by
         <= 2e-5 per parameter on a duplicate-heavy stream — the bridge-level
-        form of the ops twin tests."""
+        form of the ops twin tests — and its launches' device counters reach
+        the phase table's ``fit`` row once the statistics are read."""
         path = tmp_path / "dup.jsonl"
         path.write_text("\n".join(self._dup_heavy_lines(2000)) + "\n")
         flats = {}
-        for impl in ("scatter", "segsum"):
+        for impl in ("scatter", "plan"):
             create = _create()
             create["learner"]["dataStructure"]["scatterImpl"] = impl
             preds = []
@@ -322,6 +323,17 @@ class TestFusedSparseStaging:
             bridge.ingest_file(str(path))
             bridge.flush()
             flats[impl] = np.asarray(bridge.trainer.global_flat_params())
+            bridge.network_statistics()
+            fit = job.phase_table()["fit"]
+            if impl == "plan":
+                # 3-value vocabularies: far fewer addresses than slots
+                assert 0.0 < fit["distinct_share"] < 0.5, fit
+                assert fit["slots"] == fit["rows_padded"] * (
+                    bridge.max_nnz + 1
+                )
+                assert fit["overflow_launches"] == 0
+            else:
+                assert "distinct_share" not in fit
         np.testing.assert_allclose(
-            flats["segsum"], flats["scatter"], rtol=2e-5, atol=2e-5
+            flats["plan"], flats["scatter"], rtol=2e-5, atol=2e-5
         )

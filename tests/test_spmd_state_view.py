@@ -273,18 +273,24 @@ def _wide_primitives(jaxpr, d, path=()):
     return found
 
 
+@pytest.mark.parametrize("impl", ["scatter", "plan"])
 @pytest.mark.parametrize("learner", ["PA", "RegressorPA"])
-def test_only_the_scatter_is_model_wide_in_the_non_sync_step(learner):
+def test_only_the_scatter_is_model_wide_in_the_non_sync_step(learner, impl):
     """Counts only (the CPU says nothing of the chip's layouts): at a small
     odd width, the only primitive of the sparse step's non-sync path whose
-    result is as wide as the model is the scatter. ``chip_smoke.py``'s
+    result is as wide as the model is the scatter, under the plain pair and
+    under the index plan alike (whose overflow branch, like the sync, is the
+    one taken when its predicate holds). ``chip_smoke.py``'s
     ``stream_sparse`` leg holds the compiled programs to the same on the
     chip."""
     d = 2 ** 16 + 14
     batch, nnz = 32, 8
     spec = LearnerSpec(
         learner, hyper_parameters={"C": 0.1, "variant": "PA-II"},
-        data_structure={"sparse": True, "nFeatures": d - 1, "maxNnz": nnz},
+        data_structure={
+            "sparse": True, "nFeatures": d - 1, "maxNnz": nnz,
+            "scatterImpl": impl,
+        },
     )
     tr = _trainer(spec, d - 1, batch=batch)
     assert tr.n_params == d

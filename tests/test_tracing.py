@@ -300,9 +300,12 @@ def test_phase_table_counts_from_a_mark_and_adds_another_profile():
         pass
     with rec.span("build_state"):  # another job's: before the mark
         pass
+    # what launches counted on the device, noted where their outputs are read
+    rec.add_counts("fit", slots=1000, slots_distinct=900, overflow_launches=1)
     since = rec.mark()
     with rec.span("fit", rows=28, rows_padded=256):
         time.sleep(0.01)
+    rec.add_counts("fit", slots=400, slots_distinct=76, overflow_launches=0)
     fused = PhaseProfile(rec, since=since)
     assert fused.seconds("fit") == pytest.approx(rec.records("fit")[1].self_s)
     armed = PhaseProfile()
@@ -312,10 +315,15 @@ def test_phase_table_counts_from_a_mark_and_adds_another_profile():
     assert set(t) == {"fit", "parse", "_coverage"}  # no row from before the mark
     assert t["fit"]["count"] == 2 and t["fit"]["seconds"] >= 0.51
     assert (t["fit"]["rows"], t["fit"]["rows_padded"]) == (28, 256)
+    assert (t["fit"]["slots"], t["fit"]["overflow_launches"]) == (400, 0)
+    assert t["fit"]["distinct_share"] == 0.19
     assert t["fit"]["p99_ms"] > t["fit"]["p50_ms"] >= 10.0
-    assert "rows" not in t["parse"]
+    assert "rows" not in t["parse"] and "distinct_share" not in t["parse"]
     # the recorder itself still holds the whole stream
-    assert rec.counts("fit") == {"rows": 4124, "rows_padded": 4352}
+    assert rec.counts("fit") == {
+        "rows": 4124, "rows_padded": 4352, "slots": 1400,
+        "slots_distinct": 976, "overflow_launches": 1,
+    }
     assert PhaseProfile(rec).table()["fit"]["count"] == 2
 
 
