@@ -1692,16 +1692,6 @@ def main() -> None:
              "throughput is < 2x the single-device cohort's",
     )
     ap.add_argument(
-        "--ingest-smoke", action="store_true",
-        help="CI gate: the sharded multi-process ingest plane vs "
-             "single-process ingest. NONZERO EXIT if the sharded block "
-             "stream is not bitwise the single-process parse, a "
-             "sharded-driven StreamJob diverges bitwise from the packed "
-             "event route, phase coverage drops below 0.9 with the shard "
-             "clocks folded in, or (on hosts with >= 2 usable cores) the "
-             "sharded ingest throughput is < 1.5x single-process",
-    )
-    ap.add_argument(
         "--forecast-mix", type=float, default=0.0,
         help="serving section: sweep per-record vs adaptive-batching "
              "serving (exact + relaxed) on a forecast-heavy stream with "
@@ -1850,201 +1840,6 @@ def main() -> None:
         else ("none", args.codec) if args.codec != "none"
         else ()
     )
-
-    if args.ingest_smoke:
-        # CI gate (ISSUE 17 acceptance): the sharded multi-process ingest
-        # plane (runtime/ingest_shard.py) against single-process ingest:
-        #   (a) raw block-stream parity — the sharded plane's
-        #       concatenated (x, y, op) rows must be BITWISE the
-        #       single-process parse of the same file;
-        #   (b) full-driver parity — a StreamJob consuming the file
-        #       through run_file_sharded must match the packed event
-        #       route bitwise (fitted, score, holdout contents, trained
-        #       params);
-        #   (c) phase coverage >= 0.9 on the sharded run with the shard
-        #       parse/read clocks folded into the phase table;
-        #   (d) sharded ingest throughput >= 1.5x single-process —
-        #       ENFORCED only on hosts with >= 2 usable cores: parser
-        #       processes timeshare the driver's core on a 1-core box, so
-        #       parallel speedup is physically unavailable there (same
-        #       basis note as --shard-smoke); the measured ratio is
-        #       reported either way.
-        import tempfile
-
-        import numpy as np
-
-        from run_benchmarks import _gen_stream_file
-        from omldm_tpu.config import JobConfig
-        from omldm_tpu.runtime import StreamJob
-        from omldm_tpu.runtime.fast_ingest import iter_file_batches
-        from omldm_tpu.runtime.ingest_shard import (
-            IngestConfig,
-            ShardedIngest,
-        )
-        from omldm_tpu.runtime.job import REQUEST_STREAM
-
-        dim = 16
-        records = min(args.records, 80_000)
-        tmp = tempfile.NamedTemporaryFile(suffix=".jsonl", delete=False)
-        tmp.close()
-        _gen_stream_file(tmp.name, records, dim)
-        try:
-            n_cores = len(os.sched_getaffinity(0))
-        except AttributeError:
-            n_cores = os.cpu_count() or 1
-        n_shards = max(n_cores - 1, 1)
-        failures = []
-        warnings = []
-
-        # (a) raw block-stream parity
-        def _collect_sharded(shards, chunk_kb=256):
-            si = ShardedIngest(
-                tmp.name, dim, IngestConfig(shards=shards, chunk_kb=chunk_kb)
-            )
-            xs, ys, ops = [], [], []
-            try:
-                for x, y, op in si.blocks():
-                    xs.append(x)
-                    ys.append(y)
-                    ops.append(op)
-            finally:
-                si.close()
-            return (
-                np.concatenate(xs), np.concatenate(ys), np.concatenate(ops)
-            )
-
-        ref_parts = list(iter_file_batches(tmp.name, dim, 32768))
-        ref = tuple(
-            np.concatenate([p[i] for p in ref_parts]) for i in range(3)
-        )
-        got = _collect_sharded(max(n_shards, 2))
-        if not all(np.array_equal(ref[i], got[i]) for i in range(3)):
-            failures.append(
-                "sharded block stream is not bitwise the single-process "
-                "parse"
-            )
-
-        # (d) throughput: sharded plane vs single-process packed iterator
-        def _t_single():
-            t0 = time.perf_counter()
-            for _ in iter_file_batches(tmp.name, dim, 32768):
-                pass
-            return time.perf_counter() - t0
-
-        def _t_sharded():
-            si = ShardedIngest(
-                tmp.name, dim, IngestConfig(shards=n_shards)
-            )
-            t0 = time.perf_counter()
-            try:
-                for _ in si.blocks():
-                    pass
-            finally:
-                si.close()
-            return time.perf_counter() - t0
-
-        _t_single(), _t_sharded()  # warm (page cache, fork paths)
-        t_single = min(_t_single() for _ in range(2))
-        t_sharded = min(_t_sharded() for _ in range(2))
-        ratio = t_single / max(t_sharded, 1e-9)
-        if ratio < 1.5:
-            msg = (
-                f"sharded ingest speedup {ratio:.2f}x < 1.5x at "
-                f"{n_shards} shards"
-            )
-            if n_cores >= 2:
-                failures.append(msg)
-            else:
-                warnings.append(
-                    msg + f" — NOT enforced: {n_cores} usable core means "
-                    "the parser processes timeshare the driver's core, "
-                    "so parallel speedup is physically unavailable on "
-                    "this host"
-                )
-
-        # (b) full-driver bitwise parity + (c) phase coverage
-        create = json.dumps({
-            "id": 0,
-            "request": "Create",
-            "learner": {
-                "name": "PA",
-                "hyperParameters": {"C": 1.0},
-                "dataStructure": {"nFeatures": dim},
-            },
-            "trainingConfiguration": {"protocol": "CentralizedTraining"},
-        })
-
-        def _driver_run(sharded):
-            job = StreamJob(JobConfig(
-                parallelism=2, batch_size=128, test_set_size=64,
-                telemetry="statsEvery=1000000",
-                ingest="shards=2,chunkKb=256" if sharded else "",
-            ))
-            job.process_event(REQUEST_STREAM, create)
-            job.ensure_deployed(dim)
-            t0 = time.perf_counter()
-            if sharded:
-                assert job.run_file_sharded(tmp.name, dim=dim)
-            else:
-                for blk in iter_file_batches(tmp.name, dim, 32768):
-                    job.process_packed_batch(*blk)
-            e2e = time.perf_counter() - t0
-            table = job.phase_table(e2e)
-            rep = job.terminate()
-            st = rep.statistics[0]
-            return {
-                "fitted": st.fitted,
-                "score": st.score,
-                "coverage": table.get("_coverage", 0.0),
-                "examples_per_sec": round(records / e2e, 1),
-            }
-
-        _driver_run(True)  # warmup compiles the fit programs
-        base_run = _driver_run(False)
-        # parity must hold on EVERY sharded run; coverage takes the best
-        # of two (attribution is deterministic, but a loaded CI box can
-        # steal wall-clock from the driver loop between phase hooks)
-        shard_runs = [_driver_run(True) for _ in range(2)]
-        for shard_run in shard_runs:
-            if (
-                base_run["fitted"] != shard_run["fitted"]
-                or base_run["score"] != shard_run["score"]
-            ):
-                failures.append(
-                    "sharded-driven StreamJob diverged from the packed "
-                    f"route (fitted {shard_run['fitted']} vs "
-                    f"{base_run['fitted']}, score {shard_run['score']} "
-                    f"vs {base_run['score']})"
-                )
-                break
-        shard_run = max(shard_runs, key=lambda r: r["coverage"])
-        if shard_run["coverage"] < 0.9:
-            failures.append(
-                f"phase coverage {shard_run['coverage']} < 0.9 on the "
-                "sharded run"
-            )
-
-        os.unlink(tmp.name)
-        print(json.dumps({
-            "config": "protocol_comparison_ingest_smoke",
-            "records": records,
-            "usable_cores": n_cores,
-            "shards": n_shards,
-            "sharded_speedup_vs_single_process": round(ratio, 2),
-            "single_process_ingest_examples_per_sec": round(
-                records / t_single, 1
-            ),
-            "sharded_ingest_examples_per_sec": round(
-                records / t_sharded, 1
-            ),
-            "packed_route": base_run,
-            "sharded_route": shard_run,
-            "warnings": warnings,
-            "failures": failures,
-        }))
-        if failures:
-            sys.exit(1)
-        return
 
     if args.shard_smoke:
         # CI gate (ISSUE 9 acceptance): at 64 co-hosted tenants on the

@@ -377,204 +377,6 @@ def bench_avazu_sparse_softmax(steps):
     )
 
 
-def _gen_sparse_stream_file(path, n_records, n_num=13, n_cat=26, seed=0):
-    """Criteo-shaped sparse stream: 13 numerics + 26 categorical strings."""
-    rng = np.random.RandomState(seed)
-    w = rng.randn(n_num)
-    with open(path, "w") as f:
-        chunk = 20_000
-        written = 0
-        while written < n_records:
-            n = min(chunk, n_records - written)
-            x = np.round(rng.randn(n, n_num), 6)
-            y = (x @ w > 0).astype(np.float32)
-            cats = rng.randint(0, 1000, size=(n, n_cat))
-            lines = [
-                '{"numericalFeatures": [%s], "categoricalFeatures": [%s], '
-                '"target": %.1f, "operation": "training"}'
-                % (
-                    ", ".join("%.6f" % v for v in x[i]),
-                    ", ".join('"f%d_v%d"' % (j, cats[i, j])
-                              for j in range(n_cat)),
-                    y[i],
-                )
-                for i in range(n)
-            ]
-            f.write("\n".join(lines) + "\n")
-            written += n
-    return os.path.getsize(path)
-
-
-def bench_criteo_sparse_stream_e2e(steps, n_records=300_000):
-    """SPARSE end-to-end: JSON bytes (13 numerics + 26 categorical strings)
-    -> padded-COO -> trained 2^18-width sparse params, through the REAL
-    sparse CLI route (C COO parser with in-C zlib-CRC32 hashing ->
-    SparseSPMDBridge staging -> collective steps). The sparse twin of
-    e2e_json_to_params, decomposed the same way (host ceiling vs device
-    rate)."""
-    import tempfile
-
-    import jax
-
-    from omldm_tpu.config import JobConfig
-    from omldm_tpu.runtime import StreamJob
-    from omldm_tpu.runtime.job import REQUEST_STREAM
-
-    dim = 13 + (1 << 18)
-    tmp = tempfile.NamedTemporaryFile(suffix=".jsonl", delete=False)
-    tmp.close()
-    n_bytes = _gen_sparse_stream_file(tmp.name, n_records)  # not timed
-
-    def make_job():
-        create = {
-            "id": 0,
-            "request": "Create",
-            "learner": {
-                "name": "PA",
-                "hyperParameters": {"C": 0.1, "variant": "PA-II"},
-                "dataStructure": {
-                    "sparse": True, "nFeatures": dim,
-                    "hashSpace": 1 << 18, "maxNnz": 40,
-                },
-            },
-            "preProcessors": [],
-            "trainingConfiguration": {
-                "protocol": "Synchronous", "engine": "spmd", "syncEvery": 4,
-            },
-        }
-        job = StreamJob(JobConfig(parallelism=1, batch_size=4096))
-        job.process_event(REQUEST_STREAM, json.dumps(create))
-        [bridge] = job.spmd_bridges.values()
-        return job, bridge
-
-    # host ceiling: device stubbed, best of 3 after warmup
-    job_h, bridge_h = make_job()
-
-    class _Nop:
-        fitted = 0
-
-        def step(self, *a, **k):
-            pass
-
-        def predict(self, x):
-            return np.zeros((1,))
-
-    bridge_h.trainer = _Nop()
-    assert bridge_h.supports_fused_ingest(), (
-        "sparse fused ingest unavailable (native parser missing?) — "
-        "refusing to fabricate an e2e figure"
-    )
-    bridge_h.ingest_file(tmp.name)  # warmup (page cache, lib build)
-    host_samples = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        bridge_h.ingest_file(tmp.name)  # SERIAL: the parse ceiling
-        bridge_h.flush()
-        host_samples.append(time.perf_counter() - t0)
-    t_host = min(host_samples)
-
-    # raw run with the device in the loop, as a field — serial, so raw vs
-    # raw_overlapped shows what the producer/consumer split buys
-    job, bridge = make_job()
-    t0 = time.perf_counter()
-    bridge.ingest_file(tmp.name)
-    bridge.flush()
-    jax.block_until_ready(bridge.trainer.state["params"])
-    t_raw = time.perf_counter() - t0
-    fitted = bridge.trainer.fitted
-
-    # raw OVERLAPPED run (the route the CLI now takes): C parse + holdout
-    # fill stage k+1 while the dispatch thread scatters stage k
-    job_o, bridge_o = make_job()
-    t0 = time.perf_counter()
-    bridge_o.ingest_file_overlapped(tmp.name)
-    bridge_o.flush()
-    jax.block_until_ready(bridge_o.trainer.state["params"])
-    t_raw_overlapped = time.perf_counter() - t0
-
-    # device rate: the sparse hot loop at the same width/nnz
-    _, dev_rate, _ = _bench_sparse(
-        "sparse_dev_probe",
-        __import__("omldm_tpu.api.requests", fromlist=["LearnerSpec"])
-        .LearnerSpec(
-            "PA", hyper_parameters={"C": 0.1, "variant": "PA-II"},
-            data_structure={"sparse": True, "nFeatures": dim},
-        ),
-        dim=dim, k=40, steps=max(steps, 64),
-    )
-    t_device = n_records / dev_rate
-    corrected = n_records / max(t_host, t_device)
-
-    # MEASURED overlapped run with the device stubbed at its measured
-    # rate (same design as the dense e2e: time.sleep models an
-    # asynchronous accelerator without stealing this one-core host's CPU)
-    job_m, bridge_m = make_job()
-    bridge_m.trainer = _Nop()
-    stub = lambda si, sv, sy, n: time.sleep(n / dev_rate)
-    bridge_m.ingest_file_overlapped(tmp.name, train_fn=stub)  # warm
-    bridge_m.flush()
-    overlapped_samples = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        # the final partial stage drains THROUGH the dispatch queue, so
-        # the stub charges its device time inside the measured interval
-        bridge_m.ingest_file_overlapped(tmp.name, train_fn=stub)
-        bridge_m.flush()
-        overlapped_samples.append(time.perf_counter() - t0)
-    t_overlapped = min(overlapped_samples)
-    overlapped_measured = n_records / t_overlapped
-
-    os.unlink(tmp.name)
-    from omldm_tpu.ops.sparse import _resolve_impl
-
-    n_threads = bridge_h._make_coo_parser().n_threads
-    return "criteo_sparse_stream_e2e_2e18", overlapped_measured, {
-        "basis": "e2e stream-fed, MEASURED double-buffered overlapped run",
-        "records": n_records,
-        "stream_mb": round(n_bytes / 1e6, 1),
-        # which of the three scatter kernels the calibration table picked
-        # for this width/batch on the active backend, and which sparse
-        # ingest route the bridge resolved (ops/sparse_dispatch.json;
-        # SparseSPMDBridge._use_fused_coo)
-        "scatter_impl": _resolve_impl(dim, 4096 * 40),
-        "ingest_route": (
-            "mt-parse+c-staging" if n_threads > 1 else "fused-line-loop"
-        ),
-        "parser_threads": n_threads,
-        "overlapped_measured_examples_per_sec": round(overlapped_measured, 1),
-        "overlapped_samples_s": [round(t, 3) for t in overlapped_samples],
-        "overlapped_vs_bound": round(overlapped_measured / corrected, 3),
-        "bound_examples_per_sec": round(corrected, 1),
-        "host_pipeline_examples_per_sec": round(n_records / t_host, 1),
-        "device_exec_examples_per_sec": round(dev_rate, 1),
-        "raw_examples_per_sec": round(n_records / t_raw, 1),
-        "raw_overlapped_examples_per_sec": round(
-            n_records / t_raw_overlapped, 1
-        ),
-        "host_samples_s": [round(t, 3) for t in host_samples],
-        "t_host_s": round(t_host, 3),
-        "t_device_s": round(t_device, 3),
-        "t_raw_s": round(t_raw, 3),
-        "t_raw_overlapped_s": round(t_raw_overlapped, 3),
-        "fitted": fitted,
-        "note": (
-            "value = MEASURED wall clock of the double-buffered run "
-            "(C COO parse + holdout fill stage k+1 while the dispatch "
-            "thread applies stage k at the separately-measured device "
-            "scatter rate); bound = n / max(t_host, t_device). The host "
-            "side is the C padded-COO parser (zlib-CRC32 categorical "
-            "hashing in C) feeding the fused C holdout/staging pass; the "
-            "device side the scatter path, dispatched from the "
-            "calibration table (ops/sparse_dispatch.json)"
-        ),
-    }
-
-
-# bf16 MXU peak per chip in TFLOP/s, keyed by jax's ``device_kind``.
-# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16).
-PEAK_BF16_TFLOPS = {"TPU v5 lite": 197.0}
-
-
 def _peak_bf16_tflops() -> float:
     """Peak of the device this process runs on; a device that is not in
     the table is an error, never a default."""
@@ -758,389 +560,6 @@ def bench_flash_attention(steps):
     }
 
 
-def _gen_stream_file(path, n_records, dim, seed=0):
-    import numpy as np
-
-    rng = np.random.RandomState(seed)
-    w = rng.randn(dim)
-    with open(path, "w") as f:
-        chunk = 20_000
-        written = 0
-        while written < n_records:
-            n = min(chunk, n_records - written)
-            x = np.round(rng.randn(n, dim), 6)
-            y = (x @ w > 0).astype(np.float32)
-            lines = [
-                '{"numericalFeatures": [%s], "target": %.1f, "operation": "training"}'
-                % (", ".join("%.6f" % v for v in x[i]), y[i])
-                for i in range(n)
-            ]
-            f.write("\n".join(lines) + "\n")
-            written += n
-    return os.path.getsize(path)
-
-
-def bench_phase_attribution(path, dim, n_records, batch=256):
-    """Phase-attributed breakdown of the streaming host-plane run (ISSUE
-    13): the SAME JSON-lines stream through the packed host route with
-    the telemetry plane armed — file read + C parse timed around the
-    batch iterator, stage/holdout attributed by the spoke's phase hooks,
-    fit by the flush StepTimer — so the ingest-wall work of ROADMAP #5
-    starts from measured attribution. ``coverage`` is the fraction of the
-    measured end-to-end wall the phase table accounts for (the acceptance
-    bar is >= 0.9: anything unattributed is runtime glue, not a hot
-    phase)."""
-    import numpy as np
-
-    from omldm_tpu.config import JobConfig
-    from omldm_tpu.runtime import StreamJob
-    from omldm_tpu.runtime.fast_ingest import iter_file_batches
-    from omldm_tpu.runtime.job import REQUEST_STREAM
-
-    def _make_job():
-        job = StreamJob(JobConfig(
-            parallelism=1, batch_size=batch, test_set_size=64,
-            telemetry="statsEvery=1000000",
-        ))
-        job.process_event(REQUEST_STREAM, json.dumps({
-            "id": 0,
-            "request": "Create",
-            "learner": {
-                "name": "PA",
-                "hyperParameters": {"C": 1.0},
-                "dataStructure": {"nFeatures": dim},
-            },
-            "trainingConfiguration": {"protocol": "CentralizedTraining"},
-        }))
-        return job
-
-    def _timed_run(job):
-        phases = job.telemetry.phases
-        it = iter_file_batches(path, dim, 32768)
-        t_start = time.perf_counter()
-        while True:
-            t0 = time.perf_counter()
-            b = next(it, None)
-            # file read + C block parse live inside the iterator; the
-            # fused route cannot split them, so both attribute to parse
-            phases.note("parse", time.perf_counter() - t0)
-            if b is None:
-                break
-            job.process_packed_batch(*b)
-        return time.perf_counter() - t_start
-
-    warm = _make_job()
-    _timed_run(warm)  # warmup job compiles the shared fit programs
-    warm.terminate()
-    job = _make_job()  # fresh accounting: phases cover ONE measured run
-    e2e = _timed_run(job)
-    table = job.phase_table(e2e)
-    job.terminate()
-    return {
-        "examples_per_sec": round(n_records / e2e, 1),
-        "e2e_s": round(e2e, 3),
-        "coverage": table.get("_coverage", 0.0),
-        "phases": {
-            k: v for k, v in table.items() if k != "_coverage"
-        },
-    }
-
-
-def _make_e2e_job(dim, parallelism, chain):
-    from omldm_tpu.config import JobConfig
-    from omldm_tpu.runtime import StreamJob
-    from omldm_tpu.runtime.job import REQUEST_STREAM
-
-    create = {
-        "id": 0,
-        "request": "Create",
-        "learner": {
-            "name": "Softmax",
-            "hyperParameters": {"learningRate": 0.05, "nClasses": 2},
-            "dataStructure": {"nFeatures": dim},
-        },
-        "preProcessors": [],
-        "trainingConfiguration": {
-            "protocol": "Synchronous",
-            "engine": "spmd",
-            "extra": {"stageChain": chain},
-        },
-    }
-    job = StreamJob(JobConfig(parallelism=parallelism, batch_size=4096))
-    job.process_event(REQUEST_STREAM, json.dumps(create))
-    [bridge] = job.spmd_bridges.values()
-    return job, bridge
-
-
-def bench_e2e_stream(n_records=1_000_000, parallelism=1, chain=32):
-    """JSON-bytes -> trained-params END-TO-END throughput: the real CLI
-    ingest route (C++ block parse -> prefetch thread -> packed batches ->
-    SPMD staged chained steps), timed from first byte consumed to the
-    trained parameters materialized on host. Nothing is pre-staged on the
-    device; this is the number the reference's whole-job throughput maps to
-    (Job.scala:42-70 -> FlinkSpoke.scala:92-107 hot loop).
-
-    Reports THREE directly-measured runs so the host's and the device's
-    shares can be told apart:
-
-    - raw        : the full run with the device in the loop (ingest loop +
-                   device drain), serial and overlapped;
-    - host       : the identical pipeline with the device stubbed out --
-                   parse + holdout + staging at full speed;
-    - device     : the same chained launches on device-resident stages
-                   (what the chip sustains when fed).
-
-    bound = n / max(t_host, t_device): the pipeline bottleneck when parse
-    and device execution overlap fully. ``value`` is the overlapped run
-    with the device step STUBBED at its measured time (ROADMAP A1 replaces
-    it with the device-in-the-loop figure, reported here as
-    raw_overlapped)."""
-    import tempfile
-
-    import numpy as np
-
-    from omldm_tpu.runtime.fast_ingest import iter_file_batches
-    from omldm_tpu.runtime.prefetch import prefetch
-    from omldm_tpu.runtime.spmd_bridge import TAIL_BATCH
-
-    dim = 28
-    tmp = tempfile.NamedTemporaryFile(suffix=".jsonl", delete=False)
-    tmp.close()
-    n_bytes = _gen_stream_file(tmp.name, n_records, dim)  # not timed
-
-    import jax
-    import jax.numpy as jnp
-
-    # --- host-ceiling run: device dispatch stubbed out ---
-    # The CLI file route is the fused C parse->holdout->stage loop
-    # (StreamJob.run_file_fused); the packed numpy route stays as the
-    # fallback. Timed best-of-3 after a warmup pass: this one-core box's
-    # throughput swings ~2x between runs, and the committed number should
-    # reflect the pipeline, not one noisy scheduler window (raw samples are
-    # reported alongside).
-    job_h, bridge_h = _make_e2e_job(dim, parallelism, chain)
-
-    class _NopTrainer:
-        fitted = 0
-
-        def step_many_dense(self, *a, **k):
-            pass
-
-        def step(self, *a, **k):
-            pass
-
-        def predict(self, x):
-            return np.zeros(x.shape[0])
-
-    bridge_h.trainer = _NopTrainer()
-    use_fused = bridge_h.supports_fused_ingest() and job_h.fused_file_bridge()
-
-    def _host_pass():
-        if use_fused:
-            # SERIAL fused ingest explicitly: t_host is defined as the
-            # single-thread parse ceiling (run_file_fused now auto-routes
-            # to the overlapped loop, which is measured separately below)
-            bridge_h.ingest_file(tmp.name)
-        else:
-            for batch in prefetch(
-                iter_file_batches(tmp.name, dim, 32768), depth=3
-            ):
-                job_h.process_packed_batch(*batch)
-        bridge_h.flush()
-
-    _host_pass()  # warmup (page cache, lazy imports, first-launch paths)
-    host_samples = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        _host_pass()
-        host_samples.append(time.perf_counter() - t0)
-    t_host = min(host_samples)
-
-    # --- raw run: the real thing on the TPU ---
-    job, bridge = _make_e2e_job(dim, parallelism, chain)
-    tr = bridge.trainer
-    # deep-copy: the jitted steps donate their input state buffers
-    state0 = jax.tree.map(
-        lambda a: jnp.array(a, copy=True) if isinstance(a, jax.Array) else a,
-        tr.state,
-    )
-    dp, b = bridge.dp, 4096
-    tb = min(b, TAIL_BATCH)
-    zx = np.zeros((chain, dp, b, dim), bridge.feed_dtype)
-    zy = np.zeros((chain, dp, b), bridge.feed_dtype)
-    tr.step_many_dense(zx, zy)
-    tr.step(
-        np.zeros((dp, b, dim), np.float32), np.zeros((dp, b), np.float32),
-        np.ones((dp, b), np.float32), valid_count=dp * b,
-    )
-    tr.step(
-        np.zeros((dp, tb, dim), np.float32), np.zeros((dp, tb), np.float32),
-        np.ones((dp, tb), np.float32), valid_count=dp * tb,
-    )
-    jax.block_until_ready(tr.state["params"])  # warm compiles for real
-    tr.state = state0
-    # reset the host-side counters the warmup advanced
-    tr._fitted_host = 0
-    tr._steps_host = 0
-    tr._curve = []
-
-    t0 = time.perf_counter()
-    if use_fused and job.fused_file_bridge():
-        bridge.ingest_file(tmp.name)  # serial: raw vs raw_overlapped
-    else:
-        for batch in prefetch(iter_file_batches(tmp.name, dim, 32768), depth=3):
-            job.process_packed_batch(*batch)
-    bridge.flush()
-    t_loop = time.perf_counter() - t0
-    # materialized host params = the full-pipeline completion barrier
-    flat = bridge.trainer.global_flat_params()
-    float(np.asarray(flat[0]))
-    t_raw = time.perf_counter() - t0
-    fitted_raw = bridge.trainer.fitted
-
-    # --- device-exec run: same chained program, stages already resident ---
-    xs_d = jax.device_put(jnp.asarray(zx))
-    ys_d = jax.device_put(jnp.asarray(zy))
-    jax.block_until_ready((xs_d, ys_d))
-    tr.step_many_dense(xs_d, ys_d)
-    jax.block_until_ready(tr.state["params"])
-    rounds = 8
-    t0 = time.perf_counter()
-    for _ in range(rounds):
-        tr.step_many_dense(xs_d, ys_d)
-    jax.block_until_ready(tr.state["params"])
-    t_dev_per_rec = (time.perf_counter() - t0) / (rounds * chain * dp * b)
-    t_device = t_dev_per_rec * n_records
-
-    corrected = n_records / max(t_host, t_device)
-
-    # --- MEASURED overlapped run (double-buffered ingest) ---
-    # The bound above assumes parse and device exec can overlap; this run
-    # exercises the overlap: the C parse thread fills stage k+1 while the
-    # dispatch thread 'trains' stage k through a device stub calibrated to
-    # the measured per-stage device time (time.sleep stands in for an
-    # accelerator executing asynchronously). The REAL-device overlapped
-    # run is reported separately as raw_overlapped.
-    t_stage_dev = t_dev_per_rec * chain * dp * b
-    job_o, bridge_o = _make_e2e_job(dim, parallelism, chain)
-    bridge_o.trainer = _NopTrainer()
-    stub = lambda sx, sy, n: time.sleep(t_stage_dev * n / (chain * dp * b))
-    overlapped_samples = []
-    bridge_o.ingest_file_overlapped(tmp.name, train_fn=stub)  # warm
-    bridge_o.flush()
-    for _ in range(3):
-        t0 = time.perf_counter()
-        # the final partial stage drains THROUGH the dispatch queue, so
-        # the stub charges its device time inside the measured interval
-        bridge_o.ingest_file_overlapped(tmp.name, train_fn=stub)
-        bridge_o.flush()
-        overlapped_samples.append(time.perf_counter() - t0)
-    t_overlapped = min(overlapped_samples)
-    overlapped_measured = n_records / t_overlapped
-
-    # real-device overlapped run: the dispatch thread hides device exec
-    # under the parse
-    job_r, bridge_r = _make_e2e_job(dim, parallelism, chain)
-    tr_r = bridge_r.trainer
-    tr_r.step_many_dense(zx, zy)
-    tr_r.step(
-        np.zeros((dp, b, dim), np.float32), np.zeros((dp, b), np.float32),
-        np.ones((dp, b), np.float32), valid_count=dp * b,
-    )
-    tr_r.step(
-        np.zeros((dp, tb, dim), np.float32), np.zeros((dp, tb), np.float32),
-        np.ones((dp, tb), np.float32), valid_count=dp * tb,
-    )
-    jax.block_until_ready(tr_r.state["params"])
-    t0 = time.perf_counter()
-    bridge_r.ingest_file_overlapped(tmp.name)
-    bridge_r.flush()
-    float(np.asarray(bridge_r.trainer.global_flat_params()[0]))
-    t_raw_overlapped = time.perf_counter() - t0
-
-    # --- sharded ingest leg (ISSUE 17): N parser processes striping the
-    # file's byte-grid chunks, the driver consuming blocks in stream
-    # order through shared-memory rings (bit-identical row order). Same
-    # stubbed-device basis as t_host, so the ratio is the ingest plane's
-    # own scaling — on a 1-core host the extra processes just timeshare
-    # and the ratio reports the (honest) IPC overhead instead.
-    from omldm_tpu.runtime.ingest_shard import IngestConfig, ShardedIngest
-
-    n_cores = os.cpu_count() or 1
-    n_shards = max(n_cores - 1, 1)
-    job_s, bridge_s = _make_e2e_job(dim, parallelism, chain)
-    bridge_s.trainer = _NopTrainer()
-
-    def _sharded_pass():
-        si = ShardedIngest(tmp.name, dim, IngestConfig(shards=n_shards))
-        try:
-            for block in si.blocks():
-                bridge_s.handle_batch(*block)
-        finally:
-            si.close()
-        bridge_s.flush()
-
-    _sharded_pass()  # warmup (fork + ring setup paths)
-    sharded_samples = []
-    for _ in range(3):
-        t0 = time.perf_counter()
-        _sharded_pass()
-        sharded_samples.append(time.perf_counter() - t0)
-    t_sharded = min(sharded_samples)
-
-    # --- phase-attributed breakdown of the streaming host run (ISSUE 13):
-    # the same stream through the telemetry-armed packed host route, so
-    # the e2e number above ships with measured per-phase attribution
-    phase_attribution = bench_phase_attribution(tmp.name, dim, n_records)
-
-    os.unlink(tmp.name)
-    return "e2e_json_to_params", overlapped_measured, {
-        "basis": "e2e stream-fed, MEASURED double-buffered overlapped run",
-        "records": n_records,
-        "phase_attribution": phase_attribution,
-        "stream_mb": round(n_bytes / 1e6, 1),
-        "overlapped_measured_examples_per_sec": round(overlapped_measured, 1),
-        "overlapped_samples_s": [round(t, 3) for t in overlapped_samples],
-        "overlapped_vs_bound": round(
-            overlapped_measured / corrected, 3
-        ),
-        "bound_examples_per_sec": round(corrected, 1),
-        "raw_examples_per_sec": round(n_records / t_raw, 1),
-        "raw_overlapped_examples_per_sec": round(
-            n_records / t_raw_overlapped, 1
-        ),
-        "raw_loop_examples_per_sec": round(n_records / t_loop, 1),
-        "host_pipeline_examples_per_sec": round(n_records / t_host, 1),
-        "device_exec_examples_per_sec": round(1.0 / t_dev_per_rec, 1),
-        "host_samples_s": [round(t, 3) for t in host_samples],
-        "sharded_ingest_examples_per_sec": round(n_records / t_sharded, 1),
-        "sharded_samples_s": [round(t, 3) for t in sharded_samples],
-        "sharded_shards": n_shards,
-        "sharded_host_cores": n_cores,
-        "sharded_vs_single": round(t_host / t_sharded, 3),
-        "sharded_basis": (
-            "driver-visible, device stubbed (same basis as t_host); "
-            "shards = cores-1; on a 1-core host the shards timeshare the "
-            "driver's core, so the ratio measures IPC overhead, not "
-            "scaling"
-        ),
-        "ingest_route": "fused-c" if use_fused else "packed-numpy",
-        "t_host_s": round(t_host, 3),
-        "t_device_s": round(t_device, 3),
-        "t_raw_s": round(t_raw, 3),
-        "t_raw_overlapped_s": round(t_raw_overlapped, 3),
-        "t_drain_s": round(t_raw - t_loop, 3),
-        "fitted": fitted_raw,
-        "note": (
-            "value = MEASURED wall-clock of the double-buffered run "
-            "(parse thread fills stage k+1 while the dispatch thread "
-            "trains stage k through a stub calibrated to the measured "
-            "per-stage device time). raw figures have the real device "
-            "in the loop; raw_overlapped hides device exec under the parse"
-        ),
-    }
-
-
 def bench_prediction_latency():
     """p50/p99 single-record serving latency through the padded predict path."""
     import jax
@@ -1248,7 +667,6 @@ def emit_slo_round(tenants: int, records: int, out_path: str = "") -> str:
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
-    ap.add_argument("--e2e-records", type=int, default=300_000)
     ap.add_argument(
         "--slo-only", action="store_true",
         help="record one SLO trajectory round (SLO_rXX.json) and exit",
@@ -1279,7 +697,6 @@ def main():
         bench_avazu_softmax_dp8,
         bench_criteo_sparse_pa,
         bench_avazu_sparse_softmax,
-        bench_criteo_sparse_stream_e2e,
         bench_longctx_transformer,
         bench_longctx_transformer_4k,
         bench_flash_attention,
@@ -1302,17 +719,6 @@ def main():
                 }
             )
         )
-    name, thr, extra = bench_e2e_stream(n_records=args.e2e_records)
-    print(
-        json.dumps(
-            {
-                "config": name,
-                "metric": "examples/sec (JSON bytes -> trained params)",
-                "value": round(thr, 1),
-                **extra,
-            }
-        )
-    )
     p50, p99 = bench_prediction_latency()
     print(
         json.dumps(

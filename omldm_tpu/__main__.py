@@ -444,17 +444,6 @@ def _try_fused_run(job: StreamJob, flags: Dict[str, str]) -> bool:
         else:
             flags["__streamSpec__"] = f"{spec[0]},{spec[1]}"
     job.ensure_deployed(spec[0])
-    # sharded ingest plane (--ingest / JobConfig.ingest): dense jobs only
-    # (the parser shards run the dense packed batcher); host-plane and
-    # multi-pipeline jobs are fine — blocks replay through the packed
-    # event route, in stream order
-    if job.ingest_cfg is not None and not sparse:
-        if job.run_file_sharded(
-            flags[TRAINING_STREAM], dim=spec[0], hash_dims=spec[1]
-        ):
-            job.terminate()
-            return True
-        return False
     if job.fused_file_bridge() is None:
         return False  # requests stay processed; packed route resumes
     job.run_file_fused(flags[TRAINING_STREAM])

@@ -166,26 +166,6 @@ class JobConfig:
     # always wins (an explicit false opts a pipeline out).
     overload: str = ""
 
-    # --- ingest plane (runtime/ingest_shard.py; the reference scales
-    # source parallelism by adding Flink source subtasks over Kafka
-    # partitions — here the analogue is N parser processes striping one
-    # stream) ---
-    # Sharded multi-process ingest + device-resident hot loop for file
-    # runs, e.g. "shards=4,chunkKb=4096,ring=4,device=on" or "on". Empty
-    # (default): nothing is armed — zero ingest objects exist and
-    # StreamJob.run_file takes the exact pre-plane route (fused C ingest
-    # or packed batches). Armed, N parser processes each run the fused-C
-    # parse loop over a byte-grid stripe of the file and hand packed row
-    # blocks to the driver through shared-memory rings; the driver
-    # consumes blocks in ascending chunk order, so the fitted + holdout
-    # row order is a pure function of the stream — bit-identical to
-    # single-process ingest. ``device=on`` additionally moves the staging
-    # pad and holdout ring onto the accelerator (SPMD pipelines; see
-    # SPMDBridge.enable_resident_ingest). A dead parser process degrades
-    # to in-process ingest, reason-coded through the selfheal
-    # classification, instead of wedging the driver.
-    ingest: str = ""
-
     # --- telemetry plane (runtime/telemetry.py; the reference's only
     # observability is the terminate-time JobStatistics report on the
     # performance stream, StatisticsOperator.scala:21-150) ---

@@ -75,8 +75,7 @@ class SparseVectorLinear(SparseLinear):
         the bias slot, the function that adds ``coef[b] * val[b, k]`` back,
         and the formulation's counters (``ops.sparse.sparse_update``: one
         formulation for both halves, from the calibration table;
-        ``dataStructure.scatterImpl`` pins it per pipeline, the config twin
-        of OMLDM_SPARSE_SCATTER)."""
+        ``dataStructure.scatterImpl`` pins it per pipeline)."""
         idx, val = self._with_bias(params, x)
         margins, add, counters = sparse_update(
             params["w"], idx, val, impl=self.ds.get("scatterImpl")

@@ -1371,21 +1371,15 @@ int omldm_parse_stage(const char* buf, long long len, OmldmStageCtx* ctx,
   return 0;
 }
 
-// --- fused SPARSE parse -> holdout -> stage ------------------------------
+// --- SPARSE holdout -> stage ----------------------------------------------
 //
-// The padded-COO twin of omldm_parse_stage: the sparse e2e hot loop
-// (SparseSPMDBridge.ingest_file -> _consume_coo_block -> _train_sparse_rows
-// -> _stage_coo) re-touched every row several times in numpy — per-block
-// output allocation + concatenate in the parser driver, the vectorized
-// holdout split (mask/argsort/concatenate), and the stage memcpy. This
-// entry parses each line DIRECTLY into its COO stage slot, runs the 8-of-10
-// holdout cycle in place against the sparse holdout ring (idx/val/y
-// triple), and swaps evicted rows into the arriving row's slot — exact
+// The padded-COO stager of the sparse file route
+// (SparseSPMDBridge._consume_coo_block -> _stage_parsed_rows): rows the
+// block parser produced are copied into their COO stage slots, the 8-of-10
+// holdout cycle runs in place against the sparse holdout ring (idx/val/y
+// triple), and evicted rows swap into the arriving row's slot — exact
 // SparseHoldout.append_many + _holdout_then_stage parity, pinned by
-// tests/test_sparse_spmd_bridge.py. Specials (Python-codec fallbacks AND
-// forecasts — both re-enter through DataInstance.from_json -> handle_data
-// exactly like the block route's special path) return control to the
-// caller; the hot loop stays pure C.
+// tests/test_sparse_spmd_bridge.py.
 struct OmldmSparseStageCtx {
   int32_t* stage_i;     // [stage_cap, max_nnz] COO index stage
   float* stage_v;       // [stage_cap, max_nnz] COO value stage
@@ -1400,8 +1394,6 @@ struct OmldmSparseStageCtx {
   long long hold_head;      // oldest element
   long long holdout_count;  // position in the 0-9 holdout cycle
   int max_nnz;
-  int dense_budget;         // positional slots before the hashed region
-  long long hash_space;
   int test_enabled;
 };
 
@@ -1450,64 +1442,14 @@ inline int sparse_stage_holdout_slot(OmldmSparseStageCtx* ctx, int32_t* si,
 
 }  // namespace
 
-// Parse a block of whole JSON lines straight into the COO staging buffers.
-// Returns:
-//   0  buffer fully consumed
-//   1  stage full (caller launches the staged step, resets stage_n, resumes)
-//   2  special line (codec fallback OR forecast — the caller re-parses
-//      [*special_off, +*special_len) with the Python codec, whose
-//      handle_data path serves forecasts and odd schemas identically to
-//      the block route)
-// *bytes_consumed is the resume offset relative to buf in all cases (for
-// 2 it points past the special line).
-int omldm_parse_stage_sparse(const char* buf, long long len,
-                             OmldmSparseStageCtx* ctx,
-                             long long* bytes_consumed,
-                             long long* special_off,
-                             long long* special_len) {
-  const char* p = buf;
-  const char* bufend = buf + len;
-  const int k = ctx->max_nnz;
-  const bool hash_fits =
-      ctx->hash_space > 0 && ctx->hash_space <= 0xFFFFFFFFL;
-  const FastMod hash_mod(
-      hash_fits ? static_cast<uint32_t>(ctx->hash_space) : 1u);
-  while (p < bufend) {
-    if (ctx->stage_n >= ctx->stage_cap) {
-      *bytes_consumed = p - buf;
-      return 1;
-    }
-    const char* nl = static_cast<const char*>(memchr(p, '\n', bufend - p));
-    const char* line_end = nl ? nl : bufend;
-    const char* next = nl ? nl + 1 : bufend;
-    int32_t* si = ctx->stage_i + ctx->stage_n * static_cast<long long>(k);
-    float* sv = ctx->stage_v + ctx->stage_n * static_cast<long long>(k);
-    float yv;
-    unsigned char opv, validv;
-    parse_one_line_sparse(p, line_end, ctx->dense_budget, ctx->hash_space,
-                          hash_mod, k, si, sv, &yv, &opv, &validv);
-    if (validv == 1 && opv == 0) {
-      sparse_stage_holdout_slot(ctx, si, sv, yv);
-    } else if (validv == 2 || (validv == 1 && opv == 1)) {
-      *special_off = p - buf;
-      *special_len = line_end - p;
-      *bytes_consumed = next - buf;
-      return 2;
-    }
-    p = next;
-  }
-  *bytes_consumed = len;
-  return 0;
-}
-
 // Stage a run of ALREADY-PARSED COO training rows: the staging tail of the
 // multithreaded block route (omldm_parse_lines_sparse_mt parses on all
 // cores, then this serial pass runs the 8-of-10 holdout cycle + ring swap
 // + stage memcpy in C — the work the numpy _holdout_then_stage/_stage_coo
 // pair used to do with mask/argsort/concatenate per block). Pauses at
 // stage-full so the caller can launch the staged step; returns rows
-// consumed from [0, n). Bit-identical to the fused line loop above and to
-// the numpy route (all three share the per-record holdout semantics).
+// consumed from [0, n). Bit-identical to the numpy route (both share the
+// per-record holdout semantics).
 long long omldm_stage_coo_rows(OmldmSparseStageCtx* ctx, const int32_t* idx,
                                const float* val, const float* y,
                                long long n) {

@@ -133,11 +133,6 @@ def _build() -> Optional[ctypes.CDLL]:
         ctypes.c_void_p, ctypes.c_longlong, ctypes.POINTER(StageCtx),
         ll_p, ll_p, ll_p, f_p, f_p,
     ]
-    lib.omldm_parse_stage_sparse.restype = ctypes.c_int
-    lib.omldm_parse_stage_sparse.argtypes = [
-        ctypes.c_void_p, ctypes.c_longlong,
-        ctypes.POINTER(SparseStageCtx), ll_p, ll_p, ll_p,
-    ]
     lib.omldm_stage_coo_rows.restype = ctypes.c_longlong
     lib.omldm_stage_coo_rows.argtypes = [
         ctypes.POINTER(SparseStageCtx), i32_p,
@@ -169,9 +164,8 @@ class StageCtx(ctypes.Structure):
 
 
 class SparseStageCtx(ctypes.Structure):
-    """Mirror of OmldmSparseStageCtx (fastparse.cpp): the fused sparse
-    parse->holdout->stage loop's view of the caller's padded-COO staging
-    buffers and holdout ring."""
+    """Mirror of OmldmSparseStageCtx (fastparse.cpp): the C stager's view
+    of the caller's padded-COO staging buffers and holdout ring."""
 
     _fields_ = [
         ("stage_i", ctypes.POINTER(ctypes.c_int32)),
@@ -187,8 +181,6 @@ class SparseStageCtx(ctypes.Structure):
         ("hold_head", ctypes.c_longlong),
         ("holdout_count", ctypes.c_longlong),
         ("max_nnz", ctypes.c_int),
-        ("dense_budget", ctypes.c_int),
-        ("hash_space", ctypes.c_longlong),
         ("test_enabled", ctypes.c_int),
     ]
 
@@ -420,20 +412,14 @@ class FusedStage:
 
 
 class SparseFusedStage:
-    """Driver for the fused sparse C parse->holdout->stage loop
-    (omldm_parse_stage_sparse): the padded-COO twin of :class:`FusedStage`.
+    """Driver for the C holdout->stage pass over parsed COO rows
+    (omldm_stage_coo_rows): the padded-COO counterpart of
+    :class:`FusedStage`'s staging half.
 
     Owns the ctypes ``SparseStageCtx`` describing the caller's COO staging
     buffers and sparse holdout ring; the caller syncs the mutable cursors
     (stage_n, holdout ring state, holdout cycle counter) in before each C
-    call and out after, exactly like the dense driver. Specials (Python
-    fallbacks AND forecasts) surface as one RC_SPECIAL code — both re-enter
-    through the Python codec's handle_data path, matching the block route's
-    special handling byte for byte."""
-
-    RC_DONE = 0        # buffer fully consumed
-    RC_STAGE_FULL = 1  # caller launches the staged step and resumes
-    RC_SPECIAL = 2     # line re-enters via DataInstance.from_json
+    call and out after, exactly like the dense driver."""
 
     def __init__(
         self,
@@ -443,8 +429,6 @@ class SparseFusedStage:
         hold_i: np.ndarray,
         hold_v: np.ndarray,
         hold_y: np.ndarray,
-        dense_budget: int,
-        hash_space: int,
         test_enabled: bool,
     ):
         lib = _get_lib()
@@ -480,35 +464,15 @@ class SparseFusedStage:
             hold_head=0,
             holdout_count=0,
             max_nnz=stage_i.shape[1],
-            dense_budget=dense_budget,
-            hash_space=hash_space,
             test_enabled=1 if test_enabled else 0,
         )
-
-    def parse_stage(self, buf: bytearray, start: int, stop: int):
-        """One C call over ``buf[start:stop]`` (whole JSON lines only).
-        Returns (rc, consumed, special_off, special_len); offsets are
-        relative to ``start``."""
-        base = ctypes.addressof((ctypes.c_char * len(buf)).from_buffer(buf))
-        consumed = ctypes.c_longlong(0)
-        soff = ctypes.c_longlong(0)
-        slen = ctypes.c_longlong(0)
-        rc = self._lib.omldm_parse_stage_sparse(
-            base + start,
-            stop - start,
-            ctypes.byref(self.ctx),
-            ctypes.byref(consumed),
-            ctypes.byref(soff),
-            ctypes.byref(slen),
-        )
-        return rc, consumed.value, soff.value, slen.value
 
     def stage_rows(
         self, idx: np.ndarray, val: np.ndarray, y: np.ndarray, start: int
     ) -> int:
         """Holdout + stage already-parsed COO rows ``[start, n)`` through
-        the C stager (omldm_stage_coo_rows — the MT block route's staging
-        tail). Pauses at stage-full; returns rows consumed."""
+        the C stager (omldm_stage_coo_rows). Pauses at stage-full; returns
+        rows consumed."""
         n = idx.shape[0] - start
         if n <= 0:
             return 0

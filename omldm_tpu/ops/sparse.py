@@ -19,7 +19,6 @@ the whole operand (scatter), which is what the index plan below is for.
 
 from __future__ import annotations
 
-import os
 from typing import NamedTuple
 
 import jax
@@ -46,72 +45,6 @@ def sparse_scatter_add(
     indices accumulate, including the idx=0 pad slots whose val is 0)."""
     upd = (coef[:, None] * val).reshape(-1)
     return w.at[idx.reshape(-1)].add(upd)
-
-
-# lane width of the kron factorization below: the TPU register/MXU lane
-# count, so the one-hot matmul operands tile exactly
-MXU_LANES = 512
-
-
-def sparse_scatter_add_mxu(
-    w: jnp.ndarray, idx: jnp.ndarray, coef: jnp.ndarray, val: jnp.ndarray
-) -> jnp.ndarray:
-    """The SAME scatter-add as :func:`sparse_scatter_add`, reformulated as
-    ONE MXU contraction — XLA's TPU scatter serializes randomly-indexed
-    updates while the systolic array is idle; this trades FLOPs for that
-    serialization.
-
-    Factor the index space D <= R*C as (hi, lo) = divmod(idx, C) with
-    C = 512 lanes. The scattered delta, viewed as a [R, C] matrix, is a
-    sum of rank-1 one-hot outer products — i.e. one matmul over the
-    update dimension n:
-
-        delta[hi, lo] = sum_n u_n * e(hi_n) (x) e(lo_n)
-                      = OneHotHi[n, R]^T @ (OneHotLo[n, C] * u_n)
-
-    Numerics: one-hot entries are exact in bf16; u is split
-    u = bf16(u) + bf16(u - bf16(u)) and the two halves are CONCATENATED
-    along the contraction dim. The high half's products are exact; the
-    low-half residual is itself rounded to bf16, leaving a bounded
-    ~2^-17 relative error per update ON TOP of the f32 accumulation
-    reorder — close to, but not exactly, scatter-bit-equivalence (pinned
-    to 2e-5 against the scatter by tests/test_sparse.py).
-
-    Cost: 2 * 2 * R*C FLOPs per update — at D = 2^18 that is ~1 MFLOP
-    per scattered update, so the MXU formulation pays for itself exactly
-    when the chip's matmul rate beats 66M * 2^20 FLOP/s; see the
-    experiment's roofline section for where the crossover lands.
-
-    Reference counterpart: SparseVector updates in the reference's data
-    model (DataPointParser.scala:4,20-47) — the reference applies them
-    element-by-element on the JVM; this is the TPU-native form.
-    """
-    d = w.shape[0]
-    c = MXU_LANES
-    r = -(-d // c)
-    n = idx.size
-    flat_idx = idx.reshape(n)
-    u = (coef[:, None] * val).reshape(n).astype(jnp.float32)
-    hi = flat_idx // c
-    lo = flat_idx % c
-    one_hi = jax.nn.one_hot(hi, r, dtype=jnp.bfloat16)            # [n, R]
-    lo_oh = jax.nn.one_hot(lo, c, dtype=jnp.float32)              # [n, C]
-    u_hi = u.astype(jnp.bfloat16).astype(jnp.float32)
-    u_lo = u - u_hi
-    rhs = jnp.concatenate(
-        [
-            (lo_oh * u_hi[:, None]).astype(jnp.bfloat16),
-            (lo_oh * u_lo[:, None]).astype(jnp.bfloat16),
-        ],
-        axis=0,
-    )                                                              # [2n, C]
-    lhs = jnp.concatenate([one_hi, one_hi], axis=0)                # [2n, R]
-    delta = jax.lax.dot_general(
-        lhs, rhs, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )                                                              # [R, C]
-    flat = delta.reshape(-1)
-    return w + (flat[:d] if r * c != d else flat)
 
 
 class IndexPlan(NamedTuple):
@@ -282,44 +215,31 @@ def plan_scatter_add(
 
 
 # ---------------------------------------------------------------------------
-# dispatch: calibration table + env/config override
+# dispatch: explicit impl, else the calibration table, else the plain pair
 # ---------------------------------------------------------------------------
 
-# the update's formulations by name: two scatters beside the plain gather,
-# and the plan, which is both halves (sparse_update)
-_SCATTERS = {"scatter": sparse_scatter_add, "mxu": sparse_scatter_add_mxu}
-IMPLS = (*_SCATTERS, "plan")
-# exact in f32 up to the order of one address's sums: what a calibration
-# may name a winner. ``mxu`` rounds every update's low half to bf16
-# (about 2^-17 relative), so it is timed for the record and reached by
-# explicit config or the env knob only (ROADMAP C4).
-EXACT_IMPLS = ("scatter", "plan")
-
-# env knob: OMLDM_SPARSE_SCATTER = scatter | mxu | plan | auto ("auto" or
-# unset reads the calibration table); config twin: dataStructure
-# {"scatterImpl": "..."} on the sparse learner spec (learners pass impl=).
-_ENV_KNOB = "OMLDM_SPARSE_SCATTER"
+# the update's formulations by name: the plain pair (``jnp.take`` and
+# XLA's scatter-add) and the plan, which is both halves (sparse_update).
+# Both are exact in f32 up to the order of one address's sums.
+IMPLS = ("scatter", "plan")
 
 
 def _resolve_impl(d: int, n_updates: int, impl=None, dtype=jnp.float32) -> str:
-    """Trace-time dispatch decision, in precedence order: explicit config
-    (``impl`` argument, from dataStructure.scatterImpl), the
-    OMLDM_SPARSE_SCATTER env var, the persisted calibration table
-    (ops/sparse_dispatch.json, nearest (D, updates) grid point for this
-    backend), and only then the uncalibrated fallback: ``scatter``.
+    """Trace-time dispatch decision: the explicit ``impl`` argument (from
+    dataStructure.scatterImpl: how a test or a twin run pins a side), else
+    the calibration table's section for this backend
+    (ops/sparse_dispatch.json, nearest (D, updates) grid point), else the
+    plain pair.
 
     The table holds what ``python -m omldm_tpu.ops.sparse_calibrate``
     measured of the whole update (margin and scatter of one formulation
-    together) on that backend, and names a winner among the exact
-    formulations (``EXACT_IMPLS``); anything else it names, and a backend
-    or a table without a section, gets the plain pair, the only
-    formulation with a record everywhere. On the TPU (PR 30's section,
-    measured at the benchmark's shapes) the plan wins at a launch of
-    4096 x 41 slots over 2^28 weights and the plain pair at 256 x 41 and
-    16 x 41; on the CPU the plain pair wins the whole grid (a sort costs
-    more than the scatter there). ``mxu`` is never the default: where it
-    timed fastest (1024-row launches at D <= 2^16 on the TPU, by 4 to
-    21%) the table still names the exact runner-up.
+    together) on that backend and names the faster of the two; a backend
+    or a table without a section, and a plan that does not fit, get the
+    plain pair, the only formulation with a record everywhere. On the TPU
+    (PR 30's section, measured at the benchmark's shapes) the plan wins at
+    a launch of 4096 x 41 slots over 2^28 weights and the plain pair at
+    256 x 41 and 16 x 41; the CPU has no section (a sort costs more than
+    the scatter there, over the whole grid).
     """
     if impl:
         name = str(impl)
@@ -328,15 +248,6 @@ def _resolve_impl(d: int, n_updates: int, impl=None, dtype=jnp.float32) -> str:
                 f"unknown sparse scatter impl {name!r}; "
                 f"expected one of {sorted(IMPLS)} "
             )
-    else:
-        name = os.environ.get(_ENV_KNOB, "").strip().lower()
-        if name == "auto":
-            name = ""
-        if name and name not in IMPLS:
-            raise ValueError(
-                f"{_ENV_KNOB}={name!r}: expected {sorted(IMPLS) + ['auto']}"
-            )
-    if name:
         if name == "plan" and not plan_fits(d, n_updates, dtype):
             raise ValueError(
                 f"sparse impl 'plan' needs d + updates < 2^31 and 4-byte "
@@ -348,8 +259,6 @@ def _resolve_impl(d: int, n_updates: int, impl=None, dtype=jnp.float32) -> str:
     winner = lookup_winner(jax.default_backend(), d, n_updates)
     if winner == "plan" and plan_fits(d, n_updates, dtype):
         return "plan"
-    # an uncalibrated backend, a plan that does not fit, a name that is not
-    # exact: the plain pair
     return "scatter"
 
 
@@ -367,10 +276,9 @@ def sparse_update(
     d, n = int(w.shape[0]), int(idx.size)
     name = _resolve_impl(d, n, impl, w.dtype)
     if name != "plan":
-        scatter = _SCATTERS[name]
         return (
             sparse_matvec(w, idx, val),
-            lambda w2, coef: scatter(w2, idx, coef, val),
+            lambda w2, coef: sparse_scatter_add(w2, idx, coef, val),
             None,
         )
     plan = index_plan(idx)

@@ -1,18 +1,12 @@
 """Calibration harness for the sparse update's dispatch.
 
 A sparse linear learner's update is a gather (the margins) and a scatter-add
-(the update) over the same indices; ``ops.sparse.sparse_update`` has three
+(the update) over the same indices; ``ops.sparse.sparse_update`` has two
 formulations of the pair with very different cost models:
 
 - ``scatter``: ``jnp.take`` and XLA's native scatter-add, slot by slot: the
   natural form everywhere, and on the TPU a cost per slot (gather) and per
   distinct address (scatter) whatever the addresses;
-- ``mxu``: the same gather with the kron-factored one-hot matmul scatter
-  (``sparse_scatter_add_mxu``): ~2*2*D FLOPs per update, fastest only where
-  the chip's matmul rate beats the scatter's element rate times D. It
-  rounds every update's low half to bfloat16, so it is timed for the
-  record and never named a winner: the table decides a default, and a
-  default stays exact in float32;
 - ``plan``: one sort of the launch's indices shared by both halves
   (``index_plan``): duplicates combined, the weight vector addressed once
   per distinct index, everything else by sorts and scans. Wins where a
@@ -21,7 +15,7 @@ formulations of the pair with very different cost models:
   distinct addresses than a quarter of its slots runs the plain pair
   behind the plan's first sort.
 
-This module measures all three over a (D, batch, nnz) grid with a
+This module measures both over a (D, batch, nnz) grid with a
 hashed-categorical duplicate profile (each COO slot draws from a ~1k-value
 vocabulary, the Criteo/Avazu shape the sparse path exists for), timing the
 update AS THE LEARNER RUNS IT (margins, a coefficient that depends on
@@ -34,10 +28,8 @@ at trace time (nearest grid point in log2 space). Re-run on new hardware:
 
 Off the CPU the full grid also holds the shapes the benchmark's cells run
 (``CELL_GRID``: 2^28 + 14 weights; a 1 GiB vector is not for a shared CPU
-host). Writes merge per backend, so a TPU calibration does not clobber the
-CPU section. ``OMLDM_SPARSE_SCATTER_TABLE`` points the lookup (and the
-writer) at an alternate table path; ``OMLDM_SPARSE_SCATTER`` bypasses the
-table entirely (ops/sparse.py).
+host). Writes merge per backend, so one backend's calibration does not
+clobber another's section; ``--out`` writes an alternate table.
 """
 
 from __future__ import annotations
@@ -52,24 +44,13 @@ from typing import Dict, List, Optional
 import numpy as np
 
 DEFAULT_TABLE = os.path.join(os.path.dirname(__file__), "sparse_dispatch.json")
-ENV_TABLE = "OMLDM_SPARSE_SCATTER_TABLE"
-
-# skip a kernel whose intermediate working set would not fit a modest host
-# (the mxu one-hot operands are [2n, D/512 + 512] bf16 — at D=2^20 and
-# n=160k that is >1 GB, pointless to measure on CPU and an OOM risk in CI)
-MXU_BYTES_CAP = 1 << 28
-
-
-def table_path() -> str:
-    return os.environ.get(ENV_TABLE, "").strip() or DEFAULT_TABLE
-
 
 _cache: Dict[str, object] = {"path": None, "mtime": None, "table": None}
 
 
 def load_table(path: Optional[str] = None) -> Optional[dict]:
     """Cached table read (mtime-invalidated; None when absent/corrupt)."""
-    path = path or table_path()
+    path = path or DEFAULT_TABLE
     try:
         mtime = os.path.getmtime(path)
     except OSError:
@@ -120,8 +101,8 @@ def lookup_winner(backend: str, d: int, n_updates: int) -> Optional[str]:
 def _gen_updates(d: int, batch: int, nnz: int, seed: int = 0):
     """Hashed-categorical update profile: each COO slot draws from its own
     ~1k-value vocabulary inside [0, d) — the duplicate structure of the
-    Criteo/Avazu streams (benchmarks/run_benchmarks.py stream gen), which
-    is exactly what the index plan exists to exploit."""
+    Criteo/Avazu streams, which is exactly what the index plan exists to
+    exploit."""
     rng = np.random.RandomState(seed)
     vocab_n = min(1000, max(d // nnz, 2))
     idx = np.empty((batch, nnz), np.int32)
@@ -166,22 +147,16 @@ def _measure_kernel(name: str, d: int, idx, val, coef, steps: int,
 
 
 def measure_entry(d: int, batch: int, nnz: int, steps: int) -> dict:
-    from omldm_tpu.ops.sparse import EXACT_IMPLS, IMPLS, MXU_LANES
+    from omldm_tpu.ops.sparse import IMPLS
 
     idx, val, coef = _gen_updates(d, batch, nnz)
     n = idx.size
-    rates: Dict[str, Optional[float]] = {}
-    for name in IMPLS:
-        if name == "mxu":
-            r = -(-d // MXU_LANES)
-            est = 2 * (2 * n) * (r + MXU_LANES)  # bf16 one-hot operands
-            if est > MXU_BYTES_CAP:
-                rates[name] = None
-                continue
-        rates[name] = round(_measure_kernel(name, d, idx, val, coef, steps), 1)
-    # the winner is what sparse_update runs by DEFAULT there, so it is
-    # chosen among the exact formulations; mxu's rate is for the record
-    winner = max(EXACT_IMPLS, key=rates.__getitem__)
+    rates = {
+        name: round(_measure_kernel(name, d, idx, val, coef, steps), 1)
+        for name in IMPLS
+    }
+    # what sparse_update runs by DEFAULT there
+    winner = max(IMPLS, key=rates.__getitem__)
     dup = n / max(len(np.unique(idx)), 1)
     return {
         "d": d,
@@ -224,7 +199,7 @@ def calibrate(grid: List[tuple], steps: int, out: Optional[str] = None,
             f"dup={e['duplicate_factor']}x -> {e['winner']} "
             f"{e['rates_updates_per_sec']}"
         )
-    out = out or table_path()
+    out = out or DEFAULT_TABLE
     table = load_table(out) or {"version": 1, "backends": {}}
     table["note"] = (
         "sparse update dispatch crossover table — generated by "
@@ -257,7 +232,7 @@ def main(argv=None) -> None:
         "sides of the guessed crossover",
     )
     ap.add_argument("--out", default=None, help="table path (default: "
-                    "$OMLDM_SPARSE_SCATTER_TABLE or ops/sparse_dispatch.json)")
+                    "ops/sparse_dispatch.json)")
     ap.add_argument("--steps", type=int, default=None,
                     help="chained kernel applications per timing sample")
     args = ap.parse_args(argv)

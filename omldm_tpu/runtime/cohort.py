@@ -767,12 +767,20 @@ class Cohort:
         result, self._next_result = self._next_result, None
         t_pad = _pow2(max(counts.values()))
         self._note_launch(min(counts))
+        # the staged rows are COPIED off the staging buffers before the
+        # dispatch: it is async and jax may alias a numpy argument
+        # zero-copy (the CPU backend does where the view happens to be
+        # aligned), while the buffers' masks are re-zeroed right below and
+        # the rows refilled by the next stage_fit — without the copy a
+        # launch could read zeros for rows it was given (seen as holes of
+        # 0.0 in a learning curve and untrained batches, on some runs)
         if shared:
             # one [T, B, ...] input for the whole cohort: the conversion
             # cost stops scaling with the member count
-            xs = self._buf_x[lead, :t_pad]
-            ys = self._buf_y[lead, :t_pad]
-            ms = self._buf_m[lead, :t_pad]
+            xs, ys, ms = (
+                buf[lead, :t_pad].copy()
+                for buf in (self._buf_x, self._buf_y, self._buf_m)
+            )
             active = np.zeros((self.capacity,), np.bool_)
             active[list(counts)] = True
             with self._timed():
@@ -785,12 +793,11 @@ class Cohort:
         else:
             # sharded cohorts ship each device its own contiguous block of
             # the slot-major staging buffers (_stage_dev); unsharded, the
-            # numpy views go straight to the dispatch. Either way the
-            # transfer copies before the call returns, so reusing the
-            # staging buffers after is safe
-            xs = self._stage_dev(self._buf_x[:, :t_pad])
-            ys = self._stage_dev(self._buf_y[:, :t_pad])
-            ms = self._stage_dev(self._buf_m[:, :t_pad])
+            # arrays go straight to the dispatch
+            xs, ys, ms = (
+                self._stage_dev(buf[:, :t_pad].copy())
+                for buf in (self._buf_x, self._buf_y, self._buf_m)
+            )
             with self._timed():
                 self.stacked, losses = self._gfit(self.stacked, xs, ys, ms)
             # re-zero ONLY the staged mask region: everything else is

@@ -13,13 +13,7 @@ one PYTHON PROCESS per host, joined through ``jax.distributed``:
 - each process owns an ingest partition (a strided slice of a shared file,
   or an assigned set of Kafka partitions — the role of Flink's per-subtask
   Kafka partition assignment, KafkaUtils.scala:11-31) and stages rows for
-  its own mesh shard. The SINGLE-driver analogue of this striping is the
-  sharded ingest plane (runtime/ingest_shard.py): there the stripes are
-  byte-grid file chunks (chunk k -> worker k % N), the consumers are
-  parser processes feeding ONE driver through shared-memory rings, and
-  the driver's ascending-chunk replay keeps row order bit-identical to a
-  single process — where this module's stripes feed N independent mesh
-  shards and order is per-stripe;
+  its own mesh shard;
 - each batch is assembled into ONE globally-sharded array with
   ``host_local_array`` and trained by the standard :class:`SPMDTrainer`
   step — protocol sync is the same XLA collective whether the workers

@@ -60,34 +60,6 @@ def records_to_events(
         yield (stream, r)
 
 
-def sharded_packed_events(
-    path: str,
-    dim: int,
-    cfg: Any,
-    hash_dims: int = 0,
-    stream: str = "__packed__",
-    on_degrade: Any = None,
-) -> Iterator[Tuple[str, Any]]:
-    """The sharded ingest plane (runtime/ingest_shard.py) as PACKED-stream
-    events, for callers that drive the generic event loop — supervised
-    recovery replay, interleaved request/data sources — instead of
-    StreamJob.run_file_sharded's direct block loop. ``cfg`` is an
-    ``IngestConfig`` (see ``parse_ingest_spec``); blocks arrive in exact
-    stream order, so replay determinism matches ``file_events`` + a
-    single-process parser. The worker fleet is torn down when the
-    iterator is exhausted or released."""
-    from omldm_tpu.runtime.ingest_shard import ShardedIngest
-
-    si = ShardedIngest(
-        path, dim, cfg, hash_dims=hash_dims, on_degrade=on_degrade
-    )
-    try:
-        for block in si.blocks():
-            yield (stream, block)
-    finally:
-        si.close()
-
-
 def jsonl_dumps(objs: Iterable[Any]) -> str:
     """Serialize objects (with .to_dict) to a JSON-lines string + EOS."""
     lines = [json.dumps(o.to_dict() if hasattr(o, "to_dict") else o) for o in objs]

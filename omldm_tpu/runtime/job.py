@@ -101,15 +101,6 @@ class StreamJob:
 
         self.events = None
         _ev_cfg = parse_events_spec(getattr(self.config, "events", ""))
-        # ingest plane (runtime/ingest_shard.py): armed by the job-wide
-        # JobConfig.ingest spec (fail-fast on a malformed one). Unarmed
-        # (the default): the attribute stays None, zero ingest objects
-        # exist, and run_file takes the exact pre-plane routes.
-        from omldm_tpu.runtime.ingest_shard import parse_ingest_spec
-
-        self.ingest_cfg = parse_ingest_spec(getattr(self.config, "ingest", ""))
-        # last sharded run's worker/driver accounting (run_file_sharded)
-        self._ingest_stats: Optional[dict] = None
         self.stats = StatisticsCollector(self.config, self._emit_performance)
         # dead-letter quarantine: malformed / validation-rejected records
         # and requests land here with reason codes instead of vanishing
@@ -1361,115 +1352,15 @@ class StreamJob:
         return bridge if bridge.supports_fused_ingest() else None
 
     def run_file_fused(self, path: str) -> bool:
-        """Consume a JSON-lines training file through the fused C ingest
-        (SPMDBridge.ingest_file). Returns False when the job does not
-        qualify — callers fall back to the packed event route. Non-paced
-        pipelines take the DOUBLE-BUFFERED route (the parse thread fills
-        stage k+1 while the dispatch thread trains stage k; results are
-        bit-identical to the serial loop, tests/test_overlap.py)."""
+        """Consume a JSON-lines training file through the bridge's file
+        route (SPMDBridge.ingest_file: C parse, holdout and staging on
+        this thread, launches in order on a dispatch thread; an SSP
+        pipeline's launches on this thread). Returns False when the job
+        does not qualify — callers fall back to the packed event route."""
         bridge = self.fused_file_bridge()
         if bridge is None:
             return False
-        if bridge.supports_overlapped_ingest():
-            bridge.ingest_file_overlapped(
-                path, on_chunk=self.stats.mark_activity
-            )
-        else:
-            bridge.ingest_file(path, on_chunk=self.stats.mark_activity)
-        return True
-
-    def run_file(
-        self, path: str, dim: Optional[int] = None, hash_dims: int = 0
-    ) -> bool:
-        """File-consumption router: the sharded multi-process ingest plane
-        when JobConfig.ingest is armed, else the fused C route. Returns
-        False when no route qualifies — callers fall back to the packed /
-        per-record event loops (exact pre-plane behavior)."""
-        if self.ingest_cfg is not None:
-            return self.run_file_sharded(path, dim=dim, hash_dims=hash_dims)
-        return self.run_file_fused(path)
-
-    def run_file_sharded(
-        self, path: str, dim: Optional[int] = None, hash_dims: int = 0
-    ) -> bool:
-        """Consume a JSON-lines training file through the sharded ingest
-        plane: N parser processes stripe the file's byte-grid chunks and
-        hand packed row blocks back through shared-memory rings; the
-        driver replays them in ascending chunk order through
-        process_packed_batch, so row order — and therefore every fitted /
-        holdout / prediction sequence — is bit-identical to single-process
-        ingest. With ``device=on`` in the spec, qualifying SPMD bridges
-        additionally keep their stage + holdout ring device-resident.
-
-        A dead parser process degrades to in-process ingest from the
-        wounded chunk onward (reason-coded through the selfheal
-        classification and the flight recorder) instead of wedging the
-        driver. While the run is live, driver starvation and prefetch-ring
-        emptiness feed every armed overload controller as extra_signals
-        probes, so a slow parser shard raises the overload level."""
-        from omldm_tpu.runtime import events as _ev
-        from omldm_tpu.runtime.ingest_shard import ShardedIngest
-        from omldm_tpu.runtime.prefetch import Prefetcher
-
-        if self.ingest_cfg is None:
-            return False
-        if dim is None:
-            if not self._dims:
-                return False
-            dim = next(iter(self._dims.values()))
-        self.ensure_deployed(dim)
-        if self.ingest_cfg.device:
-            for bridge in self.spmd_bridges.values():
-                arm = getattr(bridge, "enable_resident_ingest", None)
-                if arm is not None:
-                    arm()  # bridges the resident path can't serve stay host
-
-        def on_degrade(info: dict) -> None:
-            rec = self.events
-            if rec is not None:
-                rec.journal.record(
-                    _ev.DEGRADE,
-                    f"ingest_worker_{info['class']}",
-                    worker=info["worker"],
-                    returncode=info["returncode"],
-                    chunk=info["chunk"],
-                )
-
-        si = ShardedIngest(
-            path, dim, self.ingest_cfg, hash_dims=hash_dims,
-            on_degrade=on_degrade,
-        )
-        pf = Prefetcher(si.blocks(), depth=2)
-        probes = {
-            "ingest_starvation": lambda: (si.starvation(), 0.5, 0.9),
-            "ingest_prefetch": pf.as_signal(),
-        }
-        for name, fn in probes.items():
-            for spoke in self.spokes:
-                spoke.attach_ingest_probe(name, fn)
-        try:
-            for x, y, op in pf:
-                self.process_packed_batch(x, y, op)
-        finally:
-            pf.close()
-            si.close()
-            for name in probes:
-                for spoke in self.spokes:
-                    spoke.detach_ingest_probe(name)
-            st = si.stats()
-            if si.degraded is not None:
-                st["degraded"] = dict(si.degraded)
-            self._ingest_stats = st
-            # phase attribution: the shards' parse clock folds into the
-            # telemetry profile's "parse" ring and the driver's ring-wait
-            # into "read" (worker parse seconds are summed ACROSS shard
-            # processes — on a multi-core host they overlap wall time)
-            tel = self.telemetry
-            if tel is not None and tel.phases is not None:
-                if st["parse_s"] > 0:
-                    tel.phases.note("parse", st["parse_s"])
-                if st["driver_wait_s"] > 0:
-                    tel.phases.note("read", st["driver_wait_s"])
+        bridge.ingest_file(path, on_chunk=self.stats.mark_activity)
         return True
 
     # --- run loops ---

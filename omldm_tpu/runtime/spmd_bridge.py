@@ -16,11 +16,11 @@ bytesShipped/modelsShipped accounting from the collective call sites.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Callable, List, Optional, Tuple
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from omldm_tpu.api.data import FORECASTING, DataInstance, Prediction
@@ -39,190 +39,6 @@ from omldm_tpu.utils import tracing
 # flush remainders pad to this sub-batch instead of a full dp*B group
 # (a 1-row tail no longer ships half a megabyte of zeros)
 TAIL_BATCH = 256
-
-
-def _resident_absorb(sx, sy, hx, hy, bx, by, ev_slot, ev_dst, keep_src,
-                     keep_dst, hold_dst):
-    """One device-resident ingest segment: gather the holdout rows the
-    segment evicts (before their slots are overwritten), scatter them and
-    the kept rows into the stage at their stream-order ranks, and scatter
-    the segment's test rows into their holdout ring slots. All index
-    arrays are host-computed; padding lanes carry out-of-range
-    destinations, which ``mode="drop"`` discards."""
-    sx = sx.at[ev_dst].set(hx[ev_slot], mode="drop")
-    sy = sy.at[ev_dst].set(hy[ev_slot], mode="drop")
-    sx = sx.at[keep_dst].set(bx[keep_src], mode="drop")
-    sy = sy.at[keep_dst].set(by[keep_src], mode="drop")
-    hx = hx.at[hold_dst].set(bx, mode="drop")
-    hy = hy.at[hold_dst].set(by, mode="drop")
-    return sx, sy, hx, hy
-
-
-def _resident_seg_rows(hold_cap: int, test_enabled: bool) -> int:
-    """Segment width for the resident kernel. Scatter destinations must be
-    distinct within one call, so a segment may not carry more test rows
-    than the holdout ring holds; the worst case over cycle phases for a
-    window of m rows is 2*(m//10) + min(m%10, 2)."""
-    if not test_enabled:
-        return 4096
-    m = 5 * hold_cap
-    while m > 1 and (2 * (m // 10) + min(m % 10, 2)) > hold_cap:
-        m -= 1
-    return max(m, 1)
-
-
-class _ResidentIngest:
-    """Device-resident stage + holdout for :class:`SPMDBridge`.
-
-    When armed (``JobConfig.ingest`` with ``device:on``), the staging pad
-    and the holdout ring live as jax arrays; the host computes only the
-    O(n) index arithmetic per block (the exact ``_train_rows`` /
-    ``ArrayHoldout.append_many`` semantics, counters stay host-side) and
-    one jitted gather/scatter moves the rows. A full stage launches
-    ``step_many_dense`` directly on the resident arrays — no host staging
-    copy, no per-batch holdout filtering on the host. Partial drains
-    (flush/snapshot) sync back through the bridge's ordinary host path so
-    the fitted/holdout row order stays bit-identical to the unarmed
-    route."""
-
-    def __init__(self, bridge: "SPMDBridge"):
-        self.bridge = bridge
-        self.seg = _resident_seg_rows(
-            bridge.test_set.max_size, bool(bridge.config.test)
-        )
-        self.sx = jnp.zeros((bridge._stage_cap, bridge.dim), jnp.float32)
-        self.sy = jnp.zeros((bridge._stage_cap,), jnp.float32)
-        self.hx = jnp.asarray(bridge.test_set._x)
-        self.hy = jnp.asarray(bridge.test_set._y)
-        self._kernel = jax.jit(_resident_absorb, donate_argnums=(0, 1, 2, 3))
-
-    # --- hot path ---
-
-    def absorb(self, x: np.ndarray, y: np.ndarray) -> None:
-        """Resident twin of ``_train_rows`` + ``_stage_rows``: identical
-        holdout cycle, eviction order, and stage fill order, with the row
-        movement on device."""
-        br = self.bridge
-        ts = br.test_set
-        n = x.shape[0]
-        x = np.ascontiguousarray(x, np.float32)
-        y = np.ascontiguousarray(y, np.float32)
-        cap = br._stage_cap
-        H = ts.max_size
-        i = 0
-        while i < n:
-            m = min(self.seg, n - i)
-            if br.config.test:
-                c = (br.holdout_count + np.arange(m)) % 10
-                test_mask = c >= 8
-                # a test row emits a train row only once the ring is full
-                # at its turn (it evicts the oldest holdout point)
-                free = H - ts._n
-                emits = np.where(test_mask, np.cumsum(test_mask) > free, True)
-            else:
-                test_mask = np.zeros(m, bool)
-                emits = np.ones(m, bool)
-            train_cum = np.cumsum(emits)
-            room = cap - br._stage_n
-            if train_cum.size and train_cum[-1] > room:
-                # split where the stage fills exactly; trailing rows that
-                # emit nothing may ride along (harmless), emitters may not
-                m = int(np.searchsorted(train_cum, room, side="right"))
-                test_mask = test_mask[:m]
-            t_idx = np.nonzero(test_mask)[0]
-            keep_idx = np.nonzero(~test_mask)[0]
-            fill = min(H - ts._n, t_idx.size)
-            k2 = t_idx.size - fill
-            head = ts._head
-            slot_fill = (head + ts._n + np.arange(fill)) % H
-            slot_ev = (head + np.arange(k2)) % H
-            hold_dst = np.full(self.seg, H, np.int32)
-            hold_dst[t_idx[:fill]] = slot_fill
-            hold_dst[t_idx[fill:]] = slot_ev
-            # evicted points re-enter training at the evicting row's slot:
-            # same stable order as _train_rows' argsort re-merge
-            pos = np.concatenate([keep_idx, t_idx[fill:]])
-            order = np.argsort(pos, kind="stable")
-            rank = np.empty(pos.size, np.int64)
-            rank[order] = np.arange(pos.size)
-            base = br._stage_n
-            keep_src = np.zeros(self.seg, np.int32)
-            keep_dst = np.full(self.seg, cap, np.int32)
-            keep_src[: keep_idx.size] = keep_idx
-            keep_dst[: keep_idx.size] = base + rank[: keep_idx.size]
-            ev_slot = np.zeros(self.seg, np.int32)
-            ev_dst = np.full(self.seg, cap, np.int32)
-            ev_slot[:k2] = slot_ev
-            ev_dst[:k2] = base + rank[keep_idx.size :]
-            bx = np.zeros((self.seg, br.dim), np.float32)
-            by = np.zeros((self.seg,), np.float32)
-            bx[:m] = x[i : i + m]
-            by[:m] = y[i : i + m]
-            self.sx, self.sy, self.hx, self.hy = self._kernel(
-                self.sx, self.sy, self.hx, self.hy,
-                bx, by, ev_slot, ev_dst, keep_src, keep_dst, hold_dst,
-            )
-            ts._n += fill
-            ts._head = (head + k2) % H
-            br.holdout_count += m
-            br._stage_n = base + pos.size
-            if br._stage_n >= cap:
-                self._launch_full()
-            i += m
-
-    def _launch_full(self) -> None:
-        br = self.bridge
-        b = br.config.batch_size
-        xs = self.sx.reshape(br.chain, br.dp, b, br.dim)
-        ys = self.sy.reshape(br.chain, br.dp, b)
-        br.trainer.step_many_dense(xs, ys)
-        br._stage_n = 0
-
-    # --- drains / sync (rare paths go through the host route) ---
-
-    def drain_to_host(self) -> None:
-        """Flush a partial stage through the bridge's host tail path
-        (whole [dp, B] groups + padded TAIL_BATCH remainder) so partial
-        launches are bit-identical to the unarmed route."""
-        br = self.bridge
-        n = br._stage_n
-        br._stage_n = 0
-        if n == 0:
-            return
-        br._train_buffer(np.asarray(self.sx[:n]), np.asarray(self.sy[:n]), n)
-
-    def sync_host(self) -> None:
-        """Copy the resident holdout/stage back into the host mirrors
-        (checkpoint snapshots read them)."""
-        br = self.bridge
-        ts = br.test_set
-        ts._x[...] = np.asarray(self.hx)
-        ts._y[...] = np.asarray(self.hy)
-        n = br._stage_n
-        br._stage_x[:n] = np.asarray(self.sx[:n])
-        br._stage_y[:n] = np.asarray(self.sy[:n])
-
-    def push_from_host(self) -> None:
-        """Re-upload the host mirrors (checkpoint restore writes them)."""
-        br = self.bridge
-        self.hx = jnp.asarray(br.test_set._x)
-        self.hy = jnp.asarray(br.test_set._y)
-        self.sx = jnp.asarray(br._stage_x, jnp.float32)
-        self.sy = jnp.asarray(br._stage_y, jnp.float32)
-
-    def eval_arrays(self):
-        """Holdout eval inputs straight from the resident ring — same
-        oldest-first order and zero padding as ``ArrayHoldout.arrays`` +
-        the host pad, without the device round trip."""
-        ts = self.bridge.test_set
-        cap = ts.max_size
-        idx = jnp.asarray((ts._head + np.arange(cap)) % cap)
-        mask = jnp.asarray(
-            (np.arange(cap) < ts._n).astype(np.float32)
-        )
-        xs = jnp.where(mask[:, None] > 0, self.hx[idx], 0.0)
-        ys = jnp.where(mask > 0, self.hy[idx], 0.0)
-        return xs, ys, mask
 
 
 def spmd_engine_requested(request: Request) -> bool:
@@ -285,32 +101,16 @@ def _line_aligned_chunks(path: str, chunk_bytes: int, start_offset: int = 0):
             yield buf, carry + 1
 
 
-def _in_ingest_file_span(ingest):
-    """Run a bridge's overlapped file route inside the ``ingest_file`` span
-    of its file, counting the training rows (fitted or held out) the file
-    brought."""
-
-    @functools.wraps(ingest)
-    def traced(self, *args, **kwargs):
-        rows = self.holdout_count
-        with tracing.span("ingest_file") as span:
-            try:
-                return ingest(self, *args, **kwargs)
-            finally:
-                span.add(rows=self.holdout_count - rows)
-
-    return traced
-
-
 class _OverlapDispatcher:
-    """Bounded producer/consumer scaffolding shared by the dense and
-    sparse double-buffered ingest routes: a pool of ``depth`` spare stage
-    sets bounds look-ahead memory (the parse thread blocks on ``swap``
-    when the device is behind), a work queue dispatches sets strictly in
-    order on one daemon thread, and worker exceptions surface to the
-    parse thread — the set returns to the pool even when the launch
-    raises, so the producer can never deadlock in ``swap`` instead of
-    seeing the error."""
+    """Bounded producer/consumer scaffolding of the file route: a pool of
+    ``depth`` spare stage sets bounds look-ahead memory (the parse thread
+    blocks in ``submit`` when the device is behind), a work queue
+    dispatches sets strictly in order on one daemon thread, and worker
+    exceptions surface to the parse thread — the set returns to the pool
+    even when the launch raises, so the producer can never deadlock in
+    ``submit`` instead of seeing the error. With ``depth`` 0 there is no
+    thread: ``submit`` launches on the calling thread and hands the same
+    set back, so ``quiesce`` has nothing to wait for."""
 
     def __init__(self, make_set, depth: int, train):
         import queue
@@ -320,12 +120,15 @@ class _OverlapDispatcher:
         # the dispatch thread's spans name it as their parent
         cause = tracing.current()
         with tracing.span("dispatcher_open"):
-            self.pool: "queue.Queue" = queue.Queue()
-            for _ in range(max(depth, 1)):
-                self.pool.put(make_set())
-            self.work: "queue.Queue" = queue.Queue()
             self.errors: List[BaseException] = []
             self._train = train
+            self._thread = None
+            if depth <= 0:
+                return
+            self.pool: "queue.Queue" = queue.Queue()
+            for _ in range(depth):
+                self.pool.put(make_set())
+            self.work: "queue.Queue" = queue.Queue()
 
             def worker():
                 tracing.adopt(cause)
@@ -353,6 +156,9 @@ class _OverlapDispatcher:
         """Queue a filled set, return a fresh one from the pool. Raises
         any pending worker error instead of queueing more work onto a
         dead pipeline."""
+        if self._thread is None:
+            self._train(stage_set, n)
+            return stage_set
         if self.errors:
             raise self.errors[0]
         self.work.put((stage_set, n))
@@ -363,14 +169,16 @@ class _OverlapDispatcher:
         """Drain the queue (producer-side trainer access needs the worker
         idle); re-raise any worker error."""
         with tracing.span("quiesce"):
-            self.work.join()
+            if self._thread is not None:
+                self.work.join()
         if self.errors:
             raise self.errors[0]
 
     def close(self) -> None:
         with tracing.span("dispatcher_close"):
-            self.work.put(None)
-            self._thread.join()
+            if self._thread is not None:
+                self.work.put(None)
+                self._thread.join()
 
     def raise_pending(self) -> None:
         if self.errors:
@@ -440,8 +248,10 @@ class SPMDBridge:
         self._stage_x = np.zeros((self._stage_cap, dim), self.feed_dtype)
         self._stage_y = np.zeros((self._stage_cap,), self.feed_dtype)
         self._stage_n = 0
-        # armed by enable_resident_ingest() (JobConfig.ingest device:on)
-        self._resident: Optional[_ResidentIngest] = None
+        # the C driver over the current stage set (made on first use) and,
+        # for the length of a file, the dispatcher its launches go through
+        self._fused = None
+        self._dispatcher: Optional[_OverlapDispatcher] = None
 
     # --- data path ---
 
@@ -460,8 +270,7 @@ class SPMDBridge:
             else min(max(float(inst.target), -F32_MAX), F32_MAX)
         )
         # 20% holdout: counts 8,9 of each 0-9 cycle (FlinkSpoke.scala:94-104)
-        # — the single-record case of _train_rows (which also routes through
-        # the resident stage when armed)
+        # — the single-record case of _train_rows
         self._train_rows(x[None, :], np.asarray([y], np.float32))
 
     def handle_batch(
@@ -508,9 +317,6 @@ class SPMDBridge:
         n = x.shape[0]
         if n == 0:
             return
-        if self._resident is not None:
-            self._resident.absorb(x, y)
-            return
         if self.config.test:
             c = (self.holdout_count + np.arange(n)) % 10
             self.holdout_count += n
@@ -541,30 +347,49 @@ class SPMDBridge:
             self._stage_n += take
             i += take
             if self._stage_n >= self._stage_cap:
-                self._train_staged(full=True)
+                self._train_staged()
 
-    def _train_staged(self, full: bool = False) -> None:
-        """Launch the staged rows of the bridge's own stage buffer."""
-        if self._resident is not None:
-            self._resident.drain_to_host()
-            return
+    # --- the stage set (what differs between the bridges' stages) ---
+
+    def _stage_set(self) -> tuple:
+        return (self._stage_x, self._stage_y)
+
+    def _adopt_stage_set(self, stage_set: tuple) -> None:
+        self._stage_x, self._stage_y = stage_set
+        self._fused = None  # the C driver is bound to the arrays
+
+    def _launch_stage_set(self, stage_set: tuple, n: int) -> None:
+        self._train_buffer(*stage_set, n)
+
+    def _spare_stage_set(self) -> tuple:
+        return tuple(np.zeros_like(a) for a in self._stage_set())
+
+    def _train_staged(self) -> None:
+        """Launch the staged rows: while a file is being ingested through
+        its dispatcher (which hands back the set to fill next), else on
+        the calling thread."""
         n = self._stage_n
+        if n == 0:
+            return
         self._stage_n = 0
-        self._train_buffer(self._stage_x, self._stage_y, n, full)
+        if self._dispatcher is not None:
+            self._adopt_stage_set(
+                self._dispatcher.submit(self._stage_set(), n)
+            )
+        else:
+            self._launch_stage_set(self._stage_set(), n)
 
     def _train_buffer(
-        self, buf_x: np.ndarray, buf_y: np.ndarray, n: int, full: bool = False
+        self, buf_x: np.ndarray, buf_y: np.ndarray, n: int
     ) -> None:
         """Launch ``n`` staged rows from an EXPLICIT buffer pair (the
-        double-buffered ingest owns several): a full stage is one chained
+        file route's dispatcher owns several): a full stage is one chained
         mask-free step_many_dense launch of ``chain`` [dp, B, D] steps (the
         stage buffer is exactly chain*dp*B rows, so every row is valid and
         no mask ships); a partial stage (flush) runs whole [dp, B] groups
         as single steps and the remainder through a small [dp, TAIL_B]
         padded step instead of padding a whole dp*B group for a handful of
         rows."""
-        if n == 0:
-            return
         # COPY before handing rows to the device: dispatch is async and
         # jax may alias numpy argument buffers zero-copy (observed on the
         # CPU backend — reusing the stage buffer mid-read corrupted rows
@@ -572,7 +397,7 @@ class SPMDBridge:
         # the reused stage anyway. The memcpy is small next to the parse.
         b = self.config.batch_size
         group = self.dp * b
-        if full and not self._paced:
+        if n == self._stage_cap and not self._paced:
             xs = np.array(buf_x, copy=True).reshape(
                 self.chain, self.dp, b, self.dim
             )
@@ -662,8 +487,6 @@ class SPMDBridge:
 
     def snapshot_buffers(self) -> dict:
         """Holdout + staged rows for a job checkpoint."""
-        if self._resident is not None:
-            self._resident.sync_host()
         test_x, test_y = self.test_set.arrays()
         return {
             "test_x": test_x.copy(),
@@ -677,63 +500,35 @@ class SPMDBridge:
         }
 
     def restore_buffers(self, bd: dict) -> None:
-        if self._resident is not None:
-            # restore on the host mirrors (the rare path), then re-upload
-            res, self._resident = self._resident, None
-            res.sync_host()
-            try:
-                self.restore_buffers(bd)
-            finally:
-                self._resident = res
-                res.push_from_host()
-            return
         if bd["test_x"].shape[0]:
             self.test_set.append_many(bd["test_x"], bd["test_y"])
         if bd["stage_x"].shape[0]:
             self._stage_rows(bd["stage_x"], bd["stage_y"])
 
-    # --- fused file ingest (C parse -> holdout -> stage, zero numpy) ---
+    # --- the file route (C parse -> holdout -> stage, launches in order) ---
+
+    # bytes of a file read and parsed at a time
+    CHUNK_BYTES = 1 << 22
 
     def supports_fused_ingest(self) -> bool:
         """The fused C loop writes float32 rows straight into the staging
         buffers; fp16 feeds and missing-toolchain hosts use the packed
-        numpy route instead. A resident stage lives on device — the C loop
-        cannot write it, so the packed route (which feeds _train_rows and
-        thereby the resident kernel) carries those jobs."""
+        numpy route instead."""
         from omldm_tpu.ops.native import fast_parser_available
 
-        return (
-            self.feed_dtype == np.float32
-            and self._resident is None
-            and fast_parser_available()
-        )
+        return self.feed_dtype == np.float32 and fast_parser_available()
 
-    # --- device-resident stage/holdout (JobConfig.ingest device:on) ---
-
-    def supports_resident_ingest(self) -> bool:
-        """Resident stage/holdout needs the chained mask-free launch path:
-        float32 feed, no SSP pacing (refused rows must re-enter a host
-        stage)."""
-        return self.feed_dtype == np.float32 and not self._paced
-
-    def enable_resident_ingest(self) -> bool:
-        """Arm the device-resident stage + holdout ring. Returns False
-        (and stays on the host route) for bridges the resident path cannot
-        serve. Safe to call before any data flows; arming mid-stream would
-        strand staged host rows, so it is refused then."""
-        if self._resident is not None:
-            return True
-        if not self.supports_resident_ingest():
-            return False
-        if self._stage_n or len(self.test_set):
-            return False
-        self._resident = _ResidentIngest(self)
-        return True
+    def supports_overlapped_ingest(self) -> bool:
+        """Whether the file route's launches run on a dispatch thread
+        beside the parse. SSP's launches re-enter refused rows into the
+        stage, so they run on the calling thread."""
+        return self.supports_fused_ingest() and not self._paced
 
     def _fused_stage(self):
+        """The C driver over the current stage set and the holdout ring."""
         from omldm_tpu.ops.native import FusedStage
 
-        if getattr(self, "_fused", None) is None:
+        if self._fused is None:
             hash_dims = int(
                 self.request.training_configuration.extra.get("hashDims", 0)
             )
@@ -747,162 +542,103 @@ class SPMDBridge:
             )
         return self._fused
 
-    def ingest_file(
-        self, path: str, chunk_bytes: int = 1 << 22, on_chunk=None
-    ) -> None:
-        """Stream a JSON-lines file through the fused C ingest: every
-        fast-schema line is parsed DIRECTLY into its staging slot and
-        holdout-split in C (exact handle_batch semantics, pinned by
-        tests/test_fused_ingest.py); only stage launches, Python-codec
-        fallback lines and forecasts return to Python. This is the e2e
-        hot path — one pass, no per-row numpy.
-
-        Reference counterpart: the whole-job per-record hot loop
-        Job.scala:42-70 -> FlinkSpoke.scala:92-107."""
+    @contextlib.contextmanager
+    def _c_driver(self):
+        """The C driver over the CURRENT stage set (a launch swaps the set,
+        and the driver follows it) with the mutable cursors synced in
+        (Python code between two C calls, and SSP requeue inside a launch,
+        may have moved them) and synced out after the call."""
         fs = self._fused_stage()
-        for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-            self._fused_consume(fs, buf, 0, stop)
-            if on_chunk is not None:
-                on_chunk()
+        ctx = fs.ctx
+        ctx.stage_n = self._stage_n
+        ctx.hold_n = self.test_set._n
+        ctx.hold_head = self.test_set._head
+        ctx.holdout_count = self.holdout_count
+        try:
+            yield fs
+        finally:
+            self._stage_n = int(ctx.stage_n)
+            self.test_set._n = int(ctx.hold_n)
+            self.test_set._head = int(ctx.hold_head)
+            self.holdout_count = int(ctx.holdout_count)
 
-    def supports_overlapped_ingest(self) -> bool:
-        """Double-buffered ingest needs chained launches (not SSP's paced
-        per-launch accept flags); both the dense fused stage and the
-        sparse COO route implement it. It holds ``depth`` extra stage
-        buffer sets (default 2: ~3x staging memory); set
-        trainingConfiguration extra ``{"overlappedIngest": false}`` to
-        keep the serial fused route on memory-tight hosts."""
-        flag = str(
-            self.request.training_configuration.extra.get(
-                "overlappedIngest", "true"
-            )
-        ).lower()
-        return (
-            self.supports_fused_ingest() and not self._paced
-            and flag != "false"
-        )
-
-    @_in_ingest_file_span
-    def ingest_file_overlapped(
-        self, path: str, chunk_bytes: int = 1 << 22, on_chunk=None,
-        depth: int = 2, train_fn=None,
+    def ingest_file(
+        self, path: str, chunk_bytes: Optional[int] = None, on_chunk=None,
+        depth: int = 2,
     ) -> None:
-        """DOUBLE-BUFFERED fused ingest: the C parse/holdout/stage loop
-        (which releases the GIL) fills stage buffer k+1 in the calling
-        thread while a dispatch thread ships and trains stage k — so the
-        measured wall clock of a run is max(parse, device) instead of
-        their sum, end to end. ``depth`` spare buffer pairs bound the
-        look-ahead (the parse thread blocks on a full queue, so memory
-        stays fixed). ``train_fn(sx, sy, n)`` overrides the launch for
-        calibrated device-stub measurements.
+        """Stream a JSON-lines file into the trainer: the calling thread
+        reads line-aligned chunks and parses, holdout-splits and stages
+        them in C (which releases the GIL) while a dispatch thread launches
+        every filled stage set, so a file costs max(parse, device) instead
+        of their sum. ``depth`` spare stage sets bound the look-ahead (the
+        parse blocks when every set is queued or in flight, so memory
+        stays fixed); with ``depth`` 0, which is what an SSP pipeline
+        always gets, the launches run on the calling thread.
 
-        Stages are dispatched strictly IN ORDER, so the training result is
-        bit-identical to :meth:`ingest_file` (pinned by
-        tests/test_overlap.py). Fallback lines and forecasts quiesce the
-        dispatch queue first, then run inline — the rare path stays
-        correct, the hot path never synchronizes.
+        Sets are launched strictly IN ORDER and the file's last, partial
+        set goes through the same queue, so the result is that of feeding
+        the lines one by one through :meth:`handle_data` (pinned by
+        tests/test_overlap.py, tests/test_fused_ingest.py). Fallback lines
+        and forecasts quiesce the queue first, then run on the calling
+        thread. The ``ingest_file`` span counts the training rows (fitted
+        or held out) the file brought.
 
         Reference counterpart: the pipelined whole-job hot path
         Job.scala:42-70 -> FlinkSpoke.scala:92-107 (Flink's operator
         chain keeps source/parse and the learner's fit concurrent across
         its task threads; this is the TPU-native two-thread form)."""
-        if self._paced:
-            raise ValueError(
-                "overlapped ingest requires chained launches; SSP's "
-                "per-launch accept flags force the serial path"
-            )
-        from omldm_tpu.ops.native import FusedStage
-
-        hash_dims = int(
-            self.request.training_configuration.extra.get("hashDims", 0)
-        )
-
-        def make_pair():
-            sx = np.zeros_like(self._stage_x)
-            sy = np.zeros_like(self._stage_y)
-            fs = FusedStage(
-                sx, sy, self.test_set._x, self.test_set._y,
-                n_features=self.dim - hash_dims,
-                test_enabled=bool(self.config.test),
-            )
-            return (sx, sy, fs)
-
-        train = train_fn or (
-            lambda sx, sy, n: self._train_buffer(
-                sx, sy, n, full=(n == self._stage_cap)
-            )
-        )
-        disp = _OverlapDispatcher(
-            make_pair, depth, lambda s, n: train(s[0], s[1], n)
-        )
-        current = (self._stage_x, self._stage_y, self._fused_stage())
-
-        def on_stage_full():
-            nonlocal current
-            current = disp.submit(current, self._stage_cap)
-            self._stage_x, self._stage_y = current[0], current[1]
-            self._fused = current[2]
-            self._stage_n = 0
-            return current[2]
-
-        try:
-            for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-                self._fused_consume(
-                    current[2], buf, 0, stop,
-                    on_stage_full=on_stage_full, quiesce=disp.quiesce,
+        rows = self.holdout_count
+        with tracing.span("ingest_file") as span:
+            try:
+                consume = self._block_consumer()
+                disp = self._dispatcher = _OverlapDispatcher(
+                    self._spare_stage_set,
+                    0 if self._paced else depth,
+                    self._launch_stage_set,
                 )
-                if on_chunk is not None:
-                    on_chunk()
-            # final partial stage drains through the same ordered queue
-            n_tail = self._stage_n
-            self._stage_n = 0
-            if n_tail:
-                disp.submit(current, n_tail)
-        finally:
-            disp.close()
-        disp.raise_pending()
+                try:
+                    for buf, stop in _line_aligned_chunks(
+                        path, chunk_bytes or self.CHUNK_BYTES
+                    ):
+                        # surface a dispatch-thread error at the next chunk
+                        # boundary instead of parsing the rest of the file
+                        disp.raise_pending()
+                        consume(buf, stop)
+                        if on_chunk is not None:
+                            on_chunk()
+                    # the final partial stage drains through the same queue
+                    self._train_staged()
+                finally:
+                    self._dispatcher = None
+                    disp.close()
+                disp.raise_pending()
+            finally:
+                span.add(rows=self.holdout_count - rows)
 
-    def _fused_consume(
-        self, fs, buf: bytearray, start: int, stop: int,
-        on_stage_full=None, quiesce=None,
-    ) -> None:
-        """Drive the C loop over ``buf[start:stop]`` (whole lines), handing
-        stage launches / fallback lines / forecasts back to Python.
+    def _block_consumer(self):
+        """What a file's chunks go through: ``consume(buf, stop)`` takes
+        the whole lines ``buf[:stop]``."""
+        return self._fused_consume
 
-        ``on_stage_full`` (double-buffered ingest): called instead of the
-        inline stage launch; hands the full buffer to the dispatch thread,
-        swaps the parse side to a free buffer pair and returns its
-        FusedStage. ``quiesce`` is then called before any branch that
-        touches the trainer or the parse-side stage from Python
-        (fallback/forecast), so those inline paths never race the
+    def _fused_consume(self, buf: bytearray, stop: int) -> None:
+        """Drive the C line loop over ``buf[:stop]`` (whole lines), handing
+        stage launches / fallback lines / forecasts back to Python. The
+        dispatcher is quiesced before any branch that touches the trainer
+        from this thread (fallback/forecast), so those never race the
         dispatch thread."""
-        ctx = fs.ctx
-        off = start
+        off = 0
         while off < stop:
-            # sync the mutable cursors in (Python code below, and SSP
-            # requeue inside _train_staged, may have moved them)
-            ctx.stage_n = self._stage_n
-            ctx.hold_n = self.test_set._n
-            ctx.hold_head = self.test_set._head
-            ctx.holdout_count = self.holdout_count
-            rc, consumed, soff, slen = fs.parse_stage(buf, off, stop)
-            self._stage_n = int(ctx.stage_n)
-            self.test_set._n = int(ctx.hold_n)
-            self.test_set._head = int(ctx.hold_head)
-            self.holdout_count = int(ctx.holdout_count)
+            with self._c_driver() as fs:
+                rc, consumed, soff, slen = fs.parse_stage(buf, off, stop)
             base = off
             off += consumed
             if rc == fs.RC_DONE:
                 return
-            if rc in (fs.RC_FALLBACK, fs.RC_FORECAST) and quiesce is not None:
-                quiesce()
             if rc == fs.RC_STAGE_FULL:
-                if on_stage_full is not None:
-                    fs = on_stage_full()
-                    ctx = fs.ctx
-                else:
-                    self._train_staged(full=True)
-            elif rc == fs.RC_FALLBACK:
+                self._train_staged()
+                continue
+            self._dispatcher.quiesce()
+            if rc == fs.RC_FALLBACK:
                 line = bytes(buf[base + soff : base + soff + slen]).decode(
                     "utf-8", errors="replace"
                 )
@@ -926,10 +662,6 @@ class SPMDBridge:
     def _evaluate(self) -> Tuple[float, float]:
         if self.test_set.is_empty:
             return 0.0, 0.0
-        if self._resident is not None:
-            # serve the eval straight from the resident holdout ring
-            xs, ys, mask = self._resident.eval_arrays()
-            return self.trainer.evaluate(xs, ys, mask)
         xs, ys = self.test_set.arrays()
         # pad to the holdout capacity so the jitted eval program compiles
         # once, not once per fill level while the holdout warms up
@@ -1036,7 +768,7 @@ class SparseSPMDBridge(SPMDBridge):
     # sparse chunks default to 8 MB (vs the dense 4 MB): the MT parse
     # amortizes its newline-index pass and thread handoff over longer
     # line runs — measured ~+8% host throughput on the Criteo stream
-    SPARSE_CHUNK_BYTES = 1 << 23
+    CHUNK_BYTES = 1 << 23
 
     def __init__(self, request, dim, config, emit_prediction, emit_response):
         super().__init__(request, dim, config, emit_prediction, emit_response)
@@ -1054,104 +786,45 @@ class SparseSPMDBridge(SPMDBridge):
         self._stage_i = np.zeros((self._stage_cap, self.max_nnz), np.int32)
         self._stage_v = np.zeros((self._stage_cap, self.max_nnz), np.float32)
         self._stage_y = np.zeros((self._stage_cap,), np.float32)
-        self._stage_x = self._stage_v  # base-class size probes only
+        del self._stage_x  # the dense bridge's
         self._stage_n = 0
 
     def supports_fused_ingest(self) -> bool:
-        """The sparse bridge has its own C bulk routes (ingest_file below:
-        the fused parse->holdout->stage loop, or padded-COO block packing
-        with in-C categorical hashing)."""
+        """The sparse file route: the C block parser (categorical hashing
+        in C) and the C stager."""
         from omldm_tpu.ops.native import fast_parser_available
 
         return fast_parser_available()
 
-    # supports_overlapped_ingest: inherited — supports_fused_ingest is
-    # polymorphic and the opt-out knob is shared with the dense route.
+    def _stage_set(self) -> tuple:
+        return (self._stage_i, self._stage_v, self._stage_y)
 
-    def _use_fused_coo(self) -> bool:
-        """The fused C loop (omldm_parse_stage_sparse) is the default file
-        route: it parses each line directly into its COO stage slot with
-        the holdout split in C, where the block route re-touches every row
-        in numpy (parser output allocation, holdout mask/argsort/concat,
-        stage memcpy) — ~2x host throughput measured on the Criteo-shaped
-        stream (benchmarks/run_benchmarks.py:bench_criteo_sparse_stream_e2e).
-        ``{"sparseFusedIngest": false}`` keeps the multithreaded block
-        parser instead (it can win on many-core hosts where the e2e is
-        parse-bound and the fused loop's single parse thread loses to 8
-        MT block threads)."""
-        if not self.supports_fused_ingest():
-            return False
-        flag = str(
-            self.request.training_configuration.extra.get(
-                "sparseFusedIngest", "true"
-            )
-        ).lower()
-        return flag != "false"
+    def _adopt_stage_set(self, stage_set: tuple) -> None:
+        self._stage_i, self._stage_v, self._stage_y = stage_set
+        self._fused = None  # the C stager is bound to the arrays
 
-    def _sparse_fused_stage(self):
+    def _launch_stage_set(self, stage_set: tuple, n: int) -> None:
+        self._launch_coo(*stage_set, n)
+
+    def _fused_stage(self):
+        """The C stager over the current stage set and the holdout ring."""
         from omldm_tpu.ops.native import SparseFusedStage
 
-        if getattr(self, "_fused", None) is None:
+        if self._fused is None:
             self._fused = SparseFusedStage(
                 self._stage_i, self._stage_v, self._stage_y,
                 self.test_set._idx, self.test_set._val, self.test_set._y,
-                dense_budget=self.vectorizer.dim - self.vectorizer.hash_space,
-                hash_space=self.vectorizer.hash_space,
                 test_enabled=bool(self.config.test),
             )
         return self._fused
-
-    def _fused_consume_sparse(
-        self, fs, buf: bytearray, start: int, stop: int,
-        on_stage_full=None, quiesce=None,
-    ) -> None:
-        """Drive the fused sparse C loop over ``buf[start:stop]`` (whole
-        lines), handing stage launches and special lines back to Python —
-        the COO twin of the dense :meth:`_fused_consume`, with the same
-        cursor-sync contract. Specials (codec fallbacks AND forecasts)
-        re-enter via DataInstance.from_json -> handle_data, which is
-        byte-identical to the block route's special path; ``quiesce``
-        drains the dispatch queue first so the rare path never races the
-        dispatch thread on trainer state."""
-        ctx = fs.ctx
-        off = start
-        while off < stop:
-            ctx.stage_n = self._stage_n
-            ctx.hold_n = self.test_set._n
-            ctx.hold_head = self.test_set._head
-            ctx.holdout_count = self.holdout_count
-            rc, consumed, soff, slen = fs.parse_stage(buf, off, stop)
-            self._stage_n = int(ctx.stage_n)
-            self.test_set._n = int(ctx.hold_n)
-            self.test_set._head = int(ctx.hold_head)
-            self.holdout_count = int(ctx.holdout_count)
-            base = off
-            off += consumed
-            if rc == fs.RC_DONE:
-                return
-            if rc == fs.RC_STAGE_FULL:
-                if on_stage_full is not None:
-                    fs = on_stage_full()
-                    ctx = fs.ctx
-                else:
-                    self._train_staged(full=True)
-            elif rc == fs.RC_SPECIAL:
-                if quiesce is not None:
-                    quiesce()
-                line = bytes(buf[base + soff : base + soff + slen]).decode(
-                    "utf-8", errors="replace"
-                )
-                inst = DataInstance.from_json(line)
-                if inst is not None:
-                    self.handle_data(inst)
 
     def _make_coo_parser(self):
         from omldm_tpu.ops.native import SparseFastParser
 
         # parserThreads: 0 = auto (min(cores, 8), FastParser's rule) —
         # multi-core hosts parse disjoint line ranges on C threads.
-        # reuse_buffers: the ingest routes consume every returned array
-        # within the chunk (staging memcpy / holdout copy), so the parser
+        # reuse_buffers: the file route consumes every returned array
+        # within the chunk (the C stager copies the rows), so the parser
         # may hand out scratch views instead of fresh allocations
         return SparseFastParser(
             self.vectorizer.dim - self.vectorizer.hash_space,
@@ -1165,140 +838,10 @@ class SparseSPMDBridge(SPMDBridge):
             reuse_buffers=True,
         )
 
-    @_in_ingest_file_span
-    def ingest_file_overlapped(
-        self, path: str, chunk_bytes: int = SPARSE_CHUNK_BYTES, on_chunk=None,
-        depth: int = 2, train_fn=None,
-    ) -> None:
-        """DOUBLE-BUFFERED COO ingest: the fused C parse -> holdout ->
-        stage loop fills stage set k+1 while the dispatch thread runs
-        stage k's collective steps — the sparse e2e path is host-parse
-        bound and the device scatter costs about as much, so overlapping
-        them approaches max() instead of their sum. Stage sets dispatch
-        strictly in order: results are bit-identical to the serial
-        :meth:`ingest_file` (pinned by tests/test_overlap.py). Specials
-        (forecasts, codec fallbacks) quiesce the queue first, exactly
-        like the dense route. Hosts opting out of the fused loop
-        (``sparseFusedIngest: false``) overlap the MT block route
-        instead (:meth:`_ingest_file_overlapped_blocks`)."""
-        if self._paced:
-            raise ValueError(
-                "overlapped ingest requires chained launches; SSP's "
-                "per-launch accept flags force the serial path"
-            )
-        use_fused = self._use_fused_coo()
-        parser = self._make_coo_parser() if use_fused else None
-        if not use_fused or parser.n_threads > 1:
-            # multi-core hosts overlap the MT block parse (all cores in
-            # the producer thread, C staging tail) with the dispatch
-            # thread; single-core hosts overlap the fused line loop
-            self._ingest_file_overlapped_blocks(
-                path, chunk_bytes, on_chunk, depth, train_fn, parser
-            )
-            return
-        from omldm_tpu.ops.native import SparseFusedStage
-
-        dense_budget = self.vectorizer.dim - self.vectorizer.hash_space
-
-        def make_set():
-            si = np.zeros_like(self._stage_i)
-            sv = np.zeros_like(self._stage_v)
-            sy = np.zeros_like(self._stage_y)
-            fs = SparseFusedStage(
-                si, sv, sy,
-                self.test_set._idx, self.test_set._val, self.test_set._y,
-                dense_budget=dense_budget,
-                hash_space=self.vectorizer.hash_space,
-                test_enabled=bool(self.config.test),
-            )
-            return (si, sv, sy, fs)
-
-        train = train_fn or (
-            lambda si, sv, sy, n: self._launch_coo(si, sv, sy, n)
+    def _block_consumer(self):
+        return functools.partial(
+            self._consume_coo_block, self._make_coo_parser()
         )
-        disp = _OverlapDispatcher(
-            make_set, depth, lambda s, n: train(s[0], s[1], s[2], n)
-        )
-        current = (
-            self._stage_i, self._stage_v, self._stage_y,
-            self._sparse_fused_stage(),
-        )
-
-        def on_stage_full():
-            nonlocal current
-            current = disp.submit(current, self._stage_cap)
-            self._stage_i, self._stage_v, self._stage_y = current[:3]
-            self._stage_x = self._stage_v  # base-class size probes
-            self._fused = current[3]
-            self._stage_n = 0
-            return current[3]
-
-        try:
-            for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-                # surface a dispatch-thread error at the next chunk
-                # boundary instead of parsing the rest of the file first
-                disp.raise_pending()
-                self._fused_consume_sparse(
-                    current[3], buf, 0, stop,
-                    on_stage_full=on_stage_full, quiesce=disp.quiesce,
-                )
-                if on_chunk is not None:
-                    on_chunk()
-            # final partial stage drains through the same ordered queue
-            n_tail = self._stage_n
-            self._stage_n = 0
-            if n_tail:
-                disp.submit(current, n_tail)
-        finally:
-            disp.close()
-        disp.raise_pending()
-
-    def _ingest_file_overlapped_blocks(
-        self, path: str, chunk_bytes: int, on_chunk, depth: int, train_fn,
-        parser=None,
-    ) -> None:
-        """The block-parse overlapped route: MT parse in the producer
-        thread, C (fused) or numpy holdout/staging, stage sets through
-        the same ordered dispatcher. Also serves ``sparseFusedIngest:
-        false`` hosts."""
-        if parser is None:
-            parser = self._make_coo_parser()
-
-        def make_set():
-            return (
-                np.zeros_like(self._stage_i),
-                np.zeros_like(self._stage_v),
-                np.zeros_like(self._stage_y),
-            )
-
-        train = train_fn or (
-            lambda si, sv, sy, n: self._launch_coo(si, sv, sy, n)
-        )
-        disp = _OverlapDispatcher(
-            make_set, depth, lambda s, n: train(s[0], s[1], s[2], n)
-        )
-        self._coo_enqueue = disp
-        self._coo_quiesce = disp.quiesce
-        try:
-            for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-                disp.raise_pending()
-                self._consume_coo_block(parser, buf, stop)
-                if on_chunk is not None:
-                    on_chunk()
-            # final partial stage drains through the same ordered queue
-            n_tail = self._stage_n
-            self._stage_n = 0
-            if n_tail:
-                (self._stage_i, self._stage_v, self._stage_y) = disp.submit(
-                    (self._stage_i, self._stage_v, self._stage_y), n_tail
-                )
-                self._stage_x = self._stage_v
-                self._fused = None  # C-stager driver follows the swap
-        finally:
-            self._coo_enqueue = None
-            self._coo_quiesce = None
-            disp.close()
-        disp.raise_pending()
 
     # --- data path ---
 
@@ -1406,39 +949,15 @@ class SparseSPMDBridge(SPMDBridge):
             self._stage_n += take
             i += take
             if self._stage_n >= self._stage_cap:
-                self._train_staged(full=True)
-
-    def _train_staged(self, full: bool = False) -> None:
-        n = self._stage_n
-        if n == 0:
-            return
-        # double-buffered ingest: hand the filled stage set to the
-        # dispatch thread and continue parsing into a fresh set from the
-        # pool (the serial path launches inline below)
-        if getattr(self, "_coo_enqueue", None) is not None:
-            (self._stage_i, self._stage_v, self._stage_y) = (
-                self._coo_enqueue.submit(
-                    (self._stage_i, self._stage_v, self._stage_y), n
-                )
-            )
-            self._stage_x = self._stage_v  # base-class size probes
-            # the cached C-stager driver points at the buffers that were
-            # just handed to the dispatch thread: rebuild over the new set
-            self._fused = None
-            self._stage_n = 0
-            return
-        self._stage_n = 0
-        self._launch_coo(
-            self._stage_i, self._stage_v, self._stage_y, n
-        )
+                self._train_staged()
 
     def _launch_coo(self, si, sv, sy, n) -> None:
-        """Launch ``n`` staged COO rows (explicit arrays, so the
-        double-buffered dispatch thread can drive it on pooled sets).
-        Rows are COPIED before device handoff: dispatch is async and jax
-        may alias numpy argument buffers zero-copy (observed on CPU),
-        while both the serial stage and the pooled sets are reused as
-        soon as this returns; SSP requeue also re-enters these buffers."""
+        """Launch ``n`` staged COO rows (explicit arrays, so the file
+        route's dispatch thread can drive it on pooled sets). Rows are
+        COPIED before device handoff: dispatch is async and jax may alias
+        numpy argument buffers zero-copy (observed on CPU), while the
+        stage set is reused as soon as this returns; SSP requeue also
+        re-enters these buffers."""
         with tracing.span("launch"):
             with tracing.span("copy_stage"):
                 si = si[:n].copy()
@@ -1565,56 +1084,17 @@ class SparseSPMDBridge(SPMDBridge):
             # the overflow must train, not crash or truncate
             self._stage_coo(bd["stage_i"], bd["stage_v"], bd["stage_yv"])
 
-    # --- bulk file ingest via the C sparse parser ---
+    # --- the file route's blocks: C block parser, then the C stager ---
 
-    def ingest_file(
-        self, path: str, chunk_bytes: int = SPARSE_CHUNK_BYTES, on_chunk=None
-    ) -> None:
-        """Stream a JSON-lines file through the fused sparse C loop:
-        every fast-schema line is parsed DIRECTLY into its COO stage slot
-        (zlib-CRC32 categorical hashing in C, parity fuzz-pinned by
-        tests/test_sparse_parser.py) and holdout-split in C — the sparse
-        twin of the dense fused route, bit-identical to the block route
-        (pinned by tests/test_sparse_spmd_bridge.py). Fallback lines,
+    def _consume_coo_block(self, parser, buf: bytearray, stop: int) -> None:
+        """Block parse of ``buf[:stop]`` on the parser's C threads
+        (zero-copy out of the reusable read buffer: zlib-CRC32 categorical
+        hashing in C, parity fuzz-pinned by tests/test_sparse_parser.py),
+        then holdout + staging of the parsed runs in C. Fallback lines,
         forecasts and drops re-route through the per-record codec at
-        their stream position; ``sparseFusedIngest: false`` keeps the MT
-        block route."""
-        if self._use_fused_coo():
-            parser = self._make_coo_parser()
-            if parser.n_threads <= 1:
-                # single-core host: the fused line loop (one C pass,
-                # parse straight into the stage slot) beats any split
-                for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-                    self._fused_consume_sparse(
-                        self._sparse_fused_stage(), buf, 0, stop
-                    )
-                    if on_chunk is not None:
-                        on_chunk()
-                return
-            # multi-core host: MT block parse on all cores, then the C
-            # stager (_consume_coo_block routes staging through
-            # omldm_stage_coo_rows when the fused path is enabled)
-        else:
-            parser = self._make_coo_parser()
-        for buf, stop in _line_aligned_chunks(path, chunk_bytes):
-            self._consume_coo_block(parser, buf, stop)
-            if on_chunk is not None:
-                on_chunk()
-
-    def _consume_coo_block(self, parser, buf, stop: int = None) -> None:
-        """MT block parse of ``buf[:stop]`` (zero-copy out of the reusable
-        read buffer) + vectorized holdout/staging. ``buf`` may also be a
-        plain bytes block (Kafka feeds), in which case ``stop`` defaults
-        to its length."""
-        if stop is None:
-            stop = len(buf)
+        their stream position."""
         with tracing.span("parse") as parse_span:
-            if isinstance(buf, (bytes, memoryview)):
-                block = bytes(buf[:stop])
-                idx, val, y, op, valid = parser.parse(block)
-            else:
-                block = None  # materialized lazily, only for special lines
-                idx, val, y, op, valid = parser.parse_range(buf, 0, stop)
+            idx, val, y, op, valid = parser.parse_range(buf, 0, stop)
             n = idx.shape[0]
             parse_span.add(rows=n)
         if n == 0:
@@ -1626,21 +1106,13 @@ class SparseSPMDBridge(SPMDBridge):
         if special.size:
             # one special line costs a copy and a split of the whole block
             with tracing.span("split_lines"):
-                if block is None:
-                    block = bytes(memoryview(buf)[:stop])
-                lines = block.split(b"\n")
-        # bulk runs of parsed training rows: holdout + stage in C when the
-        # fused path is on (same per-record semantics either way)
-        stage_bulk = (
-            self._stage_parsed_rows if self._use_fused_coo()
-            else self._train_sparse_rows
-        )
+                lines = bytes(memoryview(buf)[:stop]).split(b"\n")
         prev = 0
         for s in special:
             s = int(s)
             if s > prev:
                 with tracing.span("stage"):
-                    stage_bulk(idx[prev:s], val[prev:s], y[prev:s])
+                    self._stage_parsed_rows(idx[prev:s], val[prev:s], y[prev:s])
             # a line the C parser read as a forecast, or one it left to
             # the Python codec (which may still find a forecast in it)
             is_forecast = valid[s] == 1 and op[s] != 0
@@ -1651,42 +1123,29 @@ class SparseSPMDBridge(SPMDBridge):
                     )
                 if inst is not None:
                     sp.key = inst.id
-                    if getattr(self, "_coo_quiesce", None) is not None:
-                        # specials may touch the trainer from this
-                        # (producer) thread (forecasts serve a prediction):
-                        # drain queued collective steps first — including
-                        # any enqueued by the staging right above — so two
-                        # threads never race on trainer state
-                        self._coo_quiesce()
+                    # specials may touch the trainer from this (producer)
+                    # thread (forecasts serve a prediction): drain queued
+                    # collective steps first — including any enqueued by
+                    # the staging right above — so two threads never race
+                    # on trainer state
+                    self._dispatcher.quiesce()
                     self.handle_data(inst)
             prev = s + 1
         if prev < n:
             with tracing.span("stage"):
-                stage_bulk(idx[prev:], val[prev:], y[prev:])
+                self._stage_parsed_rows(idx[prev:], val[prev:], y[prev:])
 
     def _stage_parsed_rows(self, idx, val, y) -> None:
         """Holdout + stage a run of C-PARSED COO rows through the C stager
-        (omldm_stage_coo_rows): the staging tail of the MT block route,
-        bit-identical to :meth:`_holdout_then_stage` + :meth:`_stage_coo`
-        but with the holdout cycle, ring swap and stage fill in one C pass
-        instead of mask/argsort/concatenate numpy per block. Pauses at
-        stage-full for the launch (or the overlapped dispatch swap)."""
+        (omldm_stage_coo_rows): bit-identical to
+        :meth:`_holdout_then_stage` + :meth:`_stage_coo` (the per-record
+        path's, and the tests' reference) but with the holdout cycle, ring
+        swap and stage fill in one C pass instead of mask/argsort/
+        concatenate numpy per block. Pauses at stage-full for the launch."""
         n = idx.shape[0]
         i = 0
         while i < n:
-            # re-fetch per pass: a stage swap (overlapped dispatch)
-            # invalidates the cached driver
-            fs = self._sparse_fused_stage()
-            ctx = fs.ctx
-            ctx.stage_n = self._stage_n
-            ctx.hold_n = self.test_set._n
-            ctx.hold_head = self.test_set._head
-            ctx.holdout_count = self.holdout_count
-            took = fs.stage_rows(idx, val, y, i)
-            self._stage_n = int(ctx.stage_n)
-            self.test_set._n = int(ctx.hold_n)
-            self.test_set._head = int(ctx.hold_head)
-            self.holdout_count = int(ctx.holdout_count)
-            i += took
+            with self._c_driver() as fs:
+                i += fs.stage_rows(idx, val, y, i)
             if self._stage_n >= self._stage_cap:
-                self._train_staged(full=True)
+                self._train_staged()

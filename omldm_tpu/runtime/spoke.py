@@ -688,23 +688,6 @@ class Spoke:
                 net.lifecycle.events = journal
                 net.lifecycle.net_id = net.request.id
 
-    def attach_ingest_probe(self, name: str, probe) -> None:
-        """Register an ingest-plane pressure probe (a zero-arg callable
-        returning (value, high, critical)) on this spoke's overload
-        controller — e.g. sharded-ingest driver starvation or prefetch
-        ring emptiness (OverloadController.extra_signals). No-op while
-        the overload plane is unarmed: the signal has no ladder to
-        raise."""
-        if self.overload is not None:
-            self.overload.extra_signals[name] = probe
-
-    def detach_ingest_probe(self, name: str) -> None:
-        """Remove a probe registered by attach_ingest_probe (the sharded
-        ingest driver detaches its probes when the file run ends — a
-        closed ShardedIngest must not keep reporting stale pressure)."""
-        if self.overload is not None:
-            self.overload.extra_signals.pop(name, None)
-
     def _timer_percentiles(self, timer: StepTimer) -> Tuple[float, float]:
         """(p50, p99) ms of a StepTimer's retained window, cached by the
         timer's total count so a multi-tenant terminate probe sorts each

@@ -716,12 +716,7 @@ class DistributedJobSupervisor:
         is a VICTIM's code ("my peer is wedged; I refuse to block
         forever"): when every bad exit is a HANG_EXIT and some process is
         still alive, the blame lands on the live (wedged, probably
-        SIGSTOP'd/stuck-in-native) processes, not the honest survivors.
-        The sharded ingest plane's parser fleet shares the classification
-        vocabulary (ingest_shard.ShardWorkerDead carries the same
-        selfheal.classify_failure classes) but not this restart policy —
-        a dead parser degrades to in-process ingest instead of a fleet
-        restart, since the driver can always parse alone."""
+        SIGSTOP'd/stuck-in-native) processes, not the honest survivors."""
         live = [i for i, rc in enumerate(codes) if rc is None]
         hang_exits = [i for i in bad if codes[i] == HANG_EXIT]
         if hang_exits and len(hang_exits) == len(bad) and live:

@@ -31,9 +31,9 @@ the terminate fold. This module is the missing plane:
 - :class:`PhaseProfile` — per-phase wall-clock accounting (bounded sample
   rings + EXACT total seconds) for the hot-loop phases ``read``/``parse``/
   ``stage``/``holdout``/``fit``/``device_wait``/``serve``/``ship``, wired
-  through the spoke/ingest/serving paths and surfaced as the
-  phase-breakdown table in ``bench.py`` and the benchmark result rows —
-  so ingest-wall work starts from measured attribution instead of guesses.
+  through the spoke/ingest/serving paths and surfaced as the job's
+  ``phase_table()`` — so ingest-wall work starts from measured
+  attribution instead of guesses.
 - :class:`SpanLog` — sampled (``traceSample`` = 1/N) span events for
   protocol rounds, keyed by the reliable transport's existing
   (networkId, seq) stamps (falling back to a local per-stream counter when
@@ -52,17 +52,11 @@ import numpy as np
 
 from omldm_tpu.utils.tracing import Mark, Recorder, Ring, Span
 
-# canonical hot-loop phase names (the bench.py breakdown table's rows);
+# canonical hot-loop phase names (the rows of ``phase_table()``);
 # PhaseProfile accepts any name — these are the ones the runtime wires
-# NOTE: the sharded ingest plane (runtime/ingest_shard.py) folds its
-# worker-process parse clocks into "parse" and the driver's ring-wait
-# into "read" at the end of a run_file_sharded pass — worker seconds are
-# summed ACROSS shard processes, so on a multi-core host "parse" can
-# legitimately exceed the driver's wall time (parallel work attributed
-# to one table).
 PHASES = (
-    "read",        # source I/O: kafka poll / file block read / shard ring
-    "parse",       # bytes -> rows (JSON parse, C block parse, shard procs)
+    "read",        # source I/O: kafka poll / file block read
+    "parse",       # bytes -> rows (JSON parse, C block parse)
     "stage",       # rows -> fixed-shape micro-batches (vectorize + batcher)
     "holdout",     # 8-of-10 test-set split bookkeeping
     "fit",         # training program dispatch (the StepTimer flush path)
@@ -72,7 +66,7 @@ PHASES = (
     # the fused SPMD route (runtime/spmd_bridge.py; spans of the
     # process-wide utils.tracing.RECORDER). Its read/parse/stage/fit/serve
     # are the rows above; holdout runs inside the C stager, under "stage"
-    "ingest_file",       # one file through ingest_file_overlapped
+    "ingest_file",       # one file through SPMDBridge.ingest_file
     "dispatcher_open",   # stage sets allocated, dispatch thread started
     "dispatcher_close",  # join on everything still queued
     "pool_wait",         # producer blocked: every stage set queued/in flight

@@ -306,6 +306,24 @@ def _mt_job(cohort, n_pipe, records, protocol="Asynchronous", test=True,
     return job, report, preds
 
 
+# A learning_curve point is a float32 mean of one batch's losses. The gang
+# program (vmapped, or sharded over a mesh) and the solo program are two XLA
+# programs, and XLA's CPU backend does not promise two programs the same
+# order of a reduction, on one machine or across machines: read on two hosts
+# (PR 31), 10 to 30% of the points sit 1 to 2 ulp apart while score, fitted,
+# lcx, every prediction and every final weight are equal. So the curve is
+# held to a few ulp, and everything else stays exact.
+CURVE_MAX_ULP = 4
+
+
+def _final_weights(job):
+    return {
+        net_id: np.asarray(net.pipeline.get_flat_params()[0])
+        for spoke in job.spokes
+        for net_id, net in spoke.nets.items()
+    }
+
+
 def _assert_job_bitwise(off, on):
     j_off, r_off, p_off = off
     j_on, r_on, p_on = on
@@ -316,9 +334,18 @@ def _assert_job_bitwise(off, on):
         b = s_on[pid]
         assert a.score == b.score, f"pid {pid} score"
         assert a.fitted == b.fitted, f"pid {pid} fitted"
-        assert a.learning_curve == b.learning_curve, f"pid {pid} curve"
         assert a.lcx == b.lcx, f"pid {pid} lcx"
+        assert len(a.learning_curve) == len(b.learning_curve), f"pid {pid}"
+        np.testing.assert_array_max_ulp(
+            np.asarray(a.learning_curve, np.float32),
+            np.asarray(b.learning_curve, np.float32),
+            maxulp=CURVE_MAX_ULP,
+        )
     assert p_off == p_on
+    w_off, w_on = _final_weights(j_off), _final_weights(j_on)
+    assert w_off.keys() == w_on.keys()
+    for net_id, w in w_off.items():
+        np.testing.assert_array_equal(w, w_on[net_id], f"net {net_id} weights")
 
 
 class TestMultiTenantBitIdentity:

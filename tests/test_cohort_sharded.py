@@ -37,6 +37,7 @@ from omldm_tpu.runtime.cohort import (
     resolve_cohort_shards,
 )
 from omldm_tpu.runtime.job import REQUEST_STREAM
+from tests.test_cohort import _assert_job_bitwise
 
 DIM = 8
 
@@ -308,21 +309,6 @@ def _mt_job(cohort, n_pipe, records, protocol="Asynchronous", test=True,
     for p in job.predictions:
         preds.setdefault(p.mlp_id, []).append(p.value)
     return job, report, preds
-
-
-def _assert_job_bitwise(off, on):
-    _, r_off, p_off = off
-    _, r_on, p_on = on
-    s_off = {s.pipeline: s for s in r_off.statistics}
-    s_on = {s.pipeline: s for s in r_on.statistics}
-    assert s_off.keys() == s_on.keys()
-    for pid, a in s_off.items():
-        b = s_on[pid]
-        assert a.score == b.score, f"pid {pid} score"
-        assert a.fitted == b.fitted, f"pid {pid} fitted"
-        assert a.learning_curve == b.learning_curve, f"pid {pid} curve"
-        assert a.lcx == b.lcx, f"pid {pid} lcx"
-    assert p_off == p_on
 
 
 class TestShardedJobBitIdentity:

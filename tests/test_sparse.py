@@ -120,36 +120,31 @@ class TestSparseOps:
             np.asarray(out), coef @ x, rtol=1e-5, atol=1e-5
         )
 
-    def test_mxu_scatter_matches_xla_scatter(self):
-        """The kron-factored one-hot matmul reformulation
-        (sparse_scatter_add_mxu) is the same scatter-add up to f32
-        reduction order: one-hot products are exact, u rides a bf16x2
-        split. Covers duplicates, pad slots, D not a lane multiple, and
-        D > MXU_LANES (hi factor exercised)."""
-        from omldm_tpu.ops.sparse import MXU_LANES, sparse_scatter_add_mxu
+    def test_committed_tpu_table_names_the_cells_formulations(self):
+        """The choice both benchmark cells depend on, read from the file
+        itself: ``backends.tpu`` of ops/sparse_dispatch.json names ``plan``
+        at a launch of 4096 x 41 slots over 2^28 + 14 weights and
+        ``scatter`` at the tail step's 256 x 41 and a forecast's padded
+        16 x 41; the table has no other backend's section (the CPU's named
+        the plain pair everywhere, which is what no section gives)."""
+        import json
+        import os
 
-        rng = np.random.RandomState(7)
-        for d in (37, MXU_LANES, MXU_LANES * 3 + 11, 4096):
-            b, k = 16, 9
-            w = rng.randn(d).astype(np.float32)
-            idx = rng.randint(0, d, size=(b, k)).astype(np.int32)
-            idx[:, -2:] = 0  # pad slots (val 0) plus forced duplicates
-            val = rng.randn(b, k).astype(np.float32)
-            val[:, -2:] = 0.0
-            idx[3] = idx[2]  # whole-record duplicate index pattern
-            coef = rng.randn(b).astype(np.float32)
-            ref = sparse_scatter_add(
-                jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-                jnp.asarray(val),
-            )
-            out = sparse_scatter_add_mxu(
-                jnp.asarray(w), jnp.asarray(idx), jnp.asarray(coef),
-                jnp.asarray(val),
-            )
-            np.testing.assert_allclose(
-                np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5,
-                err_msg=f"mxu scatter diverged at D={d}",
-            )
+        from omldm_tpu.ops import sparse as sp
+
+        path = os.path.join(os.path.dirname(sp.__file__), "sparse_dispatch.json")
+        with open(path) as f:
+            backends = json.load(f)["backends"]
+        assert list(backends) == ["tpu"]
+        winners = {
+            (e["d"], e["batch"], e["nnz"]): e["winner"]
+            for e in backends["tpu"]["entries"]
+        }
+        d = (1 << 28) + 14
+        assert winners[(d, 4096, 41)] == "plan"
+        assert winners[(d, 256, 41)] == "scatter"
+        assert winners[(d, 16, 41)] == "scatter"
+        assert set(winners.values()) == set(sp.IMPLS)
 
     def test_update_dispatch_matches_plain_pair_under_jit(self):
         """sparse_update resolves at trace time and must be jittable; with
@@ -237,21 +232,33 @@ class TestSparseOps:
         if distinct > cap:
             np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
 
-    def test_dispatch_precedence_env_and_config(self, monkeypatch):
-        """_resolve_impl precedence: explicit config impl > env knob >
-        calibration table > guess. The env knob rejects junk loudly."""
-        from omldm_tpu.ops import sparse as sp
+    def test_dispatch_impl_then_table_then_plain_pair(
+        self, tmp_path, monkeypatch
+    ):
+        """_resolve_impl's three sources in order: the explicit ``impl``
+        (validated loudly), the table's section for the backend, the plain
+        pair."""
+        import json
 
-        monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
-        assert sp._resolve_impl(300, 40, impl="plan") == "plan"
-        monkeypatch.setenv("OMLDM_SPARSE_SCATTER", "mxu")
-        assert sp._resolve_impl(300, 40) == "mxu"
+        from omldm_tpu.ops import sparse as sp
+        from omldm_tpu.ops import sparse_calibrate as cal
+
+        table = tmp_path / "table.json"
+        table.write_text(json.dumps({"version": 1, "backends": {
+            jax.default_backend(): {"entries": [
+                {"d": 300, "updates": 40, "winner": "plan"},
+            ]},
+        }}))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(table))
+        assert sp._resolve_impl(300, 40) == "plan"
         assert sp._resolve_impl(300, 40, impl="scatter") == "scatter"
-        monkeypatch.setenv("OMLDM_SPARSE_SCATTER", "bogus")
-        with pytest.raises(ValueError, match="OMLDM_SPARSE_SCATTER"):
-            sp._resolve_impl(300, 40)
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(tmp_path / "absent"))
+        assert sp._resolve_impl(300, 40) == "scatter"
+        assert sp._resolve_impl(300, 40, impl="plan") == "plan"
         with pytest.raises(ValueError, match="unknown sparse scatter"):
             sp._resolve_impl(300, 40, impl="bogus")
+        with pytest.raises(ValueError, match="unknown sparse scatter"):
+            sp._resolve_impl(300, 40, impl="mxu")
         # the plan's spare addresses d + j must stay int32: pinned where
         # they cannot, it says so
         with pytest.raises(ValueError, match="2\\^31"):

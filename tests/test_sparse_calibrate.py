@@ -6,7 +6,8 @@ from the nearest measured (D, updates) grid point for the active backend.
 These tests pin the table format the CI smoke run
 (``python -m omldm_tpu.ops.sparse_calibrate --smoke``) regenerates, the
 nearest-neighbor lookup, the merge-per-backend write, and the dispatch
-precedence (env/config overrides beat the table)."""
+order (an explicit impl, the table, the plain pair). A test points the
+reader at a table of its own by patching ``DEFAULT_TABLE``."""
 
 import json
 
@@ -15,7 +16,7 @@ import numpy as np
 import pytest
 
 from omldm_tpu.ops import sparse_calibrate as cal
-from omldm_tpu.ops.sparse import EXACT_IMPLS, IMPLS, _resolve_impl
+from omldm_tpu.ops.sparse import IMPLS, _resolve_impl
 
 
 # the chip's verdict at the cells' shapes (rows of a launch, winner): what
@@ -31,7 +32,7 @@ def _entry(d, updates, winner):
     return {
         "d": d, "batch": 32, "nnz": 4, "updates": updates,
         "duplicate_factor": 1.0,
-        "rates_updates_per_sec": {"scatter": 1.0, "mxu": 1.0, "plan": 1.0},
+        "rates_updates_per_sec": {"scatter": 1.0, "plan": 1.0},
         "winner": winner,
     }
 
@@ -45,7 +46,7 @@ class TestLookup:
                 _entry(1 << 18, 1 << 10, "plan"),
             ]},
         })))
-        monkeypatch.setenv(cal.ENV_TABLE, str(path))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(path))
         assert cal.lookup_winner("cpu", 1 << 12, 1 << 10) == "scatter"
         assert cal.lookup_winner("cpu", 1 << 19, 2048) == "plan"
         # log2-nearest: D=2^15 ties split by first-wins, D=2^16 -> plan
@@ -54,11 +55,11 @@ class TestLookup:
         assert cal.lookup_winner("tpu", 1 << 18, 1 << 10) is None
 
     def test_missing_or_corrupt_table(self, tmp_path, monkeypatch):
-        monkeypatch.setenv(cal.ENV_TABLE, str(tmp_path / "absent.json"))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(tmp_path / "absent.json"))
         assert cal.lookup_winner("cpu", 1 << 18, 1 << 10) is None
         bad = tmp_path / "bad.json"
         bad.write_text("{not json")
-        monkeypatch.setenv(cal.ENV_TABLE, str(bad))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(bad))
         assert cal.lookup_winner("cpu", 1 << 18, 1 << 10) is None
 
     def test_auto_dispatch_reads_table(self, tmp_path, monkeypatch):
@@ -71,24 +72,22 @@ class TestLookup:
         path.write_text(json.dumps(_table({
             backend: {"entries": [_entry(1 << 10, 256, "plan")]},
         })))
-        monkeypatch.setenv(cal.ENV_TABLE, str(path))
-        monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(path))
         assert _resolve_impl(1 << 10, 256) == "plan"
         # where the plan's spare addresses would leave int32, or the
         # weights are not 4 bytes wide, the table's choice gives way to the
         # plain pair
         assert _resolve_impl(2 ** 31 - 8, 256) == "scatter"
         assert _resolve_impl(1 << 10, 256, dtype="bfloat16") == "scatter"
-        # env knob beats the table
-        monkeypatch.setenv("OMLDM_SPARSE_SCATTER", "scatter")
-        assert _resolve_impl(1 << 10, 256) == "scatter"
+        # an explicit impl beats the table
+        assert _resolve_impl(1 << 10, 256, impl="scatter") == "scatter"
 
 
 class TestCalibrate:
     def test_measure_entry_covers_all_kernels(self):
         e = cal.measure_entry(256, 16, 4, steps=2)
         assert set(e["rates_updates_per_sec"]) == set(IMPLS)
-        assert e["winner"] in EXACT_IMPLS
+        assert e["winner"] in IMPLS
         assert e["updates"] == 16 * 4
         assert e["duplicate_factor"] >= 1.0
 
@@ -99,32 +98,37 @@ class TestCalibrate:
 
         path = tmp_path / "table.json"
         path.write_text(json.dumps(_table({
-            "faux-tpu": {"entries": [_entry(1 << 18, 1 << 10, "mxu")]},
+            "faux-tpu": {"entries": [_entry(1 << 18, 1 << 10, "plan")]},
         })))
-        monkeypatch.setenv(cal.ENV_TABLE, str(path))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(path))
         table = cal.calibrate([(256, 16, 4)], steps=2)
         assert "faux-tpu" in table["backends"]
         assert jax.default_backend() in table["backends"]
         on_disk = json.loads(path.read_text())
         assert set(on_disk["backends"]) == set(table["backends"])
         [e] = on_disk["backends"][jax.default_backend()]["entries"]
-        assert e["winner"] in EXACT_IMPLS
+        assert e["winner"] in IMPLS
 
-    @pytest.mark.parametrize("backend", ["cpu", "tpu"])
-    def test_committed_table_has_section(self, backend):
-        """The repo ships a calibrated section for the tier-1 host and one
-        for the chip (PR 30), so the dispatch falls back to the plain pair
-        on neither; the smoke CI run regenerates the same shape."""
+    @pytest.mark.parametrize("backend,shipped", [("cpu", False), ("tpu", True)])
+    def test_committed_table_sections(self, backend, shipped):
+        """The repo ships the section measured on the chip (PR 30) and none
+        for the tier-1 host: the CPU's named the plain pair at every point,
+        which is what a backend without a section gets; the smoke CI run
+        regenerates the same shape."""
         table = cal.load_table(cal.DEFAULT_TABLE)
         assert table is not None, "ops/sparse_dispatch.json missing/corrupt"
         section = table["backends"].get(backend)
-        assert section and section["entries"], f"no {backend} section"
+        if not shipped:
+            assert section is None
+            assert cal.lookup_winner(backend, 1 << 18, 1 << 12) is None
+            return
+        assert section["entries"], f"no {backend} section"
         for e in section["entries"]:
-            # a winner is a default, and a default is exact in float32:
-            # mxu's rate is on record, mxu is never named
+            # the winner is the faster of the two formulations there are;
+            # a retired third one's rate may stay on record beside them
             rates = e["rates_updates_per_sec"]
-            assert set(rates) == set(IMPLS)
-            assert e["winner"] == max(EXACT_IMPLS, key=rates.__getitem__)
+            assert set(IMPLS) <= set(rates)
+            assert e["winner"] == max(IMPLS, key=rates.__getitem__)
             assert e["updates"] == e["batch"] * e["nnz"]
 
     @pytest.mark.parametrize("batch,winner", CELL_WINNERS)
@@ -138,8 +142,6 @@ class TestCalibrate:
         shape with no table at all, get the plain pair."""
         from omldm_tpu.ops import sparse as sp
 
-        monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
-        monkeypatch.delenv(cal.ENV_TABLE, raising=False)
         d, n = (1 << 28) + 14, batch * 41
         [entry] = [
             e for e in cal.load_table(cal.DEFAULT_TABLE)["backends"]["tpu"][
@@ -155,19 +157,16 @@ class TestCalibrate:
         assert sp._resolve_impl(d, n) == "scatter"
 
     def test_tpu_guess_retired(self, tmp_path, monkeypatch):
-        """The round-5 ``D >= 2^16 -> mxu`` TPU guess is retired: an
-        UNCALIBRATED backend (no table section) resolves to the plain
-        scatter at any D — the guessed crossover was never measured, and a
-        number nobody measured must not steer the dispatch. Nor does a
-        table that names ``mxu`` (an older calibration's; this one names
-        exact formulations only): ``mxu`` rounds the updates' low halves to
-        bfloat16 and is reached by explicit config or the env knob alone."""
+        """The round-5 ``D >= 2^16`` TPU guess is retired: an UNCALIBRATED
+        backend (no table section) resolves to the plain scatter at any D —
+        the guessed crossover was never measured, and a number nobody
+        measured must not steer the dispatch. Nor does a table that names
+        something other than the plan (an older calibration's ``mxu``)."""
         import jax
 
         from omldm_tpu.ops import sparse as sp
 
-        monkeypatch.delenv("OMLDM_SPARSE_SCATTER", raising=False)
-        monkeypatch.setenv(cal.ENV_TABLE, str(tmp_path / "absent.json"))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(tmp_path / "absent.json"))
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         assert sp._resolve_impl(1 << 20, 1 << 10) == "scatter"
         assert sp._resolve_impl(1 << 10, 1 << 10) == "scatter"
@@ -175,16 +174,15 @@ class TestCalibrate:
         path.write_text(json.dumps(_table({
             "tpu": {"entries": [_entry(1 << 20, 1 << 10, "mxu")]},
         })))
-        monkeypatch.setenv(cal.ENV_TABLE, str(path))
+        monkeypatch.setattr(cal, "DEFAULT_TABLE", str(path))
         assert sp._resolve_impl(1 << 20, 1 << 10) == "scatter"
-        assert sp._resolve_impl(1 << 20, 1 << 10, impl="mxu") == "mxu"
 
 
 class TestLearnerWiring:
     def test_sparse_pa_update_honors_scatter_override(self, monkeypatch):
         """The learner hot path reaches sparse_update; pinning
-        the impl via dataStructure.scatterImpl (config twin of the env
-        knob) stays numerically inside the twin envelope."""
+        the impl via dataStructure.scatterImpl stays numerically inside
+        the twin envelope."""
         import jax.numpy as jnp
 
         from omldm_tpu.api.requests import LearnerSpec

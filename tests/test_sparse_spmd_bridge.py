@@ -12,6 +12,7 @@ import json
 import numpy as np
 import pytest
 
+from omldm_tpu.api.data import DataInstance
 from omldm_tpu.config import JobConfig
 from omldm_tpu.runtime import StreamJob
 from omldm_tpu.runtime.job import REQUEST_STREAM, TRAINING_STREAM
@@ -130,7 +131,7 @@ class TestSparseSPMDBridge:
         assert stats.fitted + len(bridge.test_set) == 1500
 
     def test_bulk_coo_ingest_matches_per_record(self, tmp_path):
-        """The C padded-COO file route (SparseSPMDBridge.ingest_file) is
+        """The C padded-COO file route (SPMDBridge.ingest_file) is
         indistinguishable from per-record event delivery: same params,
         fitted count, holdout ring, predictions — forecasts, codec
         fallbacks and drops included."""
@@ -200,12 +201,13 @@ class TestSparseSPMDBridge:
 
 
 class TestFusedSparseStaging:
-    """The three serial sparse file routes — numpy block staging, the fused
-    C line loop (omldm_parse_stage_sparse), and MT block parse + C staging
-    (omldm_stage_coo_rows) — must produce BIT-IDENTICAL staging: same
-    trained params, fitted count, holdout ring and predictions. The
-    overlapped route rides the same contract (≤ bit-identical, pinned
-    exactly). Streams include forecasts, escaped-category fallbacks and
+    """The sparse file route — C block parse + C staging
+    (omldm_stage_coo_rows), with one parser thread or several, its launches
+    on the calling thread or on the dispatch thread — must produce staging
+    BIT-IDENTICAL to the per-record route (``handle_data`` line by line:
+    the Python codec and the numpy stager, the independent reference):
+    same trained params, fitted count, holdout ring and predictions.
+    Streams include forecasts, escaped-category fallbacks and
     DUPLICATE-HEAVY categoricals (tiny vocabularies, the hashed-collision
     case the index plan's pre-combine targets)."""
 
@@ -266,38 +268,40 @@ class TestFusedSparseStaging:
             assert pa.value == pb.value, label
 
     def test_serial_routes_bit_identical(self, tmp_path):
+        lines = self._dup_heavy_lines(3000)
         path = tmp_path / "dup.jsonl"
-        path.write_text("\n".join(self._dup_heavy_lines(3000)) + "\n")
-        ref, ref_p = self._bridge(
-            {"sparseFusedIngest": "false", "parserThreads": 1}
-        )
-        ref.ingest_file(str(path))
+        path.write_text("\n".join(lines) + "\n")
+        ref, ref_p = self._bridge()
+        for line in lines:
+            inst = DataInstance.from_json(line)
+            if inst is not None:
+                ref.handle_data(inst)
         ref.flush()
         for label, extra in (
-            ("numpy block MT", {"sparseFusedIngest": "false",
-                                "parserThreads": 2}),
-            ("fused line loop", {"parserThreads": 1}),
-            ("MT parse + C staging", {"parserThreads": 2}),
+            ("one parser thread", {"parserThreads": 1}),
+            ("two parser threads", {"parserThreads": 2}),
         ):
             b, p = self._bridge(extra)
-            b.ingest_file(str(path))
+            b.ingest_file(str(path), depth=0)
             b.flush()
             self._assert_identical(b, ref, p, ref_p, label)
 
     def test_overlapped_matches_serial_duplicate_heavy(self, tmp_path):
         path = tmp_path / "dup.jsonl"
         path.write_text("\n".join(self._dup_heavy_lines(3000)) + "\n")
-        ref, ref_p = self._bridge()
-        ref.ingest_file(str(path))
+        ref, ref_p = self._bridge({"parserThreads": 1})
+        ref.ingest_file(str(path), depth=0)
         ref.flush()
         for label, extra, kw in (
-            ("overlapped fused line", {"parserThreads": 1}, {"depth": 2}),
-            ("overlapped MT + C", {"parserThreads": 2}, {"depth": 2}),
-            ("overlapped small chunks", {"parserThreads": 2},
+            ("dispatch thread, one parser thread", {"parserThreads": 1},
+             {"depth": 2}),
+            ("dispatch thread, two parser threads", {"parserThreads": 2},
+             {"depth": 2}),
+            ("dispatch thread, small chunks", {"parserThreads": 2},
              {"depth": 4, "chunk_bytes": 999}),
         ):
             b, p = self._bridge(extra)
-            b.ingest_file_overlapped(str(path), **kw)
+            b.ingest_file(str(path), **kw)
             b.flush()
             self._assert_identical(b, ref, p, ref_p, label)
 
