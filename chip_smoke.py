@@ -381,19 +381,24 @@ def _hlo_called(line: str) -> List[str]:
     names: List[str] = []
     for group in re.findall(
         r"(?:calls|to_apply|body|condition|true_computation|"
-        r"false_computation|branch_computations)=\{?([^}\s]+(?:, [^}\s]+)*)\}?",
+        r"false_computation|branch_computations)=(\{[^}]*\}|[^\s,]+)",
         line,
     ):
-        names += [g.strip().lstrip("%").rstrip(",") for g in group.split(",")]
+        names += [
+            g.strip().lstrip("%") for g in group.strip("{}").split(",")
+        ]
     return names
 
 
-def hlo_wide_passes(text: str, n: int) -> List[Tuple[str, str, str]]:
+def hlo_wide_passes(text: str, n: int,
+                    every_branch: bool = False) -> List[Tuple[str, str, str]]:
     """The instructions of a compiled program whose result holds ``n`` or
     more elements and that are a relayout's (``_HLO_PASSES``) or a fusion
     without the scatter in it, outside the branch a conditional takes when
     its predicate holds (the protocol's sync): (computation, name, opcode).
-    The false branch, which every step runs, is walked."""
+    The false branch, which every step runs, is walked; with
+    ``every_branch`` the other is too (on one chip the sync moves nothing
+    either: it returns the donated vector)."""
     comps = hlo_computations(text)
     found: List[Tuple[str, str, str]] = []
     seen = set()
@@ -414,7 +419,11 @@ def hlo_wide_passes(text: str, n: int) -> List[Tuple[str, str, str]]:
                 m = re.search(r"false_computation=%?([\w.\-]+)", line)
                 # index form: branch 0 is the one taken when the predicate
                 # is false
-                walk(m.group(1) if m else called[0])
+                branches = called if every_branch else [
+                    m.group(1) if m else called[0]
+                ]
+                for c in branches:
+                    walk(c)
                 continue
             if op == "fusion":
                 if _hlo_wide(shape, n) and not any(
@@ -444,9 +453,10 @@ def hlo_aliased_parameters(text: str) -> List[int]:
 def _check_state_view_is_free(trainer, batch: int, max_nnz: int) -> dict:
     """From the compiled step and predict programs themselves: entering the
     step, leaving it and serving from it move no vector leaf. Outside the
-    sync branch the only pass over the model's width is the scatter, the
-    donated vector leaves are updated in place, and the predict program makes
-    no such pass at all."""
+    sync branch the only pass over the model's width is the scatter (on one
+    chip inside it too: the sync returns the donated vector), every vector
+    leaf the state holds is donated and updated in place, and the predict
+    program makes no such pass at all."""
     import jax
     import jax.numpy as jnp
 
@@ -462,11 +472,13 @@ def _check_state_view_is_free(trainer, batch: int, max_nnz: int) -> dict:
     )
     y = jax.ShapeDtypeStruct((dp, batch), jnp.float32)
     step_text = trainer._step.lower(shapes, x, y, y).compile().as_text()
-    passes = hlo_wide_passes(step_text, n)
+    one_chip = dp * trainer.hub == 1
+    passes = hlo_wide_passes(step_text, n, every_branch=one_chip)
     _require(
         not passes,
-        f"outside the sync branch no op of the step but the scatter has a "
-        f"result of {n} or more elements, found {passes}",
+        f"{'in every branch' if one_chip else 'outside the sync branch'} no "
+        f"op of the step but the scatter has a result of {n} or more "
+        f"elements, found {passes}",
     )
     entry = hlo_computations(step_text)["ENTRY"]
     vectors = sorted(
@@ -475,10 +487,13 @@ def _check_state_view_is_free(trainer, batch: int, max_nnz: int) -> dict:
         if op == "parameter" and _hlo_wide(shape, n)
     )
     aliased = hlo_aliased_parameters(step_text)
+    held = [
+        l for l in jax.tree_util.tree_leaves(trainer.state) if l.ndim == 1
+    ]
     _require(
-        len(vectors) >= 3 and set(vectors) <= set(aliased),
-        f"the step's vector leaves (parameters {vectors}) are aliased input "
-        f"to output, aliased are {aliased}",
+        len(vectors) == len(held) and set(vectors) <= set(aliased),
+        f"the state's {len(held)} vector leaves (parameters {vectors}) are "
+        f"aliased input to output, aliased are {aliased}",
     )
     predict_fn, _ = trainer._serve_fns()
     xp = tuple(jax.ShapeDtypeStruct((16, max_nnz), a.dtype) for a in x)

@@ -74,6 +74,15 @@ SPMD_PROTOCOLS = (
     "SSP",
 )
 
+# The protocols whose step reads the state leaf ``est``, the worker's model
+# at its last sync: GM and FGM measure their drift from it, Asynchronous and
+# SSP push their delta against it. The fleet state holds the leaf under
+# these alone (as it holds ``ef`` only under a transport codec): under
+# Synchronous and EASGD it would be a model-sized copy that nothing reads.
+# A snapshot from before that rule holds it under every protocol; the
+# restore paths drop it there (:func:`drop_unread_est`).
+EST_PROTOCOLS = frozenset({"GM", "FGM", "Asynchronous", "SSP"})
+
 
 # Compiled programs shared across same-config trainers. A fleet hosts
 # tens of thousands of pipelines whose step/serve/scan programs are
@@ -139,6 +148,17 @@ def stored(leaf):
 def stored_spec(leaf) -> P:
     """PartitionSpec of a stored leaf: one block per mesh shard."""
     return P(("dp", "hub")) if leaf.ndim == 1 else P("dp", "hub")
+
+
+def drop_unread_est(saved: dict, protocol: str) -> dict:
+    """A saved fleet state as a trainer under ``protocol`` holds it: without
+    the ``est`` a snapshot from before ``EST_PROTOCOLS`` carries under
+    Synchronous and EASGD (a copy of the weights at the last sync that no
+    code read). Nothing else is dropped: any other mismatch with the live
+    tree still fails where the state is placed."""
+    if protocol in EST_PROTOCOLS or "est" not in saved:
+        return saved
+    return {k: v for k, v in saved.items() if k != "est"}
 
 
 class SPMDTrainer:
@@ -297,13 +317,18 @@ class SPMDTrainer:
         # nodes do the same in on_start): a shared template seed would make
         # randomly-initialized learners (NN) register spurious drift and fire
         # a violation sync before any training happened
+        # (raveled on the host, in ``ravel_pytree``'s leaf order: raveling
+        # the host copy with jax would put the whole model on the device
+        # twice more, the memory peak of a process with a 1 GiB model)
         per_worker_flat = np.zeros((self.dp, self.flat_size), np.float32)
+        leaves_dp = [
+            np.asarray(l) for l in jax.tree_util.tree_leaves(params_dp)
+        ]
         for w in range(self.dp):
-            wf, _ = jax.flatten_util.ravel_pytree(
-                jax.tree_util.tree_map(lambda l: np.asarray(l)[w], params_dp)
-            )
-            per_worker_flat[w, : self.n_params] = np.asarray(wf)
-        vec = stack(per_worker_flat)
+            k = 0
+            for l in leaves_dp:
+                per_worker_flat[w, k : k + l[w].size] = np.ravel(l[w])
+                k += l[w].size
         # the center (EASGD center variable / async-SSP shared global) is PS
         # state: it must start IDENTICAL on every worker — its updates are
         # pure collectives, so replicas only stay in agreement if they agree
@@ -317,7 +342,6 @@ class SPMDTrainer:
         state = {
             "params": params,
             "preps": preps,
-            "est": vec.copy(),     # estimate at last sync (GM/FGM/async base)
             "center": stack(center0),  # EASGD center / async-SSP global
             "step": izero.copy(),
             "syncs": izero.copy(),
@@ -331,6 +355,10 @@ class SPMDTrainer:
             # executed (physical collective rounds; 0 for other protocols)
             "fold_rounds": izero.copy(),
         }
+        if self.protocol in EST_PROTOCOLS:
+            # estimate at last sync (GM/FGM drift base, async/SSP delta
+            # base): only where the step reads it
+            state["est"] = stack(per_worker_flat)
         if self._qdq is not None:
             # per-worker error-feedback residual for the transport codec:
             # the quantization error of each shipped vector, added back to
@@ -386,6 +414,7 @@ class SPMDTrainer:
         sparse = getattr(learner, "sparse", False)
 
         qdq = self._qdq  # transport codec QDQ kernel (None = raw fp32)
+        keeps_est = protocol in EST_PROTOCOLS
 
         def step_fn(state, x, y, mask):
             # per-shard views: state leaves as one shard stores them
@@ -408,7 +437,7 @@ class SPMDTrainer:
             prep_states = [
                 jax.tree_util.tree_map(shard_value, s) for s in state["preps"]
             ]
-            est = shard_value(state["est"])
+            est = shard_value(state["est"]) if keeps_est else None
             center = shard_value(state["center"])
             step_i = shard_value(state["step"])
             syncs = shard_value(state["syncs"])
@@ -451,61 +480,42 @@ class SPMDTrainer:
             # derived from mask so it carries the (dp, hub)-varying type
             accepted = jnp.sum(mask) * 0.0 + 1.0
 
+            def reduced(f, r):
+                """The fleet mean of ``f`` as it crosses the wire, and the
+                error-feedback residual left behind. Codec ship boundary:
+                the worker's contribution is quantized (with error
+                feedback) before entering the collective, and the
+                reassembled global is quantized again for the downlink —
+                both wire legs carry only codec-representable values."""
+                if qdq is None:
+                    return self._ps_allreduce(f), r
+                snd = f + r
+                t = qdq(snd)
+                return qdq(self._ps_allreduce(t)), snd - t
+
+            # the sync's ``lax.cond`` carries the leaves the state holds:
+            # ``est`` and ``ef`` are None (no leaf) where the state has none
+            def keep(*carry):
+                return carry
+
             if protocol == "Synchronous":
-                if qdq is None:
-                    def do_sync(f, e, c, s):
-                        g = self._ps_allreduce(f)
-                        return g, g, c, s + 1
+                def do_sync(f, c, s, r):
+                    g, r = reduced(f, r)
+                    return g, c, s + 1, r
 
-                    flat, est, center, syncs = jax.lax.cond(
-                        at_cadence, do_sync,
-                        lambda f, e, c, s: (f, e, c, s),
-                        flat, est, center, syncs,
-                    )
-                else:
-                    # codec ship boundary: the worker's contribution is
-                    # quantized (with error feedback) before entering the
-                    # collective, and the reassembled global is quantized
-                    # again for the downlink — both wire legs carry only
-                    # codec-representable values
-                    def do_sync(f, e, c, s, r):
-                        snd = f + r
-                        t = qdq(snd)
-                        g = qdq(self._ps_allreduce(t))
-                        return g, g, c, s + 1, snd - t
-
-                    flat, est, center, syncs, ef = jax.lax.cond(
-                        at_cadence, do_sync,
-                        lambda f, e, c, s, r: (f, e, c, s, r),
-                        flat, est, center, syncs, ef,
-                    )
+                flat, center, syncs, ef = jax.lax.cond(
+                    at_cadence, do_sync, keep, flat, center, syncs, ef,
+                )
             elif protocol == "EASGD":
-                if qdq is None:
-                    def do_sync(f, e, c, s):
-                        mean_x = self._ps_allreduce(f)
-                        new_c = c + alpha * n_workers * (mean_x - c)
-                        new_f = f - alpha * (f - c)
-                        return new_f, e, new_c, s + 1
+                def do_sync(f, c, s, r):
+                    mean_x, r = reduced(f, r)
+                    new_c = c + alpha * n_workers * (mean_x - c)
+                    new_f = f - alpha * (f - c)
+                    return new_f, new_c, s + 1, r
 
-                    flat, est, center, syncs = jax.lax.cond(
-                        at_cadence, do_sync,
-                        lambda f, e, c, s: (f, e, c, s),
-                        flat, est, center, syncs,
-                    )
-                else:
-                    def do_sync(f, e, c, s, r):
-                        snd = f + r
-                        t = qdq(snd)
-                        mean_x = qdq(self._ps_allreduce(t))
-                        new_c = c + alpha * n_workers * (mean_x - c)
-                        new_f = f - alpha * (f - c)
-                        return new_f, e, new_c, s + 1, snd - t
-
-                    flat, est, center, syncs, ef = jax.lax.cond(
-                        at_cadence, do_sync,
-                        lambda f, e, c, s, r: (f, e, c, s, r),
-                        flat, est, center, syncs, ef,
-                    )
+                flat, center, syncs, ef = jax.lax.cond(
+                    at_cadence, do_sync, keep, flat, center, syncs, ef,
+                )
             elif protocol in ("GM", "FGM"):
                 drift2 = jnp.sum((flat - est) ** 2)
                 if protocol == "GM":
@@ -519,28 +529,14 @@ class SPMDTrainer:
                     psi = jax.lax.psum(drift2 - threshold**2, "dp")
                     fire = psi >= 0.0
 
-                if qdq is None:
-                    def do_sync(f, e, c, s):
-                        g = self._ps_allreduce(f)
-                        return g, g, c, s + 1
+                def do_sync(f, e, c, s, r):
+                    g, r = reduced(f, r)
+                    return g, g, c, s + 1, r
 
-                    flat, est, center, syncs = jax.lax.cond(
-                        jnp.logical_and(at_cadence, fire), do_sync,
-                        lambda f, e, c, s: (f, e, c, s),
-                        flat, est, center, syncs,
-                    )
-                else:
-                    def do_sync(f, e, c, s, r):
-                        snd = f + r
-                        t = qdq(snd)
-                        g = qdq(self._ps_allreduce(t))
-                        return g, g, c, s + 1, snd - t
-
-                    flat, est, center, syncs, ef = jax.lax.cond(
-                        jnp.logical_and(at_cadence, fire), do_sync,
-                        lambda f, e, c, s, r: (f, e, c, s, r),
-                        flat, est, center, syncs, ef,
-                    )
+                flat, est, center, syncs, ef = jax.lax.cond(
+                    jnp.logical_and(at_cadence, fire), do_sync, keep,
+                    flat, est, center, syncs, ef,
+                )
             else:  # Asynchronous / SSP: event-driven progress + PS folds
                 # progress is per-worker: a worker only advances its clock
                 # on ticks where it has data; under SSP a worker whose
@@ -624,7 +620,6 @@ class SPMDTrainer:
                 "preps": [
                     jax.tree_util.tree_map(shard_block, s) for s in new_preps
                 ],
-                "est": shard_block(est),
                 "center": shard_block(center),
                 "step": shard_block(step_i),
                 "syncs": shard_block(syncs),
@@ -633,6 +628,8 @@ class SPMDTrainer:
                 "accepted": shard_block(accepted),
                 "fold_rounds": shard_block(fold_rounds),
             }
+            if keeps_est:
+                new_state["est"] = shard_block(est)
             if qdq is not None:
                 new_state["ef"] = shard_block(ef)
             counted = () if counters is None else (counters[None, None],)
@@ -949,10 +946,14 @@ class SPMDTrainer:
     def load(self, directory: str) -> None:
         """Restore fleet state saved by :meth:`save` (same mesh shape). A
         snapshot whose vector leaves were saved ``[dp, hub, n]`` loads too:
-        :func:`stored` brings either form to the stored one."""
+        :func:`stored` brings either form to the stored one. So does one
+        that holds an ``est`` this protocol's state has none of
+        (:func:`drop_unread_est`)."""
         from omldm_tpu.parallel.ckpt import load_tree, place_tree
 
-        host_state = jax.tree_util.tree_map(stored, load_tree(directory))
+        host_state = jax.tree_util.tree_map(
+            stored, drop_unread_est(load_tree(directory), self.protocol)
+        )
         self.state = place_tree(host_state, self._state_specs, self.mesh)
 
     def _serve_fns(self):

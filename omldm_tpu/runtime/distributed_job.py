@@ -105,6 +105,39 @@ def _interleave_rows(blocks: List[np.ndarray]) -> np.ndarray:
     return cat[_interleave_perm([b.shape[0] for b in blocks])]
 
 
+def _saved_leaf_positions(
+    state: Any, protocol: str, n_saved: int, source: str
+) -> List[int]:
+    """Where each leaf of the live fleet ``state`` (``tree_leaves`` order)
+    sits in the fleet file ``source``, which holds ``n_saved`` leaves and
+    names them by that order alone. The file of a pipeline under a protocol
+    that no longer keeps ``est`` (``spmd.EST_PROTOCOLS``) may date from when
+    it did: it then holds exactly one leaf more, and the position ``est``
+    had there is skipped. Any other difference in count is refused:
+    restored by index, every later leaf would land on the wrong one."""
+    import jax
+
+    from omldm_tpu.parallel.spmd import EST_PROTOCOLS
+
+    def keys(tree):
+        return [
+            str(getattr(path[0], "key", path[0]))
+            for path, _ in jax.tree_util.tree_flatten_with_path(tree)[0]
+        ]
+
+    live = keys(state)
+    if n_saved == len(live):
+        return list(range(n_saved))
+    if n_saved == len(live) + 1 and protocol not in EST_PROTOCOLS:
+        was = keys({**state, "est": 0}).index("est")
+        return [i + (i >= was) for i in range(len(live))]
+    raise ValueError(
+        f"{source} holds {n_saved} leaves, the state of a {protocol} pipeline "
+        f"{len(live)} ({', '.join(live)}): it is not a snapshot of this "
+        "pipeline"
+    )
+
+
 def _rescale_fleet_leaf(full: np.ndarray, key: str, dp_new: int) -> np.ndarray:
     """Redistribute one gathered fleet-state leaf (leading axis = the
     global dp worker rows) across a NEW worker-row count:
@@ -1961,12 +1994,17 @@ class DistributedStreamJob:
             # leaf index -> top-level state key (params/preps/ef/...) so
             # the rescale redistribution can apply per-leaf merge rules;
             # tree_flatten_with_path walks the same order tree_leaves
-            # walked at save time
+            # walked at save time (a file from before the state dropped an
+            # unread ``est`` has it in between: _saved_leaf_positions)
             paths_leaves, treedef = jax.tree_util.tree_flatten_with_path(
                 p.trainer.state
             )
+            saved_at = _saved_leaf_positions(
+                p.trainer.state, p.trainer.protocol, len(fleet.files),
+                os.path.join(d, f"fleet_{net_id}.npz"),
+            )
             placed = []
-            for i, (path, live) in enumerate(paths_leaves):
+            for i, (path, live) in zip(saved_at, paths_leaves):
                 key = str(getattr(path[0], "key", path[0]))
                 # saved stored; redistributed by worker row in the
                 # [dp, hub, ...] view; placed stored again. The stored

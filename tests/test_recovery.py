@@ -290,6 +290,56 @@ class TestSPMDBridgeCheckpoint:
         np.testing.assert_allclose(got[0, 0], expect, rtol=1e-6, atol=1e-7)
 
 
+    @pytest.mark.parametrize("protocol", ["Synchronous", "EASGD"])
+    @pytest.mark.parametrize("parallelism", [2, 1])
+    def test_snapshot_with_an_unread_est_restores_without_it(
+        self, tmp_path, parallelism, protocol
+    ):
+        """A job snapshot from before Synchronous / EASGD dropped the
+        ``est`` that nothing read holds one in the bridge's fleet state: it
+        restores on the same mesh and under a rescale (where the merge
+        takes ``est`` only where the trainer keeps it), and trains on."""
+        import pickle
+
+        create = dict(self.CREATE_SPMD)
+        create["trainingConfiguration"] = {
+            **create["trainingConfiguration"], "protocol": protocol,
+        }
+        cfg = JobConfig(parallelism=2, batch_size=16, test_set_size=32)
+        job = StreamJob(cfg)
+        events = [(REQUEST_STREAM, json.dumps(create))] + [
+            (TRAINING_STREAM, l) for l in stream_lines(500, seed=0)
+        ]
+        job.run(events, terminate_on_end=False)
+        job.spmd_bridges[0].flush()
+        mgr = CheckpointManager(str(tmp_path / "ck"))
+        path = mgr.save(job)
+        with open(path, "rb") as f:
+            snapshot = pickle.load(f)
+        bd = snapshot["bridges"][0]
+        assert "est" not in bd["fleet"]
+        bd["fleet"] = {**bd["fleet"], "est": bd["fleet"]["center"] + 3.0}
+        with open(path, "wb") as f:
+            pickle.dump(snapshot, f)
+        restored = mgr.restore(parallelism=parallelism)
+        trainer = restored.spmd_bridges[0].trainer
+        donor = job.spmd_bridges[0].trainer
+        assert sorted(trainer.state) == sorted(donor.state)
+        saved = donor.host_stacked(donor.state["params"]["w"])
+        got = trainer.host_stacked(trainer.state["params"]["w"])
+        if parallelism == 2:
+            np.testing.assert_array_equal(got, saved)
+        else:
+            np.testing.assert_allclose(
+                got[0, 0], saved[:, 0].mean(axis=0), rtol=1e-6, atol=1e-7
+            )
+        report = restored.run(
+            [(TRAINING_STREAM, l) for l in stream_lines(400, seed=1)]
+        )
+        [stats] = report.statistics
+        assert stats.score > 0.8
+
+
 class TestCentralModelRescaleRestore:
     CREATE_SL = {
         "id": 0,

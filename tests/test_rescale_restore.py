@@ -173,11 +173,11 @@ CREATE = json.dumps({
 })
 
 
-def _one_proc_job(test_cap=16):
+def _one_proc_job(test_cap=16, create=CREATE):
     job = DistributedStreamJob(
         JobConfig(batch_size=8, test_set_size=test_cap)
     )
-    job.sync_requests([CREATE])
+    job.sync_requests([create])
     return job
 
 
@@ -190,6 +190,21 @@ def _feed(job, n=200, seed=0):
     return x
 
 
+def _rewrite_fleet_file(d, fleet):
+    """Replace the snapshot's fleet file and refresh its integrity digest."""
+    from omldm_tpu.runtime.distributed_job import _file_sha256
+
+    np.savez(os.path.join(d, "fleet_0.npz"), **fleet)
+    with open(os.path.join(d, "manifest.json")) as f:
+        manifest = json.load(f)
+    if manifest.get("digests"):
+        manifest["digests"]["fleet_0.npz"] = _file_sha256(
+            os.path.join(d, "fleet_0.npz")
+        )
+    with open(os.path.join(d, "manifest.json"), "w") as f:
+        json.dump(manifest, f)
+
+
 def _fabricate_two_proc_snapshot(d, scale_row1=1.5, preds1=(9.0,)):
     """Turn a 1-process snapshot into a format-valid 2-process one: fleet
     leaves gain a second worker row (float leaves scaled so merges are
@@ -198,7 +213,7 @@ def _fabricate_two_proc_snapshot(d, scale_row1=1.5, preds1=(9.0,)):
     for k, leaf in fleet.items():
         row1 = leaf * scale_row1 if leaf.dtype.kind == "f" else leaf.copy()
         fleet[k] = np.concatenate([leaf, row1], axis=0)
-    np.savez(os.path.join(d, "fleet_0.npz"), **fleet)
+    _rewrite_fleet_file(d, fleet)
     with open(os.path.join(d, "proc0.json")) as f:
         meta1 = json.load(f)
     meta1["pipelines"]["0"]["predictions"] = list(preds1)
@@ -211,14 +226,8 @@ def _fabricate_two_proc_snapshot(d, scale_row1=1.5, preds1=(9.0,)):
         manifest = json.load(f)
     manifest["processes"] = 2
     manifest["dp_global"] = 2
-    # refresh the integrity digest of the rewritten fleet file (proc1's
-    # npz is a byte copy of proc0's, so its meta digest still matches)
-    from omldm_tpu.runtime.distributed_job import _file_sha256
-
-    if manifest.get("digests"):
-        manifest["digests"]["fleet_0.npz"] = _file_sha256(
-            os.path.join(d, "fleet_0.npz")
-        )
+    # (proc1's npz is a byte copy of proc0's, so its meta digest still
+    # matches)
     with open(os.path.join(d, "manifest.json"), "w") as f:
         json.dump(manifest, f)
 
@@ -339,6 +348,89 @@ class TestShrinkRestoreInProcess:
         restored._rescale_count_pinned = True
         restored.restore_checkpoint(root)
         assert restored.rescales_performed == 3
+
+
+def _create(protocol, codec="none"):
+    return json.dumps({
+        **json.loads(CREATE),
+        "trainingConfiguration": {
+            "protocol": protocol, "syncEvery": 1, "codec": codec,
+            "threshold": 0.05,
+        },
+    })
+
+
+def _saved_leaves(d):
+    fleet = np.load(os.path.join(d, "fleet_0.npz"))
+    return [fleet[f"leaf_{i}"] for i in range(len(fleet.files))]
+
+
+class TestFleetFileLeafOrder:
+    """The fleet file names its leaves by their place in ``tree_leaves``
+    order. A file from before Synchronous / EASGD dropped the ``est`` that
+    nothing read holds it in between the others."""
+
+    @pytest.mark.parametrize("codec", ["none", "int8"])
+    @pytest.mark.parametrize("protocol", ["Synchronous", "EASGD"])
+    def test_file_in_the_old_leaf_order_restores_every_leaf_where_it_belongs(
+        self, tmp_path, protocol, codec
+    ):
+        create = _create(protocol, codec)
+        job = _one_proc_job(create=create)
+        _feed(job)
+        job.pump()
+        root = str(tmp_path / "ck")
+        d = job.save_checkpoint(root, 200)
+        state = job.pipelines[0].trainer.state
+        assert "est" not in state and ("ef" in state) == (codec != "none")
+        # the old tree: the same leaves with ``est`` among them, flattened
+        # in the order the old save walked
+        treedef = jax.tree_util.tree_structure(state)
+        saved = jax.tree_util.tree_unflatten(treedef, _saved_leaves(d))
+        old = {**saved, "est": saved["center"] * 0.5 + 7.0}
+        old_leaves = jax.tree_util.tree_leaves(old)
+        assert len(old_leaves) == treedef.num_leaves + 1
+        _rewrite_fleet_file(
+            d, {f"leaf_{i}": l for i, l in enumerate(old_leaves)}
+        )
+
+        restored = _one_proc_job(create=create)
+        assert restored.restore_checkpoint(root) == 200
+        got = restored.pipelines[0].trainer.state
+        assert jax.tree_util.tree_structure(got) == treedef
+        for (path, a), b in zip(
+            jax.tree_util.tree_flatten_with_path(got)[0],
+            jax.tree_util.tree_leaves(state),
+        ):
+            assert a.dtype == b.dtype and a.shape == b.shape, path
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b), str(path))
+        # and the restored fleet goes on as the donor does
+        for j in (job, restored):
+            _feed(j, n=40, seed=1)
+            j.pump(final=True)
+        assert (
+            _params_leaf(restored.pipelines[0].trainer.state)
+            == _params_leaf(job.pipelines[0].trainer.state)
+        ).all()
+
+    @pytest.mark.parametrize(
+        "protocol,extra", [("Synchronous", 2), ("Synchronous", -1), ("GM", 1)]
+    )
+    def test_any_other_leaf_count_is_refused(self, tmp_path, protocol, extra):
+        create = _create(protocol)
+        job = _one_proc_job(create=create)
+        _feed(job)
+        job.pump()
+        root = str(tmp_path / "ck")
+        d = job.save_checkpoint(root, 200)
+        leaves = _saved_leaves(d)
+        leaves = leaves[:extra] if extra < 0 else leaves + leaves[:extra]
+        _rewrite_fleet_file(
+            d, {f"leaf_{i}": l for i, l in enumerate(leaves)}
+        )
+        restored = _one_proc_job(create=create)
+        with pytest.raises(ValueError, match=r"fleet_0\.npz holds \d+ leaves"):
+            restored.restore_checkpoint(root)
 
 
 # --- real multi-process fleets (slow) ----------------------------------------

@@ -18,6 +18,9 @@ from omldm_tpu.parallel.ckpt import save_tree
 from omldm_tpu.parallel.spmd import SPMD_PROTOCOLS, stacked, stored
 
 SYNC_EVERY = 3
+# the protocols whose step reads ``est`` (drift under GM / FGM, the delta's
+# base under Asynchronous / SSP): the state holds the leaf under these alone
+READ_EST = ("GM", "FGM", "Asynchronous", "SSP")
 
 
 def _trainer(learner, dim, protocol="Synchronous", dp=1, hub=1, batch=16,
@@ -101,10 +104,20 @@ ONE_CHIP_LEARNERS = {
 }
 
 
-@pytest.mark.parametrize("case", sorted(ONE_CHIP_LEARNERS))
-def test_steps_equal_learner_updates_bit_for_bit(case):
+# every learner under Synchronous, and one under GM with a threshold that
+# every cadence step violates: on one chip its sync, too, changes no weight
+STEP_CASES = [(c, "Synchronous") for c in sorted(ONE_CHIP_LEARNERS)] + [
+    ("sparse_pa2", "GM")
+]
+
+
+@pytest.mark.parametrize(
+    "case,protocol", STEP_CASES,
+    ids=[c if p == "Synchronous" else f"{c}-{p}" for c, p in STEP_CASES],
+)
+def test_steps_equal_learner_updates_bit_for_bit(case, protocol):
     spec, dim = ONE_CHIP_LEARNERS[case]
-    tr = _trainer(spec, dim)
+    tr = _trainer(spec, dim, protocol, extra={"threshold": 1e-9})
     sparse = getattr(tr.learner, "sparse", False)
     k = 2 * SYNC_EVERY + 1
     batches = (
@@ -123,8 +136,11 @@ def test_steps_equal_learner_updates_bit_for_bit(case):
             jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(params)
         ):
             np.testing.assert_array_equal(a, np.asarray(b))
-        if i % SYNC_EVERY == 0:
-            # the protocol's state after a sync: est == w
+        if protocol == "Synchronous":
+            # nothing reads an estimate at the last sync: none is held
+            assert "est" not in tr.state
+        elif i % SYNC_EVERY == 0:
+            # the protocol's state after a fired sync: est == w
             est = tr.host_stacked(tr.state["est"])[0, 0]
             np.testing.assert_array_equal(est, tr.global_flat_params())
     assert tr.sync_count() == k // SYNC_EVERY
@@ -152,7 +168,10 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
     state = tr.state
 
     # vector leaves are stored flat, one block a shard; the others stacked
-    assert state["est"].shape == (dp * hub * tr.flat_size,)
+    assert ("est" in state) == (protocol in READ_EST)
+    assert state["center"].shape == (dp * hub * tr.flat_size,)
+    if protocol in READ_EST:
+        assert state["est"].shape == (dp * hub * tr.flat_size,)
     assert state["params"]["w"].shape == (dp * hub * (dim + 1),)
     assert state["step"].shape == (dp, hub)
     for leaf in jax.tree_util.tree_leaves(state):
@@ -179,6 +198,8 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
     assert tr.collective_bytes_physical() > 0 or tr.sync_count() == 0
     # the stacked view of the whole leaf is the shards, in mesh order
     for key in ("est", "center", "step", "cum_loss"):
+        if key not in state:
+            continue
         full = tr.host_stacked(state[key])
         assert full.shape[:2] == (dp, hub)
         for (i, j), v in held[key].items():
@@ -218,7 +239,7 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
     old_form = jax.tree_util.tree_map(
         lambda l: stacked(np.asarray(l), dp, hub), state
     )
-    assert old_form["est"].shape == (dp, hub, tr.flat_size)
+    assert old_form["center"].shape == (dp, hub, tr.flat_size)
     save_tree(str(tmp_path / "old"), old_form)
     fresh.load(str(tmp_path / "old"))
     for a, b in zip(
@@ -230,6 +251,57 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
     np.testing.assert_array_equal(
         np.asarray(fresh.step(x, y, m)), np.asarray(tr.step(x, y, m))
     )
+
+
+@pytest.mark.parametrize("codec", ["none", "int8"])
+@pytest.mark.parametrize("protocol", ["Synchronous", "EASGD"])
+def test_snapshot_with_an_unread_est_loads_without_it(protocol, codec, tmp_path):
+    """A snapshot from before the state dropped the ``est`` that Synchronous
+    and EASGD never read holds one: it loads into the tree as it is now, and
+    the next step equals the donor's bit for bit. Only that leaf is let go:
+    any other that the live tree lacks, or misses, still fails."""
+    dp, hub, dim = 2, 2, 6
+    spec = LearnerSpec("PA", hyper_parameters={"C": 1.0})
+
+    def build(protocol=protocol):
+        return _trainer(spec, dim, protocol, dp, hub, preps=("StandardScaler",),
+                        extra={"codec": codec, "threshold": 0.05})
+
+    tr = build()
+    for x, y, m in _dense_batches(SYNC_EVERY + 1, dp, 16, dim, seed=3):
+        tr.step(x, y, m)
+    assert "est" not in tr.state and ("ef" in tr.state) == (codec != "none")
+    host = jax.tree_util.tree_map(np.asarray, tr.state)
+    # the old form: est beside the others, a vector leaf like center
+    old = {**host, "est": host["params"]["w"] * 0.5}
+    save_tree(str(tmp_path / "old"), old)
+    fresh = build()
+    fresh.load(str(tmp_path / "old"))
+    assert (
+        jax.tree_util.tree_structure(fresh.state)
+        == jax.tree_util.tree_structure(tr.state)
+    )
+    for a, b in zip(
+        jax.tree_util.tree_leaves(fresh.state), jax.tree_util.tree_leaves(tr.state)
+    ):
+        assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for x, y, m in _dense_batches(SYNC_EVERY, dp, 16, dim, seed=5):
+        np.testing.assert_array_equal(
+            np.asarray(fresh.step(x, y, m)), np.asarray(tr.step(x, y, m))
+        )
+    np.testing.assert_array_equal(
+        fresh.global_flat_params(), tr.global_flat_params()
+    )
+    assert fresh.sync_count() == tr.sync_count() > 0
+    assert fresh.bytes_shipped() == tr.bytes_shipped()
+    # a leaf the tree has none of, other than est, is not let go
+    save_tree(str(tmp_path / "odd"), {**host, "drift": host["center"]})
+    with pytest.raises(ValueError):
+        build().load(str(tmp_path / "odd"))
+    # and a protocol that reads est does not load a snapshot without one
+    with pytest.raises(ValueError):
+        build("GM").load(str(tmp_path / "odd"))
 
 
 @pytest.mark.parametrize("shape", [(2, 3), (2, 3, 5), (2, 3, 5, 4), (1, 1, 7)])
@@ -345,6 +417,9 @@ def test_hlo_guard_names_a_relayout_and_spares_the_scatter_and_the_sync():
     assert sorted(name for _, name, _ in found) == [
         "broadcast.46", "dynamic-slice.1", "reduce.1", "while.1",
     ]
+    # walked too, the sync branch gives its copy away
+    every = chip_smoke.hlo_wide_passes(_HLO_RELAYOUT, 1000, every_branch=True)
+    assert sorted(set(every) - set(found)) == [("sync", "copy.7", "copy")]
     assert chip_smoke.hlo_aliased_parameters(_HLO_RELAYOUT) == [0]
     clean = "\n".join(
         l for l in _HLO_RELAYOUT.splitlines()
