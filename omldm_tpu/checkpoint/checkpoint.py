@@ -418,15 +418,15 @@ class CheckpointManager:
         if bridge is None:
             return
         from omldm_tpu.parallel.ckpt import place_tree
-        from omldm_tpu.parallel.spmd import drop_unread_est, stacked, stored
+        from omldm_tpu.parallel.spmd import drop_unread_leaves, stacked, stored
 
         t = bridge.trainer
         # the saved leaves in their [dp, hub, ...] view (a snapshot from
         # before vector leaves were stored flat already is), without an
-        # ``est`` that this protocol's state has none of
+        # ``est`` or a ``center`` that this protocol's state has none of
         fleet = jax.tree_util.tree_map(
             lambda l: stacked(np.asarray(l), *bd["mesh"]),
-            drop_unread_est(bd["fleet"], t.protocol),
+            drop_unread_leaves(bd["fleet"], t.protocol),
         )
         if (t.dp, t.hub) == tuple(bd["mesh"]):
             new_state = fleet
@@ -451,15 +451,15 @@ class CheckpointManager:
                     jax.tree_util.tree_map(merge_tile, p)
                     for p in fleet["preps"]
                 ],
-                "center": merge_tile(fleet["center"]),
                 "step": tile(fleet["step"]),
                 "syncs": tile(fleet["syncs"]),
                 "cum_loss": tile(fleet["cum_loss"]),
                 "clock": np.zeros_like(tile(fleet["clock"])),
                 "accepted": np.ones_like(tile(fleet["accepted"])),
             }
-            if "est" in fleet:  # only under the protocols that read it
-                new_state["est"] = merge_tile(fleet["est"])
+            for key in ("est", "center"):  # only under the protocols that read them
+                if key in fleet:
+                    new_state[key] = merge_tile(fleet[key])
             # call-site byte counters and any protocol-specific extras
             # carry over worker-0's values so accounting stays monotonic
             for key, val in fleet.items():

@@ -4,7 +4,7 @@ Reference counterpart: ``ValidLists.learners = PA, RegressorPA, ORR, SVM,
 MultiClassPA, K-means, NN, HT``
 (reference: src/main/scala/omldm/utils/parsers/requestStream/PipelineMap.scala:66-69).
 ``Softmax`` is an extension (BASELINE.md config 5: multiclass softmax +
-hashed features).
+hashed features), and so is ``LM`` (a language model over token rows).
 """
 
 from __future__ import annotations
@@ -24,6 +24,7 @@ from omldm_tpu.learners.linear import (
 )
 from omldm_tpu.learners.multiclass_pa import MultiClassPA
 from omldm_tpu.learners.nn import NeuralNetwork
+from omldm_tpu.learners.seq_lm import SequenceLM
 
 LEARNERS: Dict[str, Type[Learner]] = {
     "PA": PAClassifier,
@@ -36,6 +37,8 @@ LEARNERS: Dict[str, Type[Learner]] = {
     "HT": HoeffdingTree,
     # extension beyond the reference allowlist
     "Softmax": SoftmaxClassifier,
+    # a decoder trained on token rows (models/olmo_hybrid.py)
+    "LM": SequenceLM,
 }
 
 # Learners the reference forces onto the SingleLearner protocol (one central

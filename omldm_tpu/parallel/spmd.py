@@ -80,8 +80,23 @@ SPMD_PROTOCOLS = (
 # these alone (as it holds ``ef`` only under a transport codec): under
 # Synchronous and EASGD it would be a model-sized copy that nothing reads.
 # A snapshot from before that rule holds it under every protocol; the
-# restore paths drop it there (:func:`drop_unread_est`).
+# restore paths drop it there (:func:`drop_unread_leaves`).
 EST_PROTOCOLS = frozenset({"GM", "FGM", "Asynchronous", "SSP"})
+
+# Likewise ``center``, the EASGD center variable and the shared global that
+# Asynchronous and SSP fold their deltas into: a model-sized leaf that
+# Synchronous, GM and FGM would carry through their sync untouched.
+CENTER_PROTOCOLS = frozenset({"EASGD", "Asynchronous", "SSP"})
+
+# leaf -> the protocols that hold it, in the order the state let the unread
+# ones go (``est`` first): a snapshot from before holds them still, and the
+# restore paths drop them (:func:`drop_unread_leaves`, :func:`unread_leaves`).
+READ_UNDER = {"est": EST_PROTOCOLS, "center": CENTER_PROTOCOLS}
+
+
+def unread_leaves(protocol: str) -> List[str]:
+    """The model-sized leaves the state of ``protocol`` does not hold."""
+    return [k for k, held in READ_UNDER.items() if protocol not in held]
 
 
 # Compiled programs shared across same-config trainers. A fleet hosts
@@ -150,15 +165,16 @@ def stored_spec(leaf) -> P:
     return P(("dp", "hub")) if leaf.ndim == 1 else P("dp", "hub")
 
 
-def drop_unread_est(saved: dict, protocol: str) -> dict:
+def drop_unread_leaves(saved: dict, protocol: str) -> dict:
     """A saved fleet state as a trainer under ``protocol`` holds it: without
-    the ``est`` a snapshot from before ``EST_PROTOCOLS`` carries under
-    Synchronous and EASGD (a copy of the weights at the last sync that no
-    code read). Nothing else is dropped: any other mismatch with the live
-    tree still fails where the state is placed."""
-    if protocol in EST_PROTOCOLS or "est" not in saved:
+    the ``est`` and the ``center`` that a snapshot from before
+    ``READ_UNDER`` carries under the protocols that read neither (model-sized
+    copies no code read). Nothing else is dropped: any other mismatch with
+    the live tree still fails where the state is placed."""
+    unread = [k for k in unread_leaves(protocol) if k in saved]
+    if not unread:
         return saved
-    return {k: v for k, v in saved.items() if k != "est"}
+    return {k: v for k, v in saved.items() if k not in unread}
 
 
 class SPMDTrainer:
@@ -236,17 +252,21 @@ class SPMDTrainer:
         self.learner_dim = d
 
         with tracing.span("build_state"):
-            # template params -> flat layout shared by every replica
-            template = self.learner.init(d, jax.random.PRNGKey(seed))
-            flat0, _ = jax.flatten_util.ravel_pytree(template)
-            self._template = jax.eval_shape(lambda: template)
-            self.n_params = int(flat0.size)
+            # the parameters' shapes -> flat layout shared by every replica
+            # (shapes alone: a model is not built here to be counted)
+            self._template = jax.eval_shape(
+                lambda: self.learner.init(d, jax.random.PRNGKey(seed))
+            )
+            self.n_params = sum(
+                int(np.prod(l.shape))
+                for l in jax.tree_util.tree_leaves(self._template)
+            )
             self.pad = (-self.n_params) % self.hub
             self.flat_size = self.n_params + self.pad
             self.shard_size = self.flat_size // self.hub
 
             with tracing.span("init_state_host"):
-                state_host = self._init_state(seed, prep_dims, template)
+                state_host = self._init_state(seed, prep_dims)
             self._state_specs = jax.tree_util.tree_map(stored_spec, state_host)
             # ends when device_put returns: the copies may still be in flight
             with tracing.span("place_state"):
@@ -298,7 +318,7 @@ class SPMDTrainer:
 
     # --- state construction ---
 
-    def _init_state(self, seed: int, prep_dims, template):
+    def _init_state(self, seed: int, prep_dims):
         keys = jax.random.split(jax.random.PRNGKey(seed), self.dp)
         params_dp = jax.vmap(lambda k: self.learner.init(self.learner_dim, k))(keys)
 
@@ -313,36 +333,11 @@ class SPMDTrainer:
             )
             for p, di in zip(self.preps, prep_dims)
         ]
-        # drift estimates seed from each worker's OWN init (the host-plane
-        # nodes do the same in on_start): a shared template seed would make
-        # randomly-initialized learners (NN) register spurious drift and fire
-        # a violation sync before any training happened
-        # (raveled on the host, in ``ravel_pytree``'s leaf order: raveling
-        # the host copy with jax would put the whole model on the device
-        # twice more, the memory peak of a process with a 1 GiB model)
-        per_worker_flat = np.zeros((self.dp, self.flat_size), np.float32)
-        leaves_dp = [
-            np.asarray(l) for l in jax.tree_util.tree_leaves(params_dp)
-        ]
-        for w in range(self.dp):
-            k = 0
-            for l in leaves_dp:
-                per_worker_flat[w, k : k + l[w].size] = np.ravel(l[w])
-                k += l[w].size
-        # the center (EASGD center variable / async-SSP shared global) is PS
-        # state: it must start IDENTICAL on every worker — its updates are
-        # pure collectives, so replicas only stay in agreement if they agree
-        # at step 0. Seed it with the fleet-mean init.
-        center0 = np.broadcast_to(
-            per_worker_flat.mean(axis=0, keepdims=True),
-            per_worker_flat.shape,
-        )
         zero = stack(np.zeros((self.dp,), np.float32))
         izero = stack(np.zeros((self.dp,), np.int32))
         state = {
             "params": params,
             "preps": preps,
-            "center": stack(center0),  # EASGD center / async-SSP global
             "step": izero.copy(),
             "syncs": izero.copy(),
             "cum_loss": zero.copy(),
@@ -355,10 +350,39 @@ class SPMDTrainer:
             # executed (physical collective rounds; 0 for other protocols)
             "fold_rounds": izero.copy(),
         }
+        if self.protocol in EST_PROTOCOLS | CENTER_PROTOCOLS:
+            # drift estimates seed from each worker's OWN init (the
+            # host-plane nodes do the same in on_start): a shared template
+            # seed would make randomly-initialized learners (NN) register
+            # spurious drift and fire a violation sync before any training
+            # happened
+            # (raveled on the host, in ``ravel_pytree``'s leaf order:
+            # raveling the host copy with jax would put the whole model on
+            # the device twice more, the memory peak of a process with a
+            # 1 GiB model)
+            per_worker_flat = np.zeros((self.dp, self.flat_size), np.float32)
+            leaves_dp = [
+                np.asarray(l) for l in jax.tree_util.tree_leaves(params_dp)
+            ]
+            for w in range(self.dp):
+                k = 0
+                for l in leaves_dp:
+                    per_worker_flat[w, k : k + l[w].size] = np.ravel(l[w])
+                    k += l[w].size
         if self.protocol in EST_PROTOCOLS:
             # estimate at last sync (GM/FGM drift base, async/SSP delta
             # base): only where the step reads it
             state["est"] = stack(per_worker_flat)
+        if self.protocol in CENTER_PROTOCOLS:
+            # the center (EASGD center variable / async-SSP shared global)
+            # is PS state: it must start IDENTICAL on every worker — its
+            # updates are pure collectives, so replicas only stay in
+            # agreement if they agree at step 0. Seed it with the
+            # fleet-mean init. Only where the step reads it.
+            state["center"] = stack(np.broadcast_to(
+                per_worker_flat.mean(axis=0, keepdims=True),
+                per_worker_flat.shape,
+            ))
         if self._qdq is not None:
             # per-worker error-feedback residual for the transport codec:
             # the quantization error of each shipped vector, added back to
@@ -415,6 +439,17 @@ class SPMDTrainer:
 
         qdq = self._qdq  # transport codec QDQ kernel (None = raw fp32)
         keeps_est = protocol in EST_PROTOCOLS
+        keeps_center = protocol in CENTER_PROTOCOLS
+        # the flat form of the parameters is what the collectives and the
+        # protocols' drift and center arithmetic read. On one shard under
+        # Synchronous with no codec nothing does (the mean over one worker
+        # is that worker's own model, bit for bit): the learner's new
+        # parameters are the state's, and the model is neither concatenated
+        # nor split. Read from the mesh and the protocol, never the learner.
+        reads_flat = not (
+            protocol == "Synchronous" and self.dp * self.hub == 1
+            and qdq is None
+        )
 
         def step_fn(state, x, y, mask):
             # per-shard views: state leaves as one shard stores them
@@ -438,7 +473,7 @@ class SPMDTrainer:
                 jax.tree_util.tree_map(shard_value, s) for s in state["preps"]
             ]
             est = shard_value(state["est"]) if keeps_est else None
-            center = shard_value(state["center"])
+            center = shard_value(state["center"]) if keeps_center else None
             step_i = shard_value(state["step"])
             syncs = shard_value(state["syncs"])
             cum_loss = shard_value(state["cum_loss"])
@@ -473,7 +508,7 @@ class SPMDTrainer:
                 )
                 params, loss = update(params, z, y, mask)
 
-            flat = self._flat(params)
+            flat = self._flat(params) if reads_flat else None
             step_i = step_i + 1
             at_cadence = (step_i % sync_every) == 0
             has_data = jnp.sum(mask) > 0.0
@@ -494,17 +529,20 @@ class SPMDTrainer:
                 return qdq(self._ps_allreduce(t)), snd - t
 
             # the sync's ``lax.cond`` carries the leaves the state holds:
-            # ``est`` and ``ef`` are None (no leaf) where the state has none
+            # ``est``, ``center`` and ``ef`` are None (no leaf) where the
+            # state has none
             def keep(*carry):
                 return carry
 
-            if protocol == "Synchronous":
-                def do_sync(f, c, s, r):
+            if not reads_flat:
+                syncs = syncs + at_cadence.astype(syncs.dtype)
+            elif protocol == "Synchronous":
+                def do_sync(f, s, r):
                     g, r = reduced(f, r)
-                    return g, c, s + 1, r
+                    return g, s + 1, r
 
-                flat, center, syncs, ef = jax.lax.cond(
-                    at_cadence, do_sync, keep, flat, center, syncs, ef,
+                flat, syncs, ef = jax.lax.cond(
+                    at_cadence, do_sync, keep, flat, syncs, ef,
                 )
             elif protocol == "EASGD":
                 def do_sync(f, c, s, r):
@@ -529,13 +567,13 @@ class SPMDTrainer:
                     psi = jax.lax.psum(drift2 - threshold**2, "dp")
                     fire = psi >= 0.0
 
-                def do_sync(f, e, c, s, r):
+                def do_sync(f, e, s, r):
                     g, r = reduced(f, r)
-                    return g, g, c, s + 1, r
+                    return g, g, s + 1, r
 
-                flat, est, center, syncs, ef = jax.lax.cond(
+                flat, est, syncs, ef = jax.lax.cond(
                     jnp.logical_and(at_cadence, fire), do_sync, keep,
-                    flat, est, center, syncs, ef,
+                    flat, est, syncs, ef,
                 )
             else:  # Asynchronous / SSP: event-driven progress + PS folds
                 # progress is per-worker: a worker only advances its clock
@@ -611,7 +649,8 @@ class SPMDTrainer:
             if protocol not in ("Asynchronous", "SSP"):
                 clock = clock + has_data.astype(jnp.int32)
 
-            params = self._unflat(flat)
+            if reads_flat:
+                params = self._unflat(flat)
             n = jnp.sum(mask) * accepted
             cum_loss = cum_loss + loss * n
 
@@ -620,7 +659,6 @@ class SPMDTrainer:
                 "preps": [
                     jax.tree_util.tree_map(shard_block, s) for s in new_preps
                 ],
-                "center": shard_block(center),
                 "step": shard_block(step_i),
                 "syncs": shard_block(syncs),
                 "cum_loss": shard_block(cum_loss),
@@ -630,6 +668,8 @@ class SPMDTrainer:
             }
             if keeps_est:
                 new_state["est"] = shard_block(est)
+            if keeps_center:
+                new_state["center"] = shard_block(center)
             if qdq is not None:
                 new_state["ef"] = shard_block(ef)
             counted = () if counters is None else (counters[None, None],)
@@ -947,12 +987,12 @@ class SPMDTrainer:
         """Restore fleet state saved by :meth:`save` (same mesh shape). A
         snapshot whose vector leaves were saved ``[dp, hub, n]`` loads too:
         :func:`stored` brings either form to the stored one. So does one
-        that holds an ``est`` this protocol's state has none of
-        (:func:`drop_unread_est`)."""
+        that holds an ``est`` or a ``center`` this protocol's state has none
+        of (:func:`drop_unread_leaves`)."""
         from omldm_tpu.parallel.ckpt import load_tree, place_tree
 
         host_state = jax.tree_util.tree_map(
-            stored, drop_unread_est(load_tree(directory), self.protocol)
+            stored, drop_unread_leaves(load_tree(directory), self.protocol)
         )
         self.state = place_tree(host_state, self._state_specs, self.mesh)
 

@@ -111,13 +111,14 @@ def _saved_leaf_positions(
     """Where each leaf of the live fleet ``state`` (``tree_leaves`` order)
     sits in the fleet file ``source``, which holds ``n_saved`` leaves and
     names them by that order alone. The file of a pipeline under a protocol
-    that no longer keeps ``est`` (``spmd.EST_PROTOCOLS``) may date from when
-    it did: it then holds exactly one leaf more, and the position ``est``
-    had there is skipped. Any other difference in count is refused:
-    restored by index, every later leaf would land on the wrong one."""
+    that no longer keeps ``est`` or ``center`` (``spmd.READ_UNDER``) may date
+    from when it did: it then holds one leaf more for each, the last the
+    state let go first, and the positions they had there are skipped. Any
+    other difference in count is refused: restored by index, every later
+    leaf would land on the wrong one."""
     import jax
 
-    from omldm_tpu.parallel.spmd import EST_PROTOCOLS
+    from omldm_tpu.parallel.spmd import unread_leaves
 
     def keys(tree):
         return [
@@ -128,9 +129,14 @@ def _saved_leaf_positions(
     live = keys(state)
     if n_saved == len(live):
         return list(range(n_saved))
-    if n_saved == len(live) + 1 and protocol not in EST_PROTOCOLS:
-        was = keys({**state, "est": 0}).index("est")
-        return [i + (i >= was) for i in range(len(live))]
+    unread = unread_leaves(protocol)
+    extra = n_saved - len(live)
+    if 0 < extra <= len(unread):
+        # ``est`` went first: a file with fewer extras than the protocol
+        # leaves unread lacks the earlier ones
+        gone = unread[len(unread) - extra:]
+        then = keys({**state, **{k: 0 for k in gone}})
+        return [i for i, k in enumerate(then) if k not in gone]
     raise ValueError(
         f"{source} holds {n_saved} leaves, the state of a {protocol} pipeline "
         f"{len(live)} ({', '.join(live)}): it is not a snapshot of this "

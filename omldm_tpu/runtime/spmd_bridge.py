@@ -398,11 +398,15 @@ class SPMDBridge:
         b = self.config.batch_size
         group = self.dp * b
         if n == self._stage_cap and not self._paced:
-            xs = np.array(buf_x, copy=True).reshape(
-                self.chain, self.dp, b, self.dim
-            )
-            ys = np.array(buf_y, copy=True).reshape(self.chain, self.dp, b)
-            self.trainer.step_many_dense(xs, ys)
+            with tracing.span("copy_stage"):
+                xs = np.array(buf_x, copy=True).reshape(
+                    self.chain, self.dp, b, self.dim
+                )
+                ys = np.array(buf_y, copy=True).reshape(
+                    self.chain, self.dp, b
+                )
+            with self._fit_span(n, n, tail=False, tokens=n * self.dim):
+                self.trainer.step_many_dense(xs, ys)
             return
         stage_x = buf_x[:n].copy()
         stage_y = buf_y[:n].copy()
@@ -410,12 +414,15 @@ class SPMDBridge:
         while n - done >= group:
             xg = stage_x[done : done + group].reshape(self.dp, b, self.dim)
             yg = stage_y[done : done + group].reshape(self.dp, b)
-            self.trainer.step(
-                xg.astype(np.float32, copy=False),
-                yg.astype(np.float32, copy=False),
-                np.ones((self.dp, b), np.float32),
-                valid_count=group,
-            )
+            with self._fit_span(
+                group, group, tail=False, tokens=group * self.dim
+            ):
+                self.trainer.step(
+                    xg.astype(np.float32, copy=False),
+                    yg.astype(np.float32, copy=False),
+                    np.ones((self.dp, b), np.float32),
+                    valid_count=group,
+                )
             self._requeue_refused(xg, yg, None)
             done += group
         tail_b = min(b, TAIL_BATCH)
@@ -443,9 +450,26 @@ class SPMDBridge:
                 inv = np.empty_like(order)
                 inv[order] = np.arange(self.dp)
                 xg, yg, mg = xg[inv], yg[inv], mg[inv]
-            self.trainer.step(xg, yg, mg, valid_count=rem)
+            with self._fit_span(
+                rem, tail_group, tail=True, tokens=rem * self.dim
+            ):
+                self.trainer.step(xg, yg, mg, valid_count=rem)
             self._requeue_refused(xg, yg, mg)
             done += rem
+
+    def _fit_span(self, rows: int, rows_padded: int, tail: bool, **counts):
+        """The ``fit`` span of one program dispatch, keyed by the trainer's
+        step ordinal before it (the k-th ``fit`` is the k-th execution of
+        the step program on the device; a chained launch is one program of
+        ``chain`` steps). ``rows_padded`` is the batch the program runs
+        over, padding included; the dense route adds ``tokens``, the
+        feature values of the rows it fits (a language model's tokens)."""
+        span = tracing.span(
+            "fit", key=self.trainer._steps_host, rows=rows,
+            rows_padded=rows_padded, **counts,
+        )
+        span.set(tail=tail)
+        return span
 
     def _requeue_refused(self, xg, yg, mg) -> None:
         """SSP pacing: re-stage the rows of workers whose batch the device
@@ -628,8 +652,12 @@ class SPMDBridge:
         dispatch thread."""
         off = 0
         while off < stop:
-            with self._c_driver() as fs:
-                rc, consumed, soff, slen = fs.parse_stage(buf, off, stop)
+            # the C loop parses a line into its stage slot: one span
+            before = self.holdout_count
+            with tracing.span("parse_stage") as span:
+                with self._c_driver() as fs:
+                    rc, consumed, soff, slen = fs.parse_stage(buf, off, stop)
+                span.add(rows=self.holdout_count - before)
             base = off
             off += consumed
             if rc == fs.RC_DONE:
@@ -1006,18 +1034,6 @@ class SparseSPMDBridge(SPMDBridge):
                     self.trainer.step((ig, vg), yg, mg, valid_count=rem)
                 self._requeue_refused_sparse(ig, vg, yg, mg)
                 done += rem
-
-    def _fit_span(self, rows: int, rows_padded: int, tail: bool):
-        """The ``fit`` span of one step program's dispatch, keyed by the
-        trainer's step ordinal (the k-th ``fit`` is the k-th execution of
-        the step program on the device). ``rows_padded`` is the batch the
-        program runs over, padding included."""
-        span = tracing.span(
-            "fit", key=self.trainer._steps_host, rows=rows,
-            rows_padded=rows_padded,
-        )
-        span.set(tail=tail)
-        return span
 
     def _requeue_refused_sparse(self, ig, vg, yg, mg) -> None:
         if not self._paced:

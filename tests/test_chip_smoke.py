@@ -30,7 +30,8 @@ def test_stream_sparse_tiny(tmp_path):
         test_set=16,
     )
     assert out["model_width"] == 13 + (1 << 13)
-    assert out["wide_passes"] == 0 and out["vector_leaves_aliased"] == 2
+    # the weights alone: Synchronous holds no est and no center beside them
+    assert out["wide_passes"] == 0 and out["vector_leaves_aliased"] == 1
 
 
 def test_stream_mixed_tiny(tmp_path):

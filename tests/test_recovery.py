@@ -318,7 +318,9 @@ class TestSPMDBridgeCheckpoint:
             snapshot = pickle.load(f)
         bd = snapshot["bridges"][0]
         assert "est" not in bd["fleet"]
-        bd["fleet"] = {**bd["fleet"], "est": bd["fleet"]["center"] + 3.0}
+        vec = bd["fleet"]["params"]["w"]
+        bd["fleet"] = {**bd["fleet"], "est": vec + 3.0}
+        bd["fleet"].setdefault("center", vec + 5.0)
         with open(path, "wb") as f:
             pickle.dump(snapshot, f)
         restored = mgr.restore(parallelism=parallelism)

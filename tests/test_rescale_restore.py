@@ -387,9 +387,14 @@ class TestFleetFileLeafOrder:
         # in the order the old save walked
         treedef = jax.tree_util.tree_structure(state)
         saved = jax.tree_util.tree_unflatten(treedef, _saved_leaves(d))
-        old = {**saved, "est": saved["center"] * 0.5 + 7.0}
+        vec = jax.tree_util.tree_leaves(saved["params"])[0]
+        old = {**saved, "est": vec * 0.5 + 7.0}
+        if "center" not in saved:  # Synchronous let go of it as well
+            old["center"] = vec * 0.25 + 3.0
         old_leaves = jax.tree_util.tree_leaves(old)
-        assert len(old_leaves) == treedef.num_leaves + 1
+        assert len(old_leaves) == treedef.num_leaves + (
+            2 if protocol == "Synchronous" else 1
+        )
         _rewrite_fleet_file(
             d, {f"leaf_{i}": l for i, l in enumerate(old_leaves)}
         )
@@ -414,7 +419,7 @@ class TestFleetFileLeafOrder:
         ).all()
 
     @pytest.mark.parametrize(
-        "protocol,extra", [("Synchronous", 2), ("Synchronous", -1), ("GM", 1)]
+        "protocol,extra", [("Synchronous", 3), ("Synchronous", -1), ("GM", 2)]
     )
     def test_any_other_leaf_count_is_refused(self, tmp_path, protocol, extra):
         create = _create(protocol)

@@ -21,6 +21,7 @@ SYNC_EVERY = 3
 # the protocols whose step reads ``est`` (drift under GM / FGM, the delta's
 # base under Asynchronous / SSP): the state holds the leaf under these alone
 READ_EST = ("GM", "FGM", "Asynchronous", "SSP")
+READ_CENTER = ("EASGD", "Asynchronous", "SSP")
 
 
 def _trainer(learner, dim, protocol="Synchronous", dp=1, hub=1, batch=16,
@@ -136,8 +137,10 @@ def test_steps_equal_learner_updates_bit_for_bit(case, protocol):
             jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(params)
         ):
             np.testing.assert_array_equal(a, np.asarray(b))
+        # nothing reads a center under either protocol: none is held
+        assert "center" not in tr.state
         if protocol == "Synchronous":
-            # nothing reads an estimate at the last sync: none is held
+            # nor an estimate at the last sync
             assert "est" not in tr.state
         elif i % SYNC_EVERY == 0:
             # the protocol's state after a fired sync: est == w
@@ -169,7 +172,9 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
 
     # vector leaves are stored flat, one block a shard; the others stacked
     assert ("est" in state) == (protocol in READ_EST)
-    assert state["center"].shape == (dp * hub * tr.flat_size,)
+    assert ("center" in state) == (protocol in READ_CENTER)
+    if protocol in READ_CENTER:
+        assert state["center"].shape == (dp * hub * tr.flat_size,)
     if protocol in READ_EST:
         assert state["est"].shape == (dp * hub * tr.flat_size,)
     assert state["params"]["w"].shape == (dp * hub * (dim + 1),)
@@ -239,7 +244,7 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
     old_form = jax.tree_util.tree_map(
         lambda l: stacked(np.asarray(l), dp, hub), state
     )
-    assert old_form["center"].shape == (dp, hub, tr.flat_size)
+    assert old_form["params"]["w"].shape == (dp, hub, dim + 1)
     save_tree(str(tmp_path / "old"), old_form)
     fresh.load(str(tmp_path / "old"))
     for a, b in zip(
@@ -257,7 +262,8 @@ def test_readers_agree_with_the_shards(protocol, dp, hub, tmp_path):
 @pytest.mark.parametrize("protocol", ["Synchronous", "EASGD"])
 def test_snapshot_with_an_unread_est_loads_without_it(protocol, codec, tmp_path):
     """A snapshot from before the state dropped the ``est`` that Synchronous
-    and EASGD never read holds one: it loads into the tree as it is now, and
+    and EASGD never read (and the ``center`` that Synchronous never read)
+    holds them: it loads into the tree as it is now, and
     the next step equals the donor's bit for bit. Only that leaf is let go:
     any other that the live tree lacks, or misses, still fails."""
     dp, hub, dim = 2, 2, 6
@@ -272,8 +278,11 @@ def test_snapshot_with_an_unread_est_loads_without_it(protocol, codec, tmp_path)
         tr.step(x, y, m)
     assert "est" not in tr.state and ("ef" in tr.state) == (codec != "none")
     host = jax.tree_util.tree_map(np.asarray, tr.state)
-    # the old form: est beside the others, a vector leaf like center
+    # the old form: est, and under Synchronous center too, beside the
+    # others, vector leaves like the weights
+    assert ("center" in tr.state) == (protocol == "EASGD")
     old = {**host, "est": host["params"]["w"] * 0.5}
+    old.setdefault("center", host["params"]["w"] * 0.25)
     save_tree(str(tmp_path / "old"), old)
     fresh = build()
     fresh.load(str(tmp_path / "old"))
@@ -296,7 +305,7 @@ def test_snapshot_with_an_unread_est_loads_without_it(protocol, codec, tmp_path)
     assert fresh.sync_count() == tr.sync_count() > 0
     assert fresh.bytes_shipped() == tr.bytes_shipped()
     # a leaf the tree has none of, other than est, is not let go
-    save_tree(str(tmp_path / "odd"), {**host, "drift": host["center"]})
+    save_tree(str(tmp_path / "odd"), {**host, "drift": host["params"]["w"]})
     with pytest.raises(ValueError):
         build().load(str(tmp_path / "odd"))
     # and a protocol that reads est does not load a snapshot without one
@@ -426,3 +435,60 @@ def test_hlo_guard_names_a_relayout_and_spares_the_scatter_and_the_sync():
         if not any(k in l for k in ("reduce.1 =", "broadcast.46", "while.1"))
     )
     assert chip_smoke.hlo_wide_passes(clean, 1000) == []
+
+
+# --- the model-sized leaves the state holds, and where the model is flattened -
+
+
+@pytest.mark.parametrize("protocol", SPMD_PROTOCOLS)
+def test_the_state_holds_est_and_center_only_where_the_step_reads_them(protocol):
+    """``est`` under GM, FGM, Asynchronous, SSP; ``center`` under EASGD,
+    Asynchronous, SSP; Synchronous holds neither: a model-sized leaf that no
+    code reads is a model more on the device."""
+    from omldm_tpu.parallel.spmd import drop_unread_leaves, unread_leaves
+
+    tr = _trainer(LearnerSpec("NN", hyper_parameters={"optimizer": "sgd"}), 6, protocol, dp=2)
+    held = {k for k in ("est", "center") if k in tr.state}
+    assert held == {k for k, ps in (("est", READ_EST), ("center", READ_CENTER)) if protocol in ps}
+    assert set(unread_leaves(protocol)) == {"est", "center"} - held
+    for x, y, m in _dense_batches(SYNC_EVERY, 2, 16, 6):
+        tr.step(x, y, m)
+    assert {k for k in ("est", "center") if k in tr.state} == held
+    # an old snapshot's unread leaves are dropped, and nothing else is
+    old = {**{k: 0 for k in tr.state}, "est": 1, "center": 2, "other": 3}
+    assert set(drop_unread_leaves(old, protocol)) == set(tr.state) | held | {"other"}
+
+
+def _concatenations_of(tr, x, y, m, n_least):
+    """Sizes of the ``concatenate`` results of the compiled step that hold at
+    least ``n_least`` elements."""
+    import re
+
+    text = tr._step.lower(tr.state, x, y, m).compile().as_text()
+    sizes = []
+    for dims in re.findall(r"= f32\[([\d,]*)\][^=]*? concatenate\(", text):
+        size = int(np.prod([int(d) for d in dims.split(",") if d]))
+        if size >= n_least:
+            sizes.append(size)
+    return sizes
+
+
+@pytest.mark.parametrize("protocol,codec,dp,flattens", [
+    ("Synchronous", "none", 1, False),  # nothing reads the flat form
+    ("Synchronous", "int8", 1, True),   # the codec quantizes it
+    ("Synchronous", "none", 2, True),   # the collective averages it
+    ("GM", "none", 1, True),            # the drift is measured on it
+    ("EASGD", "none", 1, True),         # the center pulls on it
+])
+def test_the_model_is_flattened_only_where_something_reads_the_flat_form(
+    protocol, codec, dp, flattens
+):
+    """At ``dp x hub = 1`` under Synchronous with no codec the learner's new
+    parameters are the state's: the compiled step concatenates no model. The
+    rule reads the mesh and the protocol, never the learner."""
+    spec = LearnerSpec("NN", hyper_parameters={"optimizer": "sgd"},
+                       data_structure={"hiddenLayers": [8]})
+    tr = _trainer(spec, 6, protocol, dp=dp, extra={"codec": codec})
+    x, y, m = _dense_batches(1, dp, 16, 6)[0]
+    wide = _concatenations_of(tr, x, y, m, tr.n_params)
+    assert bool(wide) == flattens

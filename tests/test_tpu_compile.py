@@ -38,10 +38,11 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
     """The sparse PA-II update through the index plan at the benchmark's
     width (2^28 + 14 weights, a tail step's 256 x 41 slots, which compiles
     in seconds), in the shape the SPMD step has under Synchronous on one
-    chip: a ``shard_map`` over a 1 x 1 mesh, the state's two vector leaves
-    donated, and a sync branch that returns the trainer's own
-    ``_ps_allreduce`` of the weights (with no ``est`` beside it to copy
-    them into). The vectors are updated in place, and NO computation of the
+    chip: a ``shard_map`` over a 1 x 1 mesh, the state's vector leaf
+    donated (the weights: Synchronous holds no ``est`` and no ``center``
+    beside them), and a sync branch that returns the trainer's own
+    ``_ps_allreduce`` of the weights (the form a mesh of more shards
+    compiles). The vector is updated in place, and NO computation of the
     module, the sync branch and an overflowing launch's plain pair
     included, passes over the model's width but the scatter: no copy, no
     other fusion of N elements."""
@@ -50,7 +51,7 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
     ps = SimpleNamespace(shard_size=n_weights)  # hub = 1: one bucket
 
     def step(state, idx, val, y):
-        w, center = state["w"], state["center"]
+        w = state["w"]
         k, syncs = state["step"][0, 0] + 1, state["syncs"][0, 0]
         idx, val, y = (
             jax.lax.pcast(a[0], "hub", to="varying") for a in (idx, val, y)
@@ -60,18 +61,17 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
         hinge = jnp.maximum(0.0, 1.0 - ys * margins)
         tau = hinge / (jnp.sum(val * val, axis=1) + 5.0)
         w = add(w, tau * ys / batch)
-        w, center, syncs = jax.lax.cond(
+        w, syncs = jax.lax.cond(
             k % 4 == 0,
-            lambda f, c, s: (SPMDTrainer._ps_allreduce(ps, f), c, s + 1),
-            lambda f, c, s: (f, c, s),
-            w, center, syncs,
+            lambda f, s: (SPMDTrainer._ps_allreduce(ps, f), s + 1),
+            lambda f, s: (f, s),
+            w, syncs,
         )
-        state = {"w": w, "center": center, "step": k[None, None],
-                 "syncs": syncs[None, None]}
+        state = {"w": w, "step": k[None, None], "syncs": syncs[None, None]}
         return state, (jnp.mean(hinge)[None, None], counters[None, None])
 
     vec, stack, rows = P(("dp", "hub")), P("dp", "hub"), P("dp")
-    specs = {"w": vec, "center": vec, "step": stack, "syncs": stack}
+    specs = {"w": vec, "step": stack, "syncs": stack}
 
     def shape(dims, dtype, spec):
         return jax.ShapeDtypeStruct(
@@ -86,7 +86,6 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
         donate_argnums=0,
     ).lower(
         {"w": shape((n_weights,), jnp.float32, vec),
-         "center": shape((n_weights,), jnp.float32, vec),
          "step": shape((1, 1), jnp.int32, stack),
          "syncs": shape((1, 1), jnp.int32, stack)},
         shape((1, batch, slots), jnp.int32, rows),
@@ -95,7 +94,108 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
     ).compile()
     text = compiled.as_text()
     assert chip_smoke.hlo_wide_passes(text, n_weights, every_branch=True) == []
-    # the four state leaves, both vectors among them, are donated
-    assert chip_smoke.hlo_aliased_parameters(text) == [0, 1, 2, 3]
-    # two vectors in, the same two out, and nothing model-sized between
+    # the three state leaves, the vector among them, are donated
+    assert chip_smoke.hlo_aliased_parameters(text) == [0, 1, 2]
+    # one vector in, the same out, and nothing model-sized between
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+OLMO_HYBRID_7B_L4 = "perfbench/configs/olmo_hybrid_7b_l4.json"
+LM_CELL = "olmo_hybrid_7b_l4.train_sat"
+
+
+def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
+    """The benchmark's language-model launch (one period of Olmo-Hybrid-7B at
+    its published widths, one row of 8,192 tokens, one SGD step in float32)
+    as the trainer compiles it under Synchronous on one chip: the trainer's
+    own ``step_many_dense`` body over the state tree it builds, donated. The
+    program holds the weights once (arguments) and their gradients (among
+    the temporaries) and no further float32 copy of all parameters: no
+    ``center``, no flat concatenation, no split. A compile, not a chip run."""
+    import json
+
+    from omldm_tpu.api.requests import LearnerSpec, TrainingConfiguration
+    from omldm_tpu.parallel import spmd
+
+    from perfbench import harness
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, OLMO_HYBRID_7B_L4)) as f:
+        config = json.load(f)
+    with open(os.path.join(root, "perfbench", "workloads", LM_CELL + ".json")) as f:
+        dim = int(json.load(f)["traffic"]["tokens_per_row"])
+    learner = harness.load_kind(config).create_request(config, dim, 0)["learner"]
+    mesh = one_chip_mesh
+    # the step picks its attention kernel by the default backend, which is
+    # the CPU here: the compile is for the chip, so is the choice
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    tr = spmd.SPMDTrainer.__new__(spmd.SPMDTrainer)
+    tr.mesh, tr.dp, tr.hub, tr.protocol = mesh, 1, 1, "Synchronous"
+    tr.tc = TrainingConfiguration(protocol="Synchronous")
+    tr.learner = spmd.make_learner(LearnerSpec(
+        learner["name"], hyper_parameters=learner["hyperParameters"],
+        data_structure=learner["dataStructure"],
+    ))
+    tr.preps, tr.sync_every, tr.threshold, tr.alpha = [], 4, 0.5, 0.5
+    tr.staleness, tr._qdq, tr.pad = 3, None, 0
+    step_fn = tr._build_step()
+
+    template = jax.eval_shape(lambda: tr.learner.init(dim, jax.random.PRNGKey(0)))
+    n_params = sum(int(np.prod(l.shape)) for l in jax.tree_util.tree_leaves(template))
+
+    def stored_shape(leaf_shape, dtype):
+        dims = leaf_shape if len(leaf_shape) == 1 else (1, 1) + tuple(leaf_shape)
+        spec = P(("dp", "hub")) if len(dims) == 1 else P("dp", "hub")
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(mesh, spec))
+
+    scalar = lambda dtype: stored_shape((), dtype)
+    state = {
+        "params": jax.tree_util.tree_map(
+            lambda l: stored_shape(l.shape, l.dtype), template),
+        "preps": [],
+        "step": scalar(jnp.int32), "syncs": scalar(jnp.int32),
+        "cum_loss": scalar(jnp.float32), "clock": scalar(jnp.int32),
+        "accepted": scalar(jnp.float32), "fold_rounds": scalar(jnp.int32),
+    }
+    specs = jax.tree_util.tree_map(lambda s: s.sharding.spec, state)
+
+    def launch(state, xs, ys):  # SPMDTrainer.step_many_dense's body
+        def body(st, b):
+            x, y = b
+            return step_fn(st, x, y, y.astype(jnp.float32) * 0.0 + 1.0)
+
+        return jax.lax.scan(body, state, (xs, ys))
+
+    rows = P(None, "dp")
+    compiled = jax.jit(
+        jax.shard_map(launch, mesh=mesh, in_specs=(specs, rows, rows),
+                      out_specs=(specs, (P(None, "dp", "hub"), ()))),
+        donate_argnums=0,
+    ).lower(
+        state,
+        jax.ShapeDtypeStruct((1, 1, 1, dim), jnp.float32, sharding=NamedSharding(mesh, rows)),
+        jax.ShapeDtypeStruct((1, 1, 1), jnp.float32, sharding=NamedSharding(mesh, rows)),
+    ).compile()
+    mem = compiled.memory_analysis()
+    weights = 4 * n_params
+    assert n_params > 900e6
+    assert mem.argument_size_in_bytes < weights + (1 << 20)
+    # the new weights are written over the donated ones
+    assert mem.alias_size_in_bytes >= weights
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total < 16 << 30
+    # (the temporaries are the gradients and one layer's recomputed
+    # activations: one more float32 copy of the model, a ``center`` or the
+    # flat form, would not fit beside them)
+    assert mem.temp_size_in_bytes > weights
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the flash kernels are in the program
+    # no operation of the program yields all the parameters in one array
+    import re
+
+    widest = max(
+        int(np.prod([int(d) for d in dims.split(",") if d]))
+        for dims in re.findall(r"= f32\[([\d,]*)\]", text)
+    )
+    assert widest < n_params // 2
