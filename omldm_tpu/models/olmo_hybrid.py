@@ -40,7 +40,7 @@ import jax.numpy as jnp
 
 from omldm_tpu.models.transformer import _lm_nll_fused
 from omldm_tpu.ops.attention import attention
-from omldm_tpu.ops.delta_rule import gated_delta_rule
+from omldm_tpu.ops.delta_rule import RESIDUALS, gated_delta_rule
 
 LINEAR, FULL = "linear_attention", "full_attention"
 LOSS_CHUNK = 1024  # positions a block of logits holds in the fused loss
@@ -247,8 +247,11 @@ def hidden_states(cfg: OlmoHybridConfig, params, tokens):
     ``[B, L, hidden]`` float32. Ids outside the vocabulary are clipped."""
     with jax.named_scope("omldm.lm.embed"):
         x = jnp.take(params["embed"], tokens.astype(jnp.int32), axis=0, mode="clip")
+    # a layer is recomputed in the backward pass but for what the delta rule's
+    # kernels name as theirs to keep (on a TPU; elsewhere nothing has the name)
+    keep = jax.checkpoint_policies.save_only_these_names(RESIDUALS)
     for kind, layer in zip(cfg.layer_types, params["layers"]):
-        x = jax.checkpoint(functools.partial(_layer, cfg, kind))(layer, x)
+        x = jax.checkpoint(functools.partial(_layer, cfg, kind), policy=keep)(layer, x)
     return rms_norm(x, params["norm"], cfg.rms_norm_eps)
 
 

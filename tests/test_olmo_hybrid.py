@@ -14,6 +14,7 @@ model's precision) every matrix product reads operands rounded to 2^-9:
 losses to 2e-3, the update as a whole to 0.1 of its norm."""
 
 import dataclasses
+import functools
 import importlib.util
 import json
 import os
@@ -27,6 +28,7 @@ from omldm_tpu.__main__ import build_job
 from omldm_tpu.api.requests import LearnerSpec
 from omldm_tpu.learners.registry import make_learner
 from omldm_tpu.models import olmo_hybrid
+from omldm_tpu.ops import delta_rule
 from omldm_tpu.utils import tracing
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -90,6 +92,42 @@ def test_loss_and_gradients_equal_the_references(ref, dtype):
             assert np.linalg.norm(d) < 1e-3 * np.linalg.norm(w), jax.tree_util.keystr(path)
     else:
         assert norm(diff) < 0.1 * norm(want)
+
+
+def traced_paths(mark):
+    counts = tracing.RECORDER.summary("delta_rule_path", since=mark)[2]
+    return {k: v for k, v in counts.items() if v}
+
+
+def test_the_delta_rule_kernels_give_the_fallbacks_loss_and_gradients(monkeypatch):
+    """``LM.update`` at the model's precision through either path of the
+    delta rule. On the CPU the dispatcher takes ``jax.numpy``, and says so in
+    the recorder, once a linear layer. Told the backend is a TPU (attention
+    kept off its own kernels, the delta rule's interpreted) it takes the
+    kernels, under the model's own ``jax.checkpoint``: same loss, same
+    update, within what bfloat16 operands allow."""
+    lm = learner("bfloat16")  # learningRate 1: the update is the gradient
+    p0 = lm.init(L, jax.random.PRNGKey(0))
+    x, y = rows(4)
+    args = (p0, jnp.asarray(x, jnp.float32), jnp.asarray(y, jnp.float32), jnp.ones((1,)))
+    mark = tracing.RECORDER.mark()
+    p_jnp, loss_jnp = jax.jit(lm.update)(*args)
+    assert traced_paths(mark) == {"jnp": 3, "chunks": 3 * 3, "heads": 3 * 2}
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(olmo_hybrid, "attention",
+                        functools.partial(olmo_hybrid.attention, use_pallas=False))
+    monkeypatch.setattr(delta_rule, "gated_delta_rule_pallas",
+                        functools.partial(delta_rule.gated_delta_rule_pallas, interpret=True))
+    mark = tracing.RECORDER.mark()
+    p_pallas, loss_pallas = jax.jit(lambda *a: lm.update(*a))(*args)
+    assert traced_paths(mark) == {"pallas": 3, "chunks": 3 * 3, "heads": 3 * 2}
+
+    assert abs(float(loss_pallas) - float(loss_jnp)) / float(loss_jnp) < 2e-3
+    norm = lambda t: np.sqrt(sum(np.sum(np.square(l, dtype=np.float64)) for l in jax.tree_util.tree_leaves(t)))
+    change = jax.tree_util.tree_map(lambda a, b: np.asarray(a) - np.asarray(b), p_jnp, host(p0))
+    apart = jax.tree_util.tree_map(lambda a, b: np.asarray(a) - np.asarray(b), p_pallas, p_jnp)
+    assert norm(apart) < 0.1 * norm(change)
 
 
 def test_masked_rows_contribute_nothing(ref):

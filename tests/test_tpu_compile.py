@@ -191,6 +191,33 @@ def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
     assert mem.temp_size_in_bytes > weights
     text = compiled.as_text()
     assert "tpu_custom_call" in text  # the flash kernels are in the program
+    # so are the delta rule's, for three linear layers: two forward (run
+    # once: the layer's recomputation keeps what they made) and two
+    # backward; no loop over chunks is left there
+    in_delta_rule = [l for l in text.splitlines() if "omldm.lm.delta_rule" in l]
+    assert sum("tpu_custom_call" in l for l in in_delta_rule) == 3 * (2 + 2)
+    assert not any(" while(" in l for l in in_delta_rule)
+    # what the model's checkpoint keeps of them (``RESIDUALS``) stays for the
+    # whole backward pass and grows with batchSize x tokens: 138,480 bytes a
+    # token a linear layer at these widths, 3.4 GB a launch. The temporaries
+    # hold it beside the gradients, and the launch leaves a GiB of the chip
+    # for what lives outside it (0.2 GiB of staged rows and the predict
+    # program on the chip) and the runtime: a longer row or a larger batch
+    # fails HERE, before the chip's allocator refuses it
+    from omldm_tpu.ops import delta_rule
+
+    cfg = tr.learner.cfg
+    h, dk, dv = cfg.linear_num_value_heads, cfg.linear_key_head_dim, cfg.linear_value_head_dim
+    row = lambda *width: jax.ShapeDtypeStruct((1, dim, h) + width, jnp.float32)
+    kept_a_layer = sum(
+        leaf.size * leaf.dtype.itemsize for leaf in jax.tree_util.tree_leaves(jax.eval_shape(
+            lambda *a: delta_rule._pallas_fwd(
+                *a, delta_rule.DEFAULT_CHUNK, cfg.operand_dtype, False)[1],
+            row(dk), row(dk), row(dv), row(), row())))
+    assert kept_a_layer == 138_480 * dim
+    kept = kept_a_layer * sum(kind == "linear_attention" for kind in cfg.layer_types)
+    assert mem.temp_size_in_bytes > weights + kept
+    assert total + (1 << 30) < 16 << 30
     # no operation of the program yields all the parameters in one array
     import re
 
