@@ -38,12 +38,12 @@ from typing import Any, Dict, Mapping, Tuple
 import jax
 import jax.numpy as jnp
 
+from omldm_tpu.models.blocks import LOSS_CHUNK, dense, normal_matrix, rms_norm, swiglu_ffn
 from omldm_tpu.models.transformer import _lm_nll_fused
 from omldm_tpu.ops.attention import attention
 from omldm_tpu.ops.delta_rule import RESIDUALS, gated_delta_rule
 
 LINEAR, FULL = "linear_attention", "full_attention"
-LOSS_CHUNK = 1024  # positions a block of logits holds in the fused loss
 # what a Create request's ``dataStructure`` may say of the model: the keys of
 # a published ``config.json``, and nothing of how the program computes it
 PUBLISHED_KEYS = (
@@ -97,6 +97,9 @@ class OlmoHybridConfig:
         return self.hidden_size // self.num_attention_heads
 
 
+Config = OlmoHybridConfig
+
+
 def init_params(cfg: OlmoHybridConfig, rng: jax.Array) -> Dict[str, Any]:
     """Float32 parameters. Matrices are normal(0, 0.02), norm gains 1, conv
     taps uniform in +-1/sqrt(taps), ``A_log = log(uniform(1, 16))`` and
@@ -110,7 +113,7 @@ def init_params(cfg: OlmoHybridConfig, rng: jax.Array) -> Dict[str, Any]:
     keys = iter(jax.random.split(rng, 16 * len(cfg.layer_types) + 2))
 
     def mat(n_in, n_out):
-        return 0.02 * jax.random.normal(next(keys), (n_in, n_out), f32)
+        return normal_matrix(next(keys), (n_in, n_out))
 
     def conv(width):
         return jax.random.uniform(next(keys), (taps, width), f32, -1.0, 1.0) / (taps ** 0.5)
@@ -145,35 +148,6 @@ def init_params(cfg: OlmoHybridConfig, rng: jax.Array) -> Dict[str, Any]:
 
 
 # --- the pieces --------------------------------------------------------------
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def dense(x, w, dtype):
-    """``x [..., K] @ w [K, N]`` with operands read in ``dtype`` and a
-    float32 result; the backward products read the cotangent in ``dtype``
-    too (jax's own transpose would hand them a float32 operand)."""
-    return jnp.dot(x.astype(dtype), w.astype(dtype), preferred_element_type=jnp.float32)
-
-
-def _dense_fwd(x, w, dtype):
-    x, w = x.astype(dtype), w.astype(dtype)
-    return jnp.dot(x, w, preferred_element_type=jnp.float32), (x, w)
-
-
-def _dense_bwd(dtype, res, g):
-    x, w = res
-    g = g.astype(dtype)
-    dx = jnp.dot(g, w.T, preferred_element_type=jnp.float32)
-    k, n = w.shape
-    dw = jnp.dot(x.reshape(-1, k).T, g.reshape(-1, n), preferred_element_type=jnp.float32)
-    return dx, dw
-
-
-dense.defvjp(_dense_fwd, _dense_bwd)
-
-
-def rms_norm(x, gain, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * gain
 
 
 def l2_norm(x):
@@ -229,17 +203,11 @@ def _linear_mixer(cfg: OlmoHybridConfig, layer, x):
         return dense(o.reshape(b, l, h * dv), layer["wo"], dt)
 
 
-def _ffn(cfg: OlmoHybridConfig, layer, x):
-    dt = jnp.dtype(cfg.operand_dtype)
-    with jax.named_scope("omldm.lm.ffn"):
-        hidden = jax.nn.silu(dense(x, layer["w_gate"], dt)) * dense(x, layer["w_up"], dt)
-        return dense(hidden, layer["w_down"], dt)
-
-
 def _layer(cfg: OlmoHybridConfig, kind: str, layer, x):
     mixer = _full_mixer if kind == FULL else _linear_mixer
     x = x + rms_norm(mixer(cfg, layer, x), layer["mixer_norm"], cfg.rms_norm_eps)
-    return x + rms_norm(_ffn(cfg, layer, x), layer["ffn_norm"], cfg.rms_norm_eps)
+    ffn = swiglu_ffn(layer, x, jnp.dtype(cfg.operand_dtype))
+    return x + rms_norm(ffn, layer["ffn_norm"], cfg.rms_norm_eps)
 
 
 def hidden_states(cfg: OlmoHybridConfig, params, tokens):
@@ -255,7 +223,7 @@ def hidden_states(cfg: OlmoHybridConfig, params, tokens):
     return rms_norm(x, params["norm"], cfg.rms_norm_eps)
 
 
-def nll_sum(cfg: OlmoHybridConfig, params, tokens, targets, mask):
+def objective_sum(cfg: OlmoHybridConfig, params, tokens, targets, mask):
     """Sum over positions of ``mask * -log p(target)``; ``targets`` and
     ``mask`` are ``[B, L]``."""
     x = hidden_states(cfg, params, tokens)

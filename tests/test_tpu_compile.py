@@ -100,35 +100,21 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 
-OLMO_HYBRID_7B_L4 = "perfbench/configs/olmo_hybrid_7b_l4.json"
-LM_CELL = "olmo_hybrid_7b_l4.train_sat"
-
-
-def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
-    """The benchmark's language-model launch (one period of Olmo-Hybrid-7B at
-    its published widths, one row of 8,192 tokens, one SGD step in float32)
-    as the trainer compiles it under Synchronous on one chip: the trainer's
-    own ``step_many_dense`` body over the state tree it builds, donated. The
-    program holds the weights once (arguments) and their gradients (among
-    the temporaries) and no further float32 copy of all parameters: no
-    ``center``, no flat concatenation, no split. A compile, not a chip run."""
-    import json
-
+def compiled_lm_launch(mesh, cell: str):
+    """A language-model cell's launch (its configuration's Create request at
+    the cell's ``tokens_per_row``, one row, one SGD step in float32) as the
+    trainer compiles it under Synchronous on one chip: the trainer's own
+    ``step_many_dense`` body over the state tree it builds, donated.
+    ``(compiled, trainer, tokens_per_row, n_params)``."""
     from omldm_tpu.api.requests import LearnerSpec, TrainingConfiguration
     from omldm_tpu.parallel import spmd
 
     from perfbench import harness
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    with open(os.path.join(root, OLMO_HYBRID_7B_L4)) as f:
-        config = json.load(f)
-    with open(os.path.join(root, "perfbench", "workloads", LM_CELL + ".json")) as f:
-        dim = int(json.load(f)["traffic"]["tokens_per_row"])
-    learner = harness.load_kind(config).create_request(config, dim, 0)["learner"]
-    mesh = one_chip_mesh
-    # the step picks its attention kernel by the default backend, which is
-    # the CPU here: the compile is for the chip, so is the choice
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    spec = harness.load_cell(cell, root)
+    config, dim = spec["config"], int(spec["cell"]["traffic"]["tokens_per_row"])
+    learner = spec["kind"].create_request(config, dim, 0)["learner"]
 
     tr = spmd.SPMDTrainer.__new__(spmd.SPMDTrainer)
     tr.mesh, tr.dp, tr.hub, tr.protocol = mesh, 1, 1, "Synchronous"
@@ -177,6 +163,20 @@ def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
         jax.ShapeDtypeStruct((1, 1, 1, dim), jnp.float32, sharding=NamedSharding(mesh, rows)),
         jax.ShapeDtypeStruct((1, 1, 1), jnp.float32, sharding=NamedSharding(mesh, rows)),
     ).compile()
+    return compiled, tr, dim, n_params
+
+
+def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
+    """The benchmark's language-model launch (one period of Olmo-Hybrid-7B at
+    its published widths, one row of 8,192 tokens, one SGD step in float32)
+    as the trainer compiles it (:func:`compiled_lm_launch`). The
+    program holds the weights once (arguments) and their gradients (among
+    the temporaries) and no further float32 copy of all parameters: no
+    ``center``, no flat concatenation, no split. A compile, not a chip run."""
+    # the step picks its attention kernel by the default backend, which is
+    # the CPU here: the compile is for the chip, so is the choice
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, tr, dim, n_params = compiled_lm_launch(one_chip_mesh, "olmo_hybrid_7b_l4.train_sat")
     mem = compiled.memory_analysis()
     weights = 4 * n_params
     assert n_params > 900e6
@@ -226,3 +226,49 @@ def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
         for dims in re.findall(r"= f32\[([\d,]*)\]", text)
     )
     assert widest < n_params // 2
+
+
+def test_looped_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
+    """The looped decoder's launch (12 layers of Ouro-2.6B at the published
+    widths applied 4 times, one row of 8,192 tokens, one SGD step in float32)
+    as the trainer compiles it. The arguments are the weights, 3.27 GB; the
+    temporaries hold ONE float32 gradient a weight, the input of each of the
+    48 layer applications, and what is left stays under 4.5 GB, so the launch
+    leaves a GiB of the chip. A compile, not a chip run."""
+    import re
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, tr, dim, n_params = compiled_lm_launch(one_chip_mesh, "ouro_2_6b_l12.train_sat")
+    cfg = tr.learner.cfg
+    assert n_params == 817_991_681
+    mem = compiled.memory_analysis()
+    weights = 4 * n_params
+    assert mem.argument_size_in_bytes < weights + (1 << 20)
+    assert mem.alias_size_in_bytes >= weights  # the new weights are written over the donated ones
+    total = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert total + (1 << 30) < 16 << 30
+    kept = 4 * cfg.total_ut_steps * cfg.num_hidden_layers * dim * cfg.hidden_size
+    assert weights + kept < mem.temp_size_in_bytes < weights + kept + 4.5e9
+    text = compiled.as_text()
+    # no loop carries a second float32 copy of a stacked weight: beside the
+    # parameters themselves (arguments, read in bfloat16 inside the loops)
+    # there is one accumulator for each leaf of a shape, where two scans left
+    # to ``jax.grad`` would carry the accumulator and a loop step's own
+    # stacked gradient
+    n, d, f = cfg.num_hidden_layers, cfg.hidden_size, cfg.intermediate_size
+    leaves_of = {f"f32[{n},{d},{f}]": 2, f"f32[{n},{f},{d}]": 1, f"f32[{n},{d},{d}]": 4}
+    loops = [l for l in text.splitlines() if " while(" in l]
+    assert len(loops) >= 4  # forward and backward, over loop steps and over layers
+    carried = 0
+    for line in loops:
+        for shape, leaves in leaves_of.items():
+            count = len(re.findall(re.escape(shape) + r"\{", line.split(" while(")[0]))
+            assert count <= leaves, (shape, count)
+            carried += count
+    assert carried >= 2 * 7  # both backward loops carry the accumulators
+    # every application's input is kept in one array, written where it is made
+    assert f"f32[{cfg.total_ut_steps * n},1,{dim},{d}]" in text
+    # the flash kernels and the model's scopes are in the program
+    scopes = set(re.findall(r"omldm\.lm\.([a-z_]+)", text))
+    assert {"embed", "attn_proj", "rope", "flash_attn", "ffn", "head_loss", "exit_gate", "sgd"} <= scopes
+    assert any("tpu_custom_call" in l and "omldm.lm.flash_attn" in l for l in text.splitlines())
