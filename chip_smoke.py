@@ -696,7 +696,7 @@ def _kernel_lm_steps(n_chips: int, *, vocab: int, d_model: int, n_heads: int,
 def _kernel_numerics(*, seq_len: int, long_len: int, heads: int, dh: int,
                      pa_dim: int, pa_batch: int) -> dict:
     """Flash forward (plain, causal, chunked-query offsets, long context),
-    the ``attention`` entry, the two backward kernels against
+    the ``attention`` entry, the backward kernel against
     ``mha_reference`` autodiff, and the PA scan against the exact numpy
     recurrence. On a TPU the kernels are compiled; elsewhere interpreted."""
     import jax

@@ -30,15 +30,37 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from omldm_tpu.utils import tracing
+
 NEG_INF = -1e30
 
-# Default Pallas tile sizes; forward and backward use different shapes
-# (chosen on a v5e at bf16, causal L=8192, dh=64, under an earlier jax; not
-# re-tuned since). Override per call via block_q/block_k.
-DEFAULT_BLOCK_Q = 1024
-DEFAULT_BLOCK_K = 1024
-DEFAULT_BWD_BLOCK_Q = 512
-DEFAULT_BWD_BLOCK_K = 1024
+# Pallas tile sizes, read on a TPU v5e at bf16, causal, L=8192, head width
+# 128, 16 heads, under jax 0.9 (PR 37; ms of kernel an application, the
+# parent's 1,024 x 1,024 forward 2.568 and its two backward kernels 7.137):
+#   forward, K tile in one piece: 1024x1024 2.339, 1024x512 2.295, 512x512
+#     2.433, 2048x1024 2.525, 512x1024 2.538, 256x1024 3.058; in sub-blocks
+#     (see _flash_kernel): 1024x1024 by 512 2.092, by 256 2.137, by 128 2.990,
+#     2048x1024 by 256 2.096, 1024x2048 by 256 2.161, 2048x2048 by 256 2.006
+#     (7.6 s to compile against 1.6);
+#   one-pass backward: 1024x1024 4.333, 512x1024 4.544, 1024x512 4.544,
+#     512x512 4.844, 2048x1024 4.637, 1024x2048 4.662, 256x1024 5.064.
+# What is computed above the diagonal follows the LARGER tile side (12.5% of
+# the triangle at 1,024) but a grid step costs 0.35 us whether skipped or
+# not, and below 512 the sweeps' fixed costs win. The two-kernel backward
+# keeps the tiles it was given at width 64 under an earlier jax.
+FWD_BLOCK_Q = 1024
+FWD_BLOCK_K = 1024
+BWD_BLOCK_Q = 1024
+BWD_BLOCK_K = 1024
+TWO_PASS_BLOCK_Q = 512
+TWO_PASS_BLOCK_K = 1024
+# The backward is ONE kernel where what it keeps resident for a head's dQ
+# (_one_pass_fits) is within this much VMEM, the dq and dk/dv kernels past
+# it: 8 MiB is a bf16 row of 8,192 positions at head width 128, the longest
+# measured. The kernel's scoped VMEM (default 16 MiB) is raised for the
+# float32 rows that fit
+ONE_PASS_DQ_BYTES = 8 << 20
+ONE_PASS_VMEM_LIMIT = 32 << 20
 
 
 def mha_reference(
@@ -154,21 +176,25 @@ def blockwise_attention(
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU flash-attention kernel
+# Pallas TPU flash-attention kernels
 # ---------------------------------------------------------------------------
 
 
-def _masked_scores(q_ref, k_ref, qi, ki, *, causal, q_offset, kv_offset, lk):
+def _masked_scores(q_ref, k_ref, qi, ki, *, causal, q_offset, kv_offset, lk,
+                   k_padded, k_major=False):
     """Scaled QK^T for one (Q tile, K tile) pair with the K-padding and
-    causal masks applied — the ONE implementation all three kernels
-    (forward, dq, dk/dv) share so their masking can never diverge.
+    causal masks applied — the ONE implementation every kernel (forward,
+    one-pass backward, dq, dk/dv) shares so their masking can never diverge.
+    ``[block_q, block_k]``, or ``K Q^T`` as ``[block_k, block_q]`` with
+    ``k_major``. ``ki`` counts K tiles of ``k_ref``'s own length.
 
-    The K-padding mask is STATICALLY skipped when Lk divides the tile
-    evenly (no padded keys exist) — measured worthwhile. Runtime-
-    conditional masking (lax.cond on a per-block scalar) was tried for the
-    causal mask and REGRESSED ~40% on v5e: Mosaic serializes around the
-    branch, costing more than the elementwise mask it saves. So the causal
-    mask stays unconditional."""
+    The K-padding mask is STATICALLY skipped when ``k_padded`` is false (Lk
+    divides the K tile evenly: no padded keys exist). The causal mask stays
+    unconditional. Masking only the pairs the diagonal crosses (two
+    ``pl.when`` bodies, interior and diagonal) was read at head width 128,
+    L=8192 on a v5e (PR 37): the forward rose from 2.339 to 2.396 ms, the
+    one-pass backward fell from 4.333 to 4.300 ms; not kept. (A ``lax.cond``
+    on a per-block scalar had cost 40% at width 64 under an earlier jax.)"""
     block_q, dh = q_ref.shape
     block_k = k_ref.shape[0]
     # operands keep their storage dtype: bf16 x bf16 -> f32 runs the MXU at
@@ -177,32 +203,50 @@ def _masked_scores(q_ref, k_ref, qi, ki, *, causal, q_offset, kv_offset, lk):
     q = q_ref[...]
     k = k_ref[...]
     scale = 1.0 / jnp.sqrt(float(dh))
+    lhs, rhs = (k, q) if k_major else (q, k)
     s = jax.lax.dot_general(
-        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        lhs, rhs, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     ) * scale
 
-    need_pad_mask = lk % block_k != 0  # static: no padded keys otherwise
-    if need_pad_mask or causal:
+    if k_padded or causal:
+        q_axis, k_axis = (1, 0) if k_major else (0, 1)
         ki_local = ki * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
+            jnp.int32, s.shape, k_axis
         )
-        if need_pad_mask:
+        if k_padded:
             s = jnp.where(ki_local < lk, s, NEG_INF)
         if causal:
             q_pos = (
                 q_offset + qi * block_q
-                + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
+                + jax.lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
             )
             s = jnp.where(q_pos >= kv_offset + ki_local, s, NEG_INF)
     return s, scale
 
 
-def _causal_block_needed(qi, ki, block_q, block_k, q_offset, kv_offset):
-    """A (Q tile, K tile) pair is skippable iff it lies entirely above the
-    causal diagonal."""
+def _block_needed(qi, ki, block_q, block_k, *, causal, q_offset, kv_offset,
+                  **_):
+    """Whether a (Q tile, K tile) pair holds a score that is not masked:
+    every pair without a causal mask, with one all but those entirely above
+    the diagonal (the last query row of the Q tile attends to nothing in
+    them), which the kernels skip."""
+    if not causal:
+        return qi >= 0  # always
     return (q_offset + qi * block_q + block_q - 1) >= (
         kv_offset + ki * block_k
     )
+
+
+def _needs_masked_row_guard(causal, q_offset, kv_offset):
+    """Whether a query row can have every key so far masked, so that
+    ``exp(s - m)`` of its masked scores would read ``exp(0)`` and the
+    kernels must zero them by hand (a compare and a select a score). Not
+    with ``q_offset >= kv_offset``, and never without a causal mask: key 0
+    is visible to every row in the first block, ``m`` and ``lse`` are finite
+    from there on, and ``exp(-1e30 - m)`` is exactly 0 in float32. Read at
+    head width 128 (PR 37): 8.9% of the forward, 7.3% of the one-pass
+    backward."""
+    return causal and q_offset < kv_offset
 
 
 def _causal_kv_index(block_q, block_k, q_offset, kv_offset):
@@ -220,15 +264,18 @@ def _causal_kv_index(block_q, block_k, q_offset, kv_offset):
     return index_map
 
 
-def _causal_q_index(block_q, block_k, q_offset, kv_offset, n_q):
-    """Q-side BlockSpec index_map for the dK/dV grid (B*H, k tile a, q step
-    b_): clamp to the FIRST needed Q tile for this K tile (the skipped
-    steps sit at the sweep's start), same DMA-elision trick as above."""
+def _causal_q_index(block_q, block_k, q_offset, kv_offset, n_q, rows=True):
+    """Q-side BlockSpec index_map for the grids that sweep Q inside a K tile
+    (B*H, k tile a, q step b_): clamp to the FIRST needed Q tile for this K
+    tile (the skipped steps sit at the sweep's start), same DMA-elision
+    trick as above. ``rows=False``: the tile index is the block's LAST axis
+    (a ``[1, Lq]`` row of per-query scalars)."""
 
     def index_map(i, a, b_):
         first = (kv_offset + a * block_k - q_offset) // block_q
         first = jnp.minimum(jnp.maximum(first, 0), n_q - 1)
-        return (i, jnp.maximum(b_, first), 0)
+        tile = jnp.maximum(b_, first)
+        return (i, tile, 0) if rows else (i, 0, tile)
 
     return index_map
 
@@ -244,27 +291,36 @@ def _vma_struct_factory(ref_array):
     return _struct
 
 
-def _tpu_compiler_kwargs(interpret: bool) -> dict:
-    """dimension_semantics for the canonical (parallel, parallel, arbitrary)
-    flash grids; the interpreter takes no compiler parameters."""
+def _tpu_compiler_kwargs(interpret: bool, tile_axis: str = "parallel",
+                         vmem_limit_bytes: Optional[int] = None) -> dict:
+    """dimension_semantics for the flash grids: B*H ``parallel``, the tile
+    axis ``parallel`` unless the kernel carries an accumulator across it
+    too, the innermost sweep ``arbitrary``; the interpreter takes no
+    compiler parameters."""
     if interpret:
         return {}
     return {
         "compiler_params": pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+            dimension_semantics=("parallel", tile_axis, "arbitrary"),
+            vmem_limit_bytes=vmem_limit_bytes,
         )
     }
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
-                  *, causal: bool, q_offset: int, kv_offset: int, lk: int,
-                  n_k: int):
+                  *, n_k: int, sub_k: int, guard: bool, **mask):
     """Grid: (B*H, Lq/block_q, Lk/block_k) with the K axis innermost
     (sequential). Each program sees ONE Q tile and ONE K/V tile; the
     online-softmax accumulators live in VMEM scratch and carry across the
     K sweep, so VMEM holds O(block_q * (dh + block_k)) regardless of Lk —
     the whole-K/V-per-program staging this replaces blew VMEM exactly in
-    the long-context regime the module exists for."""
+    the long-context regime the module exists for.
+
+    The K/V tile is taken in sub-blocks of ``sub_k`` keys, one online-softmax
+    update each, unrolled: ``exp(s - m)`` waits for the row maximum of the
+    whole score block, so within ONE block the matrix unit idles through the
+    softmax and the vector unit through the product; with two sub-blocks the
+    next one's ``Q K^T`` has nothing to wait for."""
     block_q, dh = q_ref.shape
     block_k = k_ref.shape[0]
     qi = pl.program_id(1)
@@ -276,18 +332,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    if causal:
-        # skip K blocks entirely above the causal diagonal: the last query
-        # row of this Q tile attends to nothing in them
-        needed = _causal_block_needed(qi, ki, block_q, block_k,
-                                      q_offset, kv_offset)
-    else:
-        needed = ki >= 0  # always
+    needed = _block_needed(qi, ki, block_q, block_k, **mask)
 
-    @pl.when(needed)
-    def _block():
-        s, _ = _masked_scores(q_ref, k_ref, qi, ki, causal=causal,
-                              q_offset=q_offset, kv_offset=kv_offset, lk=lk)
+    def _sub_block(j):
+        keys = pl.ds(j * sub_k, sub_k)
+        s, _ = _masked_scores(
+            q_ref, k_ref.at[keys, :], qi, ki * (block_k // sub_k) + j, **mask)
         # m/l scratch is LANES wide with every lane identical: subtracting
         # a [bq, 1] vector from the [bq, bk] scores broadcasts from lane 0,
         # which the VPU does poorly — pltpu.repeat of a full vreg is cheap
@@ -297,14 +347,15 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         lanes = m_prev.shape[-1]
         m_curr = jnp.max(s, axis=-1, keepdims=True)  # [bq, 1]
         m_new = jnp.maximum(m_prev, m_curr)          # [bq, LANES]
-        if block_k % lanes == 0 and block_k > lanes:
-            m_rep = pltpu.repeat(m_new, block_k // lanes, axis=1)
-        elif block_k <= lanes:
-            m_rep = m_new[:, :block_k]
-        else:  # ragged block_k (< full tiles): lane-0 broadcast fallback
+        if sub_k % lanes == 0 and sub_k > lanes:
+            m_rep = pltpu.repeat(m_new, sub_k // lanes, axis=1)
+        elif sub_k <= lanes:
+            m_rep = m_new[:, :sub_k]
+        else:  # ragged sub_k (< full tiles): lane-0 broadcast fallback
             m_rep = jnp.broadcast_to(m_new[:, :1], s.shape)
-        # same fully-masked-row guard as the blockwise/ring variants
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - m_rep))
+        p = jnp.exp(s - m_rep)
+        if guard:  # the fully-masked-row guard of the blockwise/ring variants
+            p = jnp.where(s <= NEG_INF / 2, 0.0, p)
         alpha = jnp.exp(jnp.minimum(m_prev - m_new, 0.0))  # [bq, LANES]
         l_ref[...] = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         m_ref[...] = m_new
@@ -317,9 +368,14 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, acc_ref, m_ref, l_ref,
         # P quantizes to the value dtype for the PV matmul (bf16 MXU rate;
         # identity for f32 inputs) — the accumulator stays f32
         acc_ref[...] = acc_ref[...] * alpha_dh + jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[...], (((1,), (0,)), ((), ())),
+            p.astype(v_ref.dtype), v_ref[keys, :], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    @pl.when(needed)
+    def _block():
+        for j in range(block_k // sub_k):
+            _sub_block(j)
 
     @pl.when(ki == n_k - 1)
     def _finish():
@@ -361,13 +417,16 @@ def flash_attention_pallas(
     The grid is (B*H, ceil(Lq/block_q), ceil(Lk/block_k)) with the K axis
     sequential: VMEM holds one Q tile, one K/V tile and the online-softmax
     accumulators — O(block_q * (dh + block_k)) regardless of context
-    length. Causal runs skip K tiles above the diagonal. 512/512 tiles
-    measured fastest on TPU v5e (11 TFLOP/s causal at L=8192, 48x the
-    lax blockwise scan). Use ``interpret=True`` on CPU."""
+    length. Causal runs skip K tiles above the diagonal. Tiles of 1,024 x
+    1,024 taken in two sub-blocks of 512 keys read fastest at head width 128
+    on a TPU v5e (the table at the module's tile sizes). Use
+    ``interpret=True`` on CPU."""
     b, lq, h, dh = q.shape
     lk = k.shape[1]
-    block_q = min(block_q or DEFAULT_BLOCK_Q, lq)
-    block_k = min(block_k or DEFAULT_BLOCK_K, lk)
+    block_q = min(block_q or FWD_BLOCK_Q, lq)
+    block_k = min(block_k or FWD_BLOCK_K, lk)
+    # half a K tile where that is whole lanes, else the tile in one piece
+    sub_k = block_k // 2 if block_k % 256 == 0 else block_k
     pad_q = (-lq) % block_q
     pad_k = (-lk) % block_k
 
@@ -409,11 +468,14 @@ def flash_attention_pallas(
     out = pl.pallas_call(
         functools.partial(
             _flash_kernel,
+            n_k=n_k,
+            sub_k=sub_k,
+            guard=_needs_masked_row_guard(causal, q_offset, kv_offset),
             causal=causal,
             q_offset=q_offset,
             kv_offset=kv_offset,
             lk=lk,
-            n_k=n_k,
+            k_padded=pad_k != 0,
         ),
         grid=grid,
         in_specs=[
@@ -437,12 +499,92 @@ def flash_attention_pallas(
     return out
 
 
+def _bwd_block(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki, *,
+               guard, k_major=False, **mask):
+    """``P``, ``dS`` and the scale of one (Q tile, K tile) pair, recomputed
+    from the saved per-row logsumexp (flash backward never materializes P):
+    float32 ``[block_q, block_k]`` with ``lse_ref`` and ``delta_ref``
+    ``[block_q, 1]`` columns, or, ``k_major``, ``[block_k, block_q]`` with
+    ``[1, block_q]`` rows."""
+    s, scale = _masked_scores(q_ref, k_ref, qi, ki, k_major=k_major, **mask)
+    p = jnp.exp(s - lse_ref[...])
+    if guard:
+        p = jnp.where(s <= NEG_INF / 2, 0.0, p)
+    # storage-dtype operands, f32 accumulators (see _masked_scores)
+    lhs, rhs = (v_ref, do_ref) if k_major else (do_ref, v_ref)
+    dp = jax.lax.dot_general(
+        lhs[...], rhs[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    return p, p * (dp - delta_ref[...]), scale
+
+
+def _contract(a, b, a_axis):
+    """``a^T b`` (``a_axis`` 0) or ``a b`` (``a_axis`` 1) with ``a`` cast to
+    ``b``'s storage dtype, float32 out."""
+    return jax.lax.dot_general(
+        a.astype(b.dtype), b, (((a_axis,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc, *,
+                      n_q, n_k, guard, **mask):
+    """The backward pass in ONE kernel: grid (B*H, Lk/bk, Lq/bq), both tile
+    axes sequential, Q innermost. ``S``, ``P``, ``dP`` and ``dS`` of a pair
+    are made once and all three gradients take from them: ``dK`` / ``dV`` of
+    the K tile accumulate over the Q sweep in VMEM scratch (written at its
+    end), ``dQ`` of the head's WHOLE length accumulates in a float32 VMEM
+    scratch over the K tiles, and the head's ``dq`` block stays resident
+    (its index depends on the head alone) until the head's last step
+    writes it: no partial ``dQ`` goes to HBM.
+
+    The scores are made key-major (``S^T = K Q^T``, ``[block_k, block_q]``):
+    ``lse`` and ``delta`` are then ``[1, block_q]`` rows that broadcast down
+    the sublanes, ``dV += P^T dO`` and ``dK += dS^T Q`` are plain products
+    and only ``dQ`` contracts a transposed block."""
+    block_q, dh = q_ref.shape
+    block_k = k_ref.shape[0]
+    ki = pl.program_id(1)
+    qi = pl.program_id(2)
+
+    @pl.when(jnp.logical_and(ki == 0, qi == 0))
+    def _init_head():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    @pl.when(qi == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    needed = _block_needed(qi, ki, block_q, block_k, **mask)
+
+    @pl.when(needed)
+    def _block():
+        pt, dst, scale = _bwd_block(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+            guard=guard, k_major=True, **mask)
+        dv_acc[...] = dv_acc[...] + _contract(pt, do_ref[...], 1)
+        dk_acc[...] = dk_acc[...] + _contract(dst, q_ref[...], 1) * scale
+        rows = pl.ds(pl.multiple_of(qi * block_q, block_q), block_q)
+        dq_acc[rows, :] = dq_acc[rows, :] + _contract(
+            dst, k_ref[...], 0) * scale
+
+    @pl.when(qi == n_q - 1)
+    def _finish():
+        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(jnp.logical_and(ki == n_k - 1, qi == n_q - 1))
+    def _finish_head():
+        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
+
+
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                         dq_ref, acc_ref, *, causal, q_offset, kv_offset,
-                         lk, n_k):
-    """dQ pass: grid (B*H, Lq/bq, Lk/bk), K sequential. Recomputes each
-    score block from the saved per-row logsumexp (flash backward never
-    materializes P) and accumulates dQ in VMEM scratch."""
+                         dq_ref, acc_ref, *, n_k, guard, **mask):
+    """dQ pass of the two-kernel backward: grid (B*H, Lq/bq, Lk/bk), K
+    sequential; accumulates dQ in VMEM scratch."""
     block_q, dh = q_ref.shape
     block_k = k_ref.shape[0]
     qi = pl.program_id(1)
@@ -452,28 +594,14 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    if causal:
-        needed = _causal_block_needed(qi, ki, block_q, block_k,
-                                      q_offset, kv_offset)
-    else:
-        needed = ki >= 0
+    needed = _block_needed(qi, ki, block_q, block_k, **mask)
 
     @pl.when(needed)
     def _block():
-        s, scale = _masked_scores(q_ref, k_ref, qi, ki, causal=causal,
-                                  q_offset=q_offset, kv_offset=kv_offset,
-                                  lk=lk)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse_ref[...]))
-        # storage-dtype operands, f32 accumulators (see _masked_scores)
-        dp = jax.lax.dot_general(
-            do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[...])
-        acc_ref[...] = acc_ref[...] + jax.lax.dot_general(
-            ds.astype(k_ref.dtype), k_ref[...], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        _, ds, scale = _bwd_block(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+            guard=guard, **mask)
+        acc_ref[...] = acc_ref[...] + _contract(ds, k_ref[...], 1) * scale
 
     @pl.when(ki == n_k - 1)
     def _finish():
@@ -481,10 +609,11 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                           q_offset, kv_offset, lk, n_q):
-    """dK/dV pass: grid (B*H, Lk/bk, Lq/bq), Q sequential. One K/V tile's
-    gradients accumulate across the whole Q sweep in VMEM scratch."""
+                           dk_ref, dv_ref, dk_acc, dv_acc, *, n_q, guard,
+                           **mask):
+    """dK/dV pass of the two-kernel backward: grid (B*H, Lk/bk, Lq/bq), Q
+    sequential. One K/V tile's gradients accumulate across the whole Q sweep
+    in VMEM scratch."""
     block_q, dh = q_ref.shape
     block_k = k_ref.shape[0]
     ki = pl.program_id(1)
@@ -495,34 +624,16 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    if causal:
-        needed = _causal_block_needed(qi, ki, block_q, block_k,
-                                      q_offset, kv_offset)
-    else:
-        needed = qi >= 0
+    needed = _block_needed(qi, ki, block_q, block_k, **mask)
 
     @pl.when(needed)
     def _block():
-        s, scale = _masked_scores(q_ref, k_ref, qi, ki, causal=causal,
-                                  q_offset=q_offset, kv_offset=kv_offset,
-                                  lk=lk)
-        p = jnp.where(s <= NEG_INF / 2, 0.0, jnp.exp(s - lse_ref[...]))
-        # storage-dtype operands, f32 accumulators (see _masked_scores)
-        # dV += P^T dO
-        dv_acc[...] = dv_acc[...] + jax.lax.dot_general(
-            p.astype(do_ref.dtype), do_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = jax.lax.dot_general(
-            do_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (dp - delta_ref[...])
-        # dK += dS^T Q * scale
-        dk_acc[...] = dk_acc[...] + jax.lax.dot_general(
-            ds.astype(q_ref.dtype), q_ref[...], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale
+        p, ds, scale = _bwd_block(
+            q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, qi, ki,
+            guard=guard, **mask)
+        # dV += P^T dO; dK += dS^T Q * scale
+        dv_acc[...] = dv_acc[...] + _contract(p, do_ref[...], 0)
+        dk_acc[...] = dk_acc[...] + _contract(ds, q_ref[...], 0) * scale
 
     @pl.when(qi == n_q - 1)
     def _finish():
@@ -530,13 +641,12 @@ def _flash_bwd_dkdv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
 
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_diff(q, k, v, causal: bool, q_offset: int, kv_offset: int,
                 interpret: bool = False):
     """Differentiable Pallas flash attention: Pallas forward AND backward
-    (dq / dk-dv passes recompute scores from the saved logsumexp)."""
+    (:func:`flash_backward`, which recomputes the scores from the saved
+    logsumexp)."""
     return flash_attention_pallas(
         q, k, v, causal=causal, q_offset=q_offset, kv_offset=kv_offset,
         interpret=interpret,
@@ -552,11 +662,37 @@ def _flash_diff_fwd(q, k, v, causal, q_offset, kv_offset, interpret=False):
 
 
 def _flash_diff_bwd(causal, q_offset, kv_offset, interpret, res, g):
-    q, k, v, out, lse = res
+    return flash_backward(*res, g, causal=causal, q_offset=q_offset,
+                          kv_offset=kv_offset, interpret=interpret)
+
+
+def _one_pass_fits(lq: int, dh: int, dtype) -> bool:
+    """Whether the one-pass backward can hold a head's ``dQ`` in VMEM: the
+    float32 ``[Lq, dh]`` accumulator and the two buffers of the resident
+    ``dq`` block in the storage dtype, ``dh`` as the lanes pad it, against
+    ``ONE_PASS_DQ_BYTES``."""
+    lanes = -(-dh // 128) * 128
+    return lq * lanes * (4 + 2 * jnp.dtype(dtype).itemsize) <= ONE_PASS_DQ_BYTES
+
+
+def flash_backward(q, k, v, out, lse, g, *, causal, q_offset, kv_offset,
+                   interpret=False, block_q=None, block_k=None):
+    """``(dq, dk, dv)`` from the forward's residuals (``lse`` as
+    :func:`flash_attention_pallas` returns it) and the cotangent ``g`` of
+    ``out``. ONE kernel where a head's whole-length ``dQ`` fits VMEM
+    (:func:`_one_pass_fits`), the dq and dk/dv kernels for longer rows: the
+    same sums, staged by a size read from the shapes. Which was traced is
+    counted under ``flash_bwd_path`` in ``tracing.RECORDER``. ``block_q``,
+    ``block_k``: the tests' tiles; callers leave them to the module."""
     b, lq, h, dh = q.shape
     lk = k.shape[1]
-    block_q = min(DEFAULT_BWD_BLOCK_Q, lq)
-    block_k = min(DEFAULT_BWD_BLOCK_K, lk)
+    one_pass = _one_pass_fits(lq, dh, q.dtype)
+    tracing.RECORDER.add_counts(
+        "flash_bwd_path", **{"one_pass" if one_pass else "two_pass": 1})
+    tiles = (BWD_BLOCK_Q, BWD_BLOCK_K) if one_pass else (
+        TWO_PASS_BLOCK_Q, TWO_PASS_BLOCK_K)
+    block_q = min(block_q or tiles[0], lq)
+    block_k = min(block_k or tiles[1], lk)
     pad_q = (-lq) % block_q
     pad_k = (-lk) % block_k
     n_q = (lq + pad_q) // block_q
@@ -584,6 +720,54 @@ def _flash_diff_bwd(causal, q_offset, kv_offset, interpret, res, g):
     # under shard_map's vma typing the kernel outputs must declare which
     # mesh axes they vary over — inherit the cotangent's (same as forward)
     _struct = _vma_struct_factory(dof)
+    where = dict(causal=causal, q_offset=q_offset, kv_offset=kv_offset, lk=lk,
+                 k_padded=pad_k != 0,
+                 guard=_needs_masked_row_guard(causal, q_offset, kv_offset))
+    dq_shape = _struct((b * h, lq + pad_q, dh), q.dtype)
+    dkv_shape = (_struct((b * h, lk + pad_k, dh), k.dtype),
+                 _struct((b * h, lk + pad_k, dh), v.dtype))
+    dkv_scratch = [pltpu.VMEM((block_k, dh), jnp.float32),
+                   pltpu.VMEM((block_k, dh), jnp.float32)]
+
+    # the grids that sweep Q inside a K tile, axes (k tile a, q step b_).
+    # causal: the skipped q steps sit at the sweep's start; clamp their
+    # index to the first needed tile (DMA elision, see _causal_kv_index)
+    def q_map(rows=True):
+        if causal:
+            return _causal_q_index(block_q, block_k, q_offset, kv_offset, n_q,
+                                   rows=rows)
+        return (lambda i, a, b_: (i, b_, 0)) if rows else (
+            lambda i, a, b_: (i, 0, b_))
+
+    q_spec2 = pl.BlockSpec((None, block_q, dh), q_map())
+    kv_spec2 = pl.BlockSpec((None, block_k, dh), lambda i, a, b_: (i, a, 0))
+
+    def unflat(a, l):
+        return a[:, :l].reshape(b, h, l, dh).transpose(0, 2, 1, 3)
+
+    if one_pass:
+        # per-query scalars as [1, Lq] rows: on the lanes, where a
+        # [Lq, 1] column is padded to 128 of them in HBM and in VMEM
+        lse, delta = (a.reshape(b * h, 1, lq + pad_q) for a in (lse, delta))
+        row_spec2 = pl.BlockSpec((None, 1, block_q), q_map(rows=False))
+        dq, dk, dv = pl.pallas_call(
+            functools.partial(_flash_bwd_kernel, n_q=n_q, n_k=n_k, **where),
+            grid=(b * h, n_k, n_q),
+            in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2,
+                      row_spec2],
+            out_specs=(
+                pl.BlockSpec((None, lq + pad_q, dh), lambda i, a, b_: (i, 0, 0)),
+                kv_spec2, kv_spec2,
+            ),
+            out_shape=(dq_shape,) + dkv_shape,
+            scratch_shapes=[pltpu.VMEM((lq + pad_q, dh), jnp.float32)]
+            + dkv_scratch,
+            interpret=interpret,
+            **_tpu_compiler_kwargs(interpret, tile_axis="arbitrary",
+                                   vmem_limit_bytes=ONE_PASS_VMEM_LIMIT),
+        )(qf, kf, vf, dof, lse, delta)
+        return unflat(dq, lq), unflat(dk, lk), unflat(dv, lk)
+
     kwargs = _tpu_compiler_kwargs(interpret)
     q_spec = pl.BlockSpec((None, block_q, dh), lambda i, a, b_: (i, a, 0))
     row_spec = pl.BlockSpec((None, block_q, 1), lambda i, a, b_: (i, a, 0))
@@ -594,54 +778,27 @@ def _flash_diff_bwd(causal, q_offset, kv_offset, interpret, res, g):
         if causal else (lambda i, a, b_: (i, b_, 0))
     )
     kv_spec = pl.BlockSpec((None, block_k, dh), kv_map)
-
     dq = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dq_kernel, causal=causal, q_offset=q_offset,
-            kv_offset=kv_offset, lk=lk, n_k=n_k,
-        ),
+        functools.partial(_flash_bwd_dq_kernel, n_k=n_k, **where),
         grid=(b * h, n_q, n_k),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=q_spec,
-        out_shape=_struct((b * h, lq + pad_q, dh), q.dtype),
+        out_shape=dq_shape,
         scratch_shapes=[pltpu.VMEM((block_q, dh), jnp.float32)],
         interpret=interpret,
         **kwargs,
     )(qf, kf, vf, dof, lse, delta)
-
-    # dK/dV pass: grid axes swap roles — a/b_ are (k tile, q tile). The
-    # causal-skipped q steps sit at the sweep start; clamp their index to
-    # the first needed tile (DMA elision again).
-    q_map = (
-        _causal_q_index(block_q, block_k, q_offset, kv_offset, n_q)
-        if causal else (lambda i, a, b_: (i, b_, 0))
-    )
-    q_spec2 = pl.BlockSpec((None, block_q, dh), q_map)
-    row_spec2 = pl.BlockSpec((None, block_q, 1), q_map)
-    kv_spec2 = pl.BlockSpec((None, block_k, dh), lambda i, a, b_: (i, a, 0))
+    row_spec2 = pl.BlockSpec((None, block_q, 1), q_map())
     dk, dv = pl.pallas_call(
-        functools.partial(
-            _flash_bwd_dkdv_kernel, causal=causal, q_offset=q_offset,
-            kv_offset=kv_offset, lk=lk, n_q=n_q,
-        ),
+        functools.partial(_flash_bwd_dkdv_kernel, n_q=n_q, **where),
         grid=(b * h, n_k, n_q),
         in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, row_spec2, row_spec2],
         out_specs=(kv_spec2, kv_spec2),
-        out_shape=(
-            _struct((b * h, lk + pad_k, dh), k.dtype),
-            _struct((b * h, lk + pad_k, dh), v.dtype),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_k, dh), jnp.float32),
-            pltpu.VMEM((block_k, dh), jnp.float32),
-        ],
+        out_shape=dkv_shape,
+        scratch_shapes=dkv_scratch,
         interpret=interpret,
         **kwargs,
     )(qf, kf, vf, dof, lse, delta)
-
-    def unflat(a, l):
-        return a[:, :l].reshape(b, h, l, dh).transpose(0, 2, 1, 3)
-
     return unflat(dq, lq), unflat(dk, lk), unflat(dv, lk)
 
 
@@ -659,8 +816,9 @@ def attention(
     use_pallas: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Backend-dispatching attention entry point: the Pallas kernel on TPU
-    (differentiable end to end — Pallas forward AND the dq / dk-dv backward
-    kernels recomputing P from the saved logsumexp), blockwise scan
+    (differentiable end to end — Pallas forward AND backward, the latter
+    recomputing P from the saved logsumexp in one kernel, or two for rows
+    past its VMEM budget: :func:`flash_backward`), blockwise scan
     elsewhere."""
     if use_pallas is None:
         use_pallas = jax.default_backend() == "tpu"

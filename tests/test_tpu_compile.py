@@ -105,9 +105,11 @@ def compiled_lm_launch(mesh, cell: str):
     the cell's ``tokens_per_row``, one row, one SGD step in float32) as the
     trainer compiles it under Synchronous on one chip: the trainer's own
     ``step_many_dense`` body over the state tree it builds, donated.
-    ``(compiled, trainer, tokens_per_row, n_params)``."""
+    ``(compiled, trainer, tokens_per_row, n_params)``. Both cells' rows fit
+    the one-pass flash backward, and the trace says so (``flash_bwd_path``)."""
     from omldm_tpu.api.requests import LearnerSpec, TrainingConfiguration
     from omldm_tpu.parallel import spmd
+    from omldm_tpu.utils import tracing
 
     from perfbench import harness
 
@@ -154,6 +156,7 @@ def compiled_lm_launch(mesh, cell: str):
         return jax.lax.scan(body, state, (xs, ys))
 
     rows = P(None, "dp")
+    paths = tracing.RECORDER.counts("flash_bwd_path")
     compiled = jax.jit(
         jax.shard_map(launch, mesh=mesh, in_specs=(specs, rows, rows),
                       out_specs=(specs, (P(None, "dp", "hub"), ()))),
@@ -163,7 +166,22 @@ def compiled_lm_launch(mesh, cell: str):
         jax.ShapeDtypeStruct((1, 1, 1, dim), jnp.float32, sharding=NamedSharding(mesh, rows)),
         jax.ShapeDtypeStruct((1, 1, 1), jnp.float32, sharding=NamedSharding(mesh, rows)),
     ).compile()
+    traced = tracing.RECORDER.counts("flash_bwd_path")
+    assert traced.get("one_pass", 0) > paths.get("one_pass", 0)
+    assert traced.get("two_pass", 0) == paths.get("two_pass", 0)
     return compiled, tr, dim, n_params
+
+
+def flash_attn_calls(text: str):
+    """The Pallas calls under ``omldm.lm.flash_attn`` in a compiled launch's
+    text, ``(forward, backward)``: the forward kernel, run again by a layer's
+    recomputation too, is called through ``jit(flash_attention_pallas)``,
+    the backward's kernels straight from the ``custom_vjp``'s rule. A loop's
+    body is counted once however often it runs."""
+    calls = [l for l in text.splitlines()
+             if "tpu_custom_call" in l and "omldm.lm.flash_attn" in l]
+    forward = sum("jit(flash_attention_pallas)" in l for l in calls)
+    return forward, len(calls) - forward
 
 
 def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
@@ -189,8 +207,15 @@ def test_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeypatch):
     # activations: one more float32 copy of the model, a ``center`` or the
     # flat form, would not fit beside them)
     assert mem.temp_size_in_bytes > weights
+    # not above what the parent of PR 37 compiles to here (two backward
+    # flash kernels and their padded copies)
+    assert mem.temp_size_in_bytes <= 8_801_004_544
     text = compiled.as_text()
-    assert "tpu_custom_call" in text  # the flash kernels are in the program
+    # the flash kernels are in the program, at 30 heads of 128 over 8,192
+    # positions (Mosaic took the one-pass backward's VMEM: the compile is the
+    # check): the full layer's forward, its recomputation, and ONE backward
+    # kernel where PR 36 held two
+    assert flash_attn_calls(text) == (2, 1)
     # so are the delta rule's, for three linear layers: two forward (run
     # once: the layer's recomputation keeps what they made) and two
     # backward; no loop over chunks is left there
@@ -271,4 +296,9 @@ def test_looped_lm_step_at_published_widths_fits_one_chip(one_chip_mesh, monkeyp
     # the flash kernels and the model's scopes are in the program
     scopes = set(re.findall(r"omldm\.lm\.([a-z_]+)", text))
     assert {"embed", "attn_proj", "rope", "flash_attn", "ffn", "head_loss", "exit_gate", "sgd"} <= scopes
-    assert any("tpu_custom_call" in l and "omldm.lm.flash_attn" in l for l in text.splitlines())
+    # the flash kernels at 16 heads of 128: the forward loop's body holds
+    # the forward, the backward loop's body its recomputation and ONE
+    # backward kernel where PR 36 held two
+    assert flash_attn_calls(text) == (2, 1)
+    # not above what the parent of PR 37 compiles to here
+    assert mem.temp_size_in_bytes <= 10_738_496_000
