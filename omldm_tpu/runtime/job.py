@@ -921,65 +921,69 @@ class StreamJob:
         (PipelineMap.scala:54-57) and spoke 0 creates each of the
         hubParallelism hubs (FlinkSpoke.scala:220-222). A request whose
         trainingConfiguration sets {"engine": "spmd"} (and a supported
-        protocol/learner) deploys on the SPMD collective engine instead."""
-        from omldm_tpu.runtime.spmd_bridge import (
-            make_spmd_bridge,
-            spmd_engine_requested,
-            spmd_engine_supported,
-        )
-
-        # lazy telemetry arming: the first pipeline whose
-        # trainingConfiguration carries a telemetry table creates the
-        # job's plane (the gate already validated the spec; job-wide
-        # arming happened at __init__)
-        if self.telemetry is None:
-            from omldm_tpu.runtime.telemetry import telemetry_config
-
-            try:
-                tel_cfg = telemetry_config(
-                    request.training_configuration,
-                    getattr(self.config, "telemetry", ""),
-                )
-            except (ValueError, TypeError):
-                tel_cfg = None  # gate-validated; belt and braces
-            if tel_cfg is not None:
-                self._arm_telemetry(tel_cfg)
-        # ... and lazy flight-recorder arming, same rule (the gate already
-        # validated the table; job-wide arming happened at __init__)
-        if self.events is None:
-            from omldm_tpu.runtime.events import events_config
-
-            try:
-                ev_cfg = events_config(
-                    request.training_configuration,
-                    getattr(self.config, "events", ""),
-                )
-            except (ValueError, TypeError):
-                ev_cfg = None  # gate-validated; belt and braces
-            if ev_cfg is not None:
-                self._arm_events(ev_cfg)
-        use_spmd = spmd_engine_requested(request) and spmd_engine_supported(request)
-        # an Update must tear down the previous deployment on EITHER plane
-        if request.id in self._dims:
-            self.hub_manager.delete_network(request.id)
-            self.spmd_bridges.pop(request.id, None)
-            if use_spmd:
-                # clear stale host-plane nets when switching planes
-                delete = dataclasses.replace(request, request=RequestType.DELETE)
-                for spoke in self.spokes:
-                    spoke.handle_request(delete, 0)
-        self._dims[request.id] = dim
-        if use_spmd:
-            self.spmd_bridges[request.id] = make_spmd_bridge(
-                request, dim, self.config,
-                self._emit_prediction, self._route_response_fragment,
+        protocol/learner) deploys on the SPMD collective engine instead.
+        The ``deploy`` span times the building (the SPMD trainer's
+        ``build_state`` is its descendant) and counts the SPMD trainer's
+        ``params``; the backlog it releases is replayed after it."""
+        with tracing.span("deploy", key=request.id) as span:
+            from omldm_tpu.runtime.spmd_bridge import (
+                make_spmd_bridge,
+                spmd_engine_requested,
+                spmd_engine_supported,
             )
-            self._replay_backlog()
-            return
-        for spoke in self.spokes:
-            spoke.handle_request(request, dim)
-        for h in range(request.training_configuration.hub_parallelism):
-            self.hub_manager.create_hub(request, h, dim)
+
+            # lazy telemetry arming: the first pipeline whose
+            # trainingConfiguration carries a telemetry table creates the
+            # job's plane (the gate already validated the spec; job-wide
+            # arming happened at __init__)
+            if self.telemetry is None:
+                from omldm_tpu.runtime.telemetry import telemetry_config
+
+                try:
+                    tel_cfg = telemetry_config(
+                        request.training_configuration,
+                        getattr(self.config, "telemetry", ""),
+                    )
+                except (ValueError, TypeError):
+                    tel_cfg = None  # gate-validated; belt and braces
+                if tel_cfg is not None:
+                    self._arm_telemetry(tel_cfg)
+            # ... and lazy flight-recorder arming, same rule (the gate already
+            # validated the table; job-wide arming happened at __init__)
+            if self.events is None:
+                from omldm_tpu.runtime.events import events_config
+
+                try:
+                    ev_cfg = events_config(
+                        request.training_configuration,
+                        getattr(self.config, "events", ""),
+                    )
+                except (ValueError, TypeError):
+                    ev_cfg = None  # gate-validated; belt and braces
+                if ev_cfg is not None:
+                    self._arm_events(ev_cfg)
+            use_spmd = spmd_engine_requested(request) and spmd_engine_supported(request)
+            # an Update must tear down the previous deployment on EITHER plane
+            if request.id in self._dims:
+                self.hub_manager.delete_network(request.id)
+                self.spmd_bridges.pop(request.id, None)
+                if use_spmd:
+                    # clear stale host-plane nets when switching planes
+                    delete = dataclasses.replace(request, request=RequestType.DELETE)
+                    for spoke in self.spokes:
+                        spoke.handle_request(delete, 0)
+            self._dims[request.id] = dim
+            if use_spmd:
+                bridge = self.spmd_bridges[request.id] = make_spmd_bridge(
+                    request, dim, self.config,
+                    self._emit_prediction, self._route_response_fragment,
+                )
+                span.add(params=bridge.trainer.n_params)
+            else:
+                for spoke in self.spokes:
+                    spoke.handle_request(request, dim)
+                for h in range(request.training_configuration.hub_parallelism):
+                    self.hub_manager.create_hub(request, h, dim)
         self._replay_backlog()
 
     def rescale(self, n_new: int) -> None:

@@ -665,25 +665,35 @@ class SPMDBridge:
             if rc == fs.RC_STAGE_FULL:
                 self._train_staged()
                 continue
-            self._dispatcher.quiesce()
+            # the spans and their nesting are the sparse route's
             if rc == fs.RC_FALLBACK:
-                line = bytes(buf[base + soff : base + soff + slen]).decode(
-                    "utf-8", errors="replace"
-                )
-                inst = DataInstance.from_json(line)
-                if inst is not None:
-                    self.handle_data(inst)
+                with tracing.span("fallback"):
+                    self._dispatcher.quiesce()
+                    with tracing.span("decode"):
+                        line = bytes(
+                            buf[base + soff : base + soff + slen]
+                        ).decode("utf-8", errors="replace")
+                        inst = DataInstance.from_json(line)
+                    if inst is not None:
+                        self.handle_data(inst)
             elif rc == fs.RC_FORECAST:
-                x, _ = fs.forecast_row()
-                xb = np.zeros((PREDICT_BATCH, self.dim), np.float32)
-                xb[0] = x
-                preds = self.trainer.predict(xb)
-                inst = DataInstance(
-                    numerical_features=x.tolist(), operation=FORECASTING
-                )
-                self._emit_prediction(
-                    Prediction(self.request.id, inst, float(preds[0]))
-                )
+                with tracing.span("forecast"):
+                    self._dispatcher.quiesce()
+                    x, _ = fs.forecast_row()
+                    with tracing.span(
+                        "serve", rows=1, rows_padded=PREDICT_BATCH
+                    ):
+                        xb = np.zeros((PREDICT_BATCH, self.dim), np.float32)
+                        xb[0] = x
+                        preds = self.trainer.predict(xb)
+                    with tracing.span("emit"):
+                        inst = DataInstance(
+                            numerical_features=x.tolist(),
+                            operation=FORECASTING,
+                        )
+                        self._emit_prediction(
+                            Prediction(self.request.id, inst, float(preds[0]))
+                        )
 
     # --- query / termination path ---
 

@@ -73,11 +73,12 @@ PHASES = (
     "split_lines",       # a block with a special line: copied and split
     "launch",            # dispatch thread: one stage set (copy + its fits)
     "copy_stage",        # ... its rows copied off the reused stage set
-    "forecast",          # one forecast inside a file (children below)
+    "forecast",          # one forecast inside a file (children below; both routes)
     "fallback",          # a line left to the Python codec (same children)
     "decode",            # forecast line -> DataInstance -> COO row
     "quiesce",           # wait for the dispatch queue to drain
     "emit",              # the prediction sink
+    "deploy",            # StreamJob._deploy: learner, bridge and trainer built
     "build_state",       # SPMDTrainer state: template, host build, placement
     "init_state_host",   # ... the leaves built on the host
     "place_state",       # ... device_put of every leaf

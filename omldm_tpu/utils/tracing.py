@@ -17,14 +17,20 @@ observability is CountableSerial byte accounting). Four things live here:
   newest records; ``**counts`` add to the name's counters at the same
   boundary (``rows``, ``rows_padded``: the phase table prints them beside
   the seconds); :meth:`Recorder.add_counts` adds what is known only later
-  (a launch's device counters). :data:`RECORDER` is the process-wide recorder ``span``
-  writes to; it is always on (two clock reads, one inactive annotation and
-  one ring slot a span -- measured in ``PERF.md``).
+  (a launch's device counters). :data:`RECORDER` is the process-wide
+  recorder ``span`` writes to (``compile`` keeps :data:`COMPILE_RING_CAP`
+  records, a whole run's); it is always on (two clock reads, one inactive
+  annotation and one ring slot a span -- measured in ``PERF.md``).
   ``runtime.telemetry.PhaseProfile`` is the table over a recorder, so the
   host plane's phases are these spans.
-- a ``jax.monitoring`` listener, registered on import, that notes every
+- two ``jax.monitoring`` listeners, registered on import, that note every
   program jax traces, lowers or compiles as a ``compile`` record under the
-  span that called it, so a measured window that compiled says so.
+  span that called it, so a measured window that compiled says so. The
+  record's ``key`` is the program's name and its ``stage`` attribute the
+  event's; a ``backend_compile_duration`` record also carries the
+  persistent cache's answer, ``cache``: ``"hit"`` (and ``retrieval_s``, the
+  seconds the read took), ``"miss"`` (asked, no entry) or ``"off"`` (never
+  asked: the cache is off, or jax made no key for the program).
 - :func:`trace` -- context manager around ``jax.profiler`` writing a
   TensorBoard-loadable trace directory (op/fusion timeline, HBM usage).
 - :class:`StepTimer` -- host-side wall-clock accounting for the host
@@ -50,6 +56,10 @@ from omldm_tpu.utils import clock
 # bounded per-name sample window (percentiles and records cover the most
 # recent window; counts and totals stay exact)
 RING_CAP = 4096
+# the ``compile`` records of a whole run: the Olmo cell's set-up alone writes
+# about 4,900 (jax reports every function it traces), its run 5,403 (my chip
+# runs, PR 38)
+COMPILE_RING_CAP = 16384
 
 ANNOTATION_PREFIX = "omldm."
 
@@ -57,6 +67,13 @@ ANNOTATION_PREFIX = "omldm."
 # trace, lowering and backend compile (a persistent-cache hit still emits
 # the last one, timing the retrieval)
 COMPILE_EVENT_PREFIX = "/jax/core/compile/"
+BACKEND_COMPILE_STAGE = "backend_compile_duration"
+# what jax 0.9 emits inside the backend compile's event, on its thread,
+# before it ends (jax/_src/compiler.py compile_or_get_cached): the cache was
+# asked, it answered, and how long the read took
+CACHE_ASKED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
 
 class Record(NamedTuple):
@@ -238,8 +255,9 @@ class Recorder:
     seconds, its counters, and a bounded ring of the newest records. Safe
     to note into from several threads."""
 
-    def __init__(self, cap: int = RING_CAP):
+    def __init__(self, cap: int = RING_CAP, caps: Optional[Dict[str, int]] = None):
         self.cap = cap
+        self.caps = caps or {}  # a name's own cap, where it has one
         self._stats: Dict[str, _SpanStats] = {}
         self._ids = itertools.count(1)  # next() is atomic under the GIL
         self._lock = threading.Lock()
@@ -271,7 +289,7 @@ class Recorder:
     def _stats_for(self, name: str) -> _SpanStats:
         stats = self._stats.get(name)
         if stats is None:
-            stats = self._stats[name] = _SpanStats(self.cap)
+            stats = self._stats[name] = _SpanStats(self.caps.get(name, self.cap))
         return stats
 
     def _thread_state(self):
@@ -280,6 +298,9 @@ class Recorder:
             local.stack = []
             local.cause = None
             local.ident = threading.get_ident()
+            # the persistent cache's answer to the backend compile under
+            # way on this thread: (hit?, retrieval seconds), or None
+            local.cache = None
         return local
 
     def _note(self, record: Record, counts: Optional[dict]) -> None:
@@ -380,10 +401,19 @@ class Recorder:
 
 
 # the process-wide recorder: always on, outlives any job
-RECORDER = Recorder()
+RECORDER = Recorder(caps={"compile": COMPILE_RING_CAP})
 span = RECORDER.span
 current = RECORDER.current
 adopt = RECORDER.adopt
+
+
+def _on_event(event: str, **kwargs) -> None:
+    """The persistent cache was asked, or answered, for the backend compile
+    under way on this thread: kept until that compile's record takes it."""
+    if event == CACHE_ASKED_EVENT:
+        RECORDER._thread_state().cache = (False, None)
+    elif event == CACHE_HIT_EVENT:
+        RECORDER._thread_state().cache = (True, None)
 
 
 def _on_duration_event(event: str, seconds: float, **kwargs) -> None:
@@ -392,21 +422,35 @@ def _on_duration_event(event: str, seconds: float, **kwargs) -> None:
     self time holds these seconds too). jax reports an event when it
     ends."""
     if not event.startswith(COMPILE_EVENT_PREFIX):
+        if event == CACHE_RETRIEVAL_EVENT:
+            local = RECORDER._thread_state()
+            if local.cache is not None:
+                local.cache = (local.cache[0], seconds)
         return
     local = RECORDER._thread_state()
     end = clock.PERF()
+    stage = event[len(COMPILE_EVENT_PREFIX):]
+    attrs = {"stage": stage}
+    if stage == BACKEND_COMPILE_STAGE:
+        answer, local.cache = local.cache, None
+        if answer is None:
+            attrs["cache"] = "off"
+        else:
+            attrs["cache"] = "hit" if answer[0] else "miss"
+            if answer[1] is not None:
+                attrs["retrieval_s"] = answer[1]
     RECORDER._note(
         Record(
             next(RECORDER._ids), "compile", end - seconds, end, seconds,
             local.ident,
             *_parent(local.stack[-1] if local.stack else local.cause),
-            kwargs.get("fun_name"),
-            {"stage": event[len(COMPILE_EVENT_PREFIX):]},
+            kwargs.get("fun_name"), attrs,
         ),
         None,
     )
 
 
+jax.monitoring.register_event_listener(_on_event)
 jax.monitoring.register_event_duration_secs_listener(_on_duration_event)
 
 

@@ -436,3 +436,176 @@ def test_fused_sparse_route_span_names_and_counters(tmp_path):
     assert "ingest_file" not in other.phase_table()
     other.terminate()
     job.terminate()
+
+
+@pytest.fixture
+def persistent_cache(tmp_path):
+    """jax's persistent cache in a directory of the test's own, every
+    program stored; jax's settings and its cache's state put back after."""
+    import jax
+    from jax._src import compilation_cache
+
+    names = ("jax_compilation_cache_dir", "jax_enable_compilation_cache",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    saved = {name: getattr(jax.config, name) for name in names}
+    jax.config.update("jax_compilation_cache_dir", str(tmp_path))
+    jax.config.update("jax_enable_compilation_cache", True)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    compilation_cache.reset_cache()
+    yield compilation_cache
+    for name, value in saved.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+def test_backend_compile_records_the_persistent_caches_answer(persistent_cache):
+    import jax
+    import jax.numpy as jnp
+
+    def cache_probe(x):
+        return jnp.sin(x) * 5.0 - 2.0
+
+    def backend_compile():
+        with tracing.span("fit", key="cache-test") as sp:
+            jax.jit(cache_probe)(jnp.ones(8)).block_until_ready()
+        [r] = [r for r in tracing.RECORDER.records("compile")
+               if r.parent == sp.id and r.key == "jit(cache_probe)"
+               and r.attrs["stage"] == tracing.BACKEND_COMPILE_STAGE]
+        return r.attrs
+
+    # an empty directory: asked, no entry; the entry is written
+    assert backend_compile() == {"stage": "backend_compile_duration", "cache": "miss"}
+    jax.clear_caches()
+    hit = backend_compile()
+    assert hit["cache"] == "hit" and 0.0 < hit["retrieval_s"] < 10.0
+    jax.config.update("jax_enable_compilation_cache", False)
+    persistent_cache.reset_cache()
+    jax.clear_caches()
+    assert backend_compile() == {"stage": "backend_compile_duration", "cache": "off"}
+    # no answer is left pending for the thread's next compile
+    assert tracing.RECORDER._thread_state().cache is None
+
+
+def test_cache_answer_follows_jaxs_event_order_on_its_thread():
+    """The listeners fed as jax 0.9's compile_or_get_cached feeds them: an
+    answer noted on one thread is taken by that thread's next backend
+    compile alone."""
+    import jax.monitoring as monitoring
+
+    rec = tracing.Recorder()
+    orig = tracing.RECORDER
+    tracing.RECORDER = rec
+    try:
+        def compile_event(name):
+            monitoring.record_event_duration_secs(
+                tracing.COMPILE_EVENT_PREFIX + tracing.BACKEND_COMPILE_STAGE, 0.5, fun_name=name)
+
+        monitoring.record_event(tracing.CACHE_ASKED_EVENT)
+        monitoring.record_event(tracing.CACHE_HIT_EVENT)
+        monitoring.record_event_duration_secs("/jax/compilation_cache/compile_time_saved_sec", 2.0)
+        monitoring.record_event_duration_secs(tracing.CACHE_RETRIEVAL_EVENT, 0.25)
+        compile_event("a")
+        other = threading.Thread(target=lambda: monitoring.record_event(tracing.CACHE_ASKED_EVENT))
+        other.start()
+        other.join()
+        compile_event("b")
+        monitoring.record_event(tracing.CACHE_ASKED_EVENT)
+        compile_event("c")
+    finally:
+        tracing.RECORDER = orig
+    assert [(r.key, r.attrs) for r in rec.records("compile")] == [
+        ("a", {"stage": "backend_compile_duration", "cache": "hit", "retrieval_s": 0.25}),
+        ("b", {"stage": "backend_compile_duration", "cache": "off"}),
+        ("c", {"stage": "backend_compile_duration", "cache": "miss"}),
+    ]
+
+
+def test_a_name_may_keep_more_records_than_the_rest():
+    rec = tracing.Recorder(cap=4, caps={"compile": 8})
+    for k in range(10):
+        with rec.span("fit", key=k):
+            pass
+        rec._note(tracing.Record(0, "compile", 0.0, 1.0, 1.0, 0, 0, "", k, None), None)
+    assert [r.key for r in rec.records("fit")] == [6, 7, 8, 9] and rec.dropped("fit") == 6
+    assert [r.key for r in rec.records("compile")] == list(range(2, 10)) and rec.dropped("compile") == 2
+    # the process-wide recorder keeps a whole run's compiles
+    assert tracing.RECORDER.caps == {"compile": tracing.COMPILE_RING_CAP} and tracing.COMPILE_RING_CAP > tracing.RING_CAP
+
+
+def test_deploy_span_holds_the_trainers_state_build():
+    t_mark = time.perf_counter()
+    job = _sparse_job([])
+    rec = tracing.RECORDER
+    [deploy] = [r for r in rec.records("deploy") if r.start >= t_mark]
+    [build] = [r for r in rec.records("build_state") if r.start >= deploy.start]
+    by_id = {r.id: r for name in rec.names() for r in rec.records(name)}
+    ancestors, r = [], build
+    while r.parent:
+        r = by_id[r.parent]
+        ancestors.append(r.name)
+    assert ancestors == ["deploy"] and deploy.key == 0
+    assert deploy.start <= build.start <= build.end <= deploy.end
+    [bridge] = job.spmd_bridges.values()
+    assert job.phase_table()["deploy"]["count"] == 1
+    assert job.phase_table()["deploy"]["params"] == bridge.trainer.n_params > 0
+    job.terminate()
+
+
+def test_dense_route_forecast_and_fallback_spans(tmp_path):
+    """The dense route's forecasts and codec fallbacks take the sparse
+    route's names and nesting: ``forecast`` > ``quiesce``, ``serve`` (one
+    row padded to the predict batch), ``emit``; ``fallback`` > ``quiesce``,
+    ``decode``."""
+    from omldm_tpu.config import JobConfig
+    from omldm_tpu.ops.native import fast_parser_available
+    from omldm_tpu.runtime import StreamJob
+    from omldm_tpu.runtime.job import REQUEST_STREAM
+    from omldm_tpu.runtime.spoke import PREDICT_BATCH
+
+    if not fast_parser_available():
+        pytest.skip("native parser unavailable")
+    rng = np.random.RandomState(5)
+    path = tmp_path / "dense.jsonl"
+    with open(path, "w") as f:
+        for i in range(200):
+            row = {"numericalFeatures": [round(float(v), 6) for v in rng.randn(6)]}
+            if i in (70, 150):
+                row["operation"] = "forecasting"
+            else:
+                row.update(target=float(rng.randint(2)), operation="training")
+                if i == 110:  # categorical features: left to the Python codec
+                    row["categoricalFeatures"] = ["red"]
+            f.write(json.dumps(row) + "\n")
+    preds = []
+    job = StreamJob(JobConfig(parallelism=1, batch_size=16, test=False))
+    job.set_sinks(on_prediction=preds.append)
+    job.process_event(REQUEST_STREAM, json.dumps({
+        "id": 0, "request": "Create",
+        "learner": {"name": "PA", "hyperParameters": {"C": 0.1},
+                    "dataStructure": {"nFeatures": 6}},
+        "trainingConfiguration": {"protocol": "Synchronous", "engine": "spmd"},
+    }))
+    job.ensure_deployed(6)
+    rec = tracing.RECORDER
+    t_mark = time.perf_counter()
+    serve0 = rec.counts("serve")
+    assert job.run_file_fused(str(path))
+    records = [r for name in rec.names() if name != "compile"
+               for r in rec.records(name) if r.start >= t_mark]
+    children = collections.defaultdict(list)
+    for r in sorted(records, key=lambda r: r.start):
+        children[r.parent].append(r.name)
+    forecasts = [r for r in records if r.name == "forecast"]
+    [fallback] = [r for r in records if r.name == "fallback"]
+    [file] = [r for r in records if r.name == "ingest_file"]
+    assert len(forecasts) == len(preds) == 2
+    assert {r.parent for r in forecasts} == {fallback.parent} == {file.id}
+    assert [children[r.id] for r in forecasts] == [["quiesce", "serve", "emit"]] * 2
+    assert children[fallback.id] == ["quiesce", "decode"]
+    counts = rec.counts("serve")
+    assert (counts["rows"] - serve0.get("rows", 0),
+            counts["rows_padded"] - serve0.get("rows_padded", 0)) == (2, 2 * PREDICT_BATCH)
+    assert PREDICT_BATCH == 16
+    job.terminate()
