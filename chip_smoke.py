@@ -394,8 +394,12 @@ def hlo_wide_passes(text: str, n: int,
                     every_branch: bool = False) -> List[Tuple[str, str, str]]:
     """The instructions of a compiled program whose result holds ``n`` or
     more elements and that are a relayout's (``_HLO_PASSES``) or a fusion
-    without the scatter in it, outside the branch a conditional takes when
-    its predicate holds (the protocol's sync): (computation, name, opcode).
+    that does not write in place, outside the branch a conditional takes
+    when its predicate holds (the protocol's sync): (computation, name,
+    opcode). A fusion writes in place when it holds the scatter, or when its
+    root is a ``dynamic-update-slice`` (on a TPU the plan's final add is the
+    kernel ``sparse_tile_scatter_add``, a ``tpu_custom_call`` that no rule
+    here names, and such a slice for the vector's partial last tile: PR 39).
     The false branch, which every step runs, is walked; with
     ``every_branch`` the other is too (on one chip the sync moves nothing
     either: it returns the donated vector)."""
@@ -406,6 +410,12 @@ def hlo_wide_passes(text: str, n: int,
     def holds_scatter(comp: str) -> bool:
         return any(
             op == "scatter" or any(holds_scatter(c) for c in _hlo_called(line))
+            for _, op, _, line in comps.get(comp, ())
+        )
+
+    def in_place(comp: str) -> bool:
+        return holds_scatter(comp) or any(
+            op == "dynamic-update-slice" and line.lstrip().startswith("ROOT ")
             for _, op, _, line in comps.get(comp, ())
         )
 
@@ -427,7 +437,7 @@ def hlo_wide_passes(text: str, n: int,
                 continue
             if op == "fusion":
                 if _hlo_wide(shape, n) and not any(
-                    holds_scatter(c) for c in called
+                    in_place(c) for c in called
                 ):
                     found.append((comp, name, op))
                 continue

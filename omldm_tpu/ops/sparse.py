@@ -19,10 +19,16 @@ the whole operand (scatter), which is what the index plan below is for.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from omldm_tpu.ops.attention import _vma_struct_factory
+from omldm_tpu.utils import tracing
 
 SparseBatch = tuple  # (idx[B, K] int32, val[B, K] float32)
 
@@ -188,10 +194,17 @@ def plan_scatter_add(
     index, in place. Only the order in which one address's duplicates are
     summed differs from the plain scatter, which an overflowing launch
     takes itself. ``place`` is what :func:`plan_gather` returned beside the
-    values."""
+    values.
+
+    The final add at the distinct addresses is read from the backend: on a
+    TPU :func:`tile_scatter_add`, which reads and writes only the touched
+    tiles of ``v``; elsewhere XLA's scatter. Which one was traced is counted
+    under ``sparse_scatter_path`` (``pallas`` or ``xla``) in
+    ``tracing.RECORDER``."""
     n = plan.sidx.shape[0]
     cap = plan_capacity(n)
     upd = upd.reshape(n)
+    pallas = jax.default_backend() == "tpu"
 
     def combined(v):
         _, upd_sorted = _permute(place, upd)
@@ -199,6 +212,11 @@ def plan_scatter_add(
         # a run's total stands at its END
         is_end = jnp.concatenate([plan.is_start[1:], jnp.ones((1,), bool)])
         uidx, tot = _compact(is_end, plan.sidx, v.shape[0], total)
+        tracing.RECORDER.add_counts(
+            "sparse_scatter_path", **{"pallas" if pallas else "xla": 1}
+        )
+        if pallas:
+            return tile_scatter_add(v, uidx[:cap], tot[:cap], plan.distinct)
         # told "sorted" only: told "unique" too, the TPU's compiler copies
         # the whole operand (3.2 ms at 2^28 weights, whatever the number of
         # updates; PERF.md section 6, PR 30)
@@ -212,6 +230,184 @@ def plan_scatter_add(
         combined,
         w,
     )
+
+
+# ---------------------------------------------------------------------------
+# the plan's final add on a TPU: a Pallas kernel over the touched tiles
+# ---------------------------------------------------------------------------
+
+# a 1-D float32 vector lies in HBM in tiles of 1,024 elements (layout
+# T(1024)), the least a DMA may move of it: Mosaic refuses a slice of 128
+# ("must be aligned to tiling (1024)"). So a tile is the kernel's window.
+TILE = 1024
+# addresses the reads run ahead of the one being added; twice as many VMEM
+# slots, so that a write has as long to land (the probe: PERF.md section
+# 6, PR 39)
+AHEAD = 16
+# addresses a grid step holds in SMEM (1 MiB on a v5e): two int32 / float32
+# arrays, double-buffered, 512 KiB at most (the cell's launch of 4096 x 41
+# slots, 41,984 addresses, is two steps)
+BLOCK = 32768
+# an address's word in the kernel: the address, and in the sign bit
+# whether it is the first of its group
+_ADDRESS = (1 << 31) - 1
+
+
+def _tile_kernel(block, ahead, ends_ref, word_ref, tot_ref, v_in, v_ref,
+                 rows, sems, tiles):
+    """One grid step: its block's live addresses (before ``distinct`` and
+    in one of the whole tiles; a prefix of the block), in groups that share
+    a tile, one pass. Group ``g``'s tile is read into VMEM slot ``g % (2 *
+    ahead)`` when the pass is ``ahead`` addresses before it, gets each
+    address's total at its lane (``row + t``; every other lane keeps its
+    bits) and is written back after its last address. A slot is read into
+    again only once its last write has landed, ``ahead`` groups at least
+    after it began. The addresses are sorted, so a tile is one group and
+    never has two read-modify-writes in flight; a tile that two blocks
+    share is two groups (a block's first address starts one), the first
+    written before the second is read, since a step waits for all of its
+    writes."""
+    slots = 2 * ahead
+    end = ends_ref[pl.program_id(0)]
+    # a tile as the vector unit holds it, 8 x 128
+    lanes = (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) * 128
+             + jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1))
+
+    def copy(s, read):
+        hbm = v_ref.at[pl.ds(pl.multiple_of(tiles[s] * TILE, TILE), TILE)]
+        vmem = rows.at[pl.ds(pl.multiple_of(s * TILE, TILE), TILE)]
+        if read:
+            return pltpu.make_async_copy(hbm, vmem, sems.at[0, s])
+        return pltpu.make_async_copy(vmem, hbm, sems.at[1, s])
+
+    def read(r, g):
+        """Where address ``r`` starts a group, read its tile as group
+        ``g``'s: -> the groups read."""
+        w = word_ref[jnp.minimum(r, block - 1)]
+        starts = (r < end) & (w < 0)
+
+        @pl.when(starts)
+        def _():
+            s = g % slots
+
+            @pl.when(g >= slots)
+            def _():  # group g - slots's write
+                copy(s, False).wait()
+
+            tiles[s] = jax.lax.shift_right_logical(
+                w & _ADDRESS, TILE.bit_length() - 1
+            )
+            copy(s, True).start()
+
+        return g + starts.astype(jnp.int32)
+
+    def add(j, c):
+        groups_read, g, w = c
+        groups_read = read(j + ahead, groups_read)
+        nxt = word_ref[jnp.minimum(j + 1, block - 1)]
+        g = g + (w < 0).astype(jnp.int32)
+        s = g % slots
+
+        @pl.when(w < 0)
+        def _():
+            copy(s, True).wait()
+
+        at = pl.multiple_of(s * TILE, TILE)
+        row = rows[pl.ds(at, TILE)].reshape(8, 128)
+        rows[pl.ds(at, TILE)] = jnp.where(
+            lanes == (w & (TILE - 1)), row + tot_ref[j], row
+        ).reshape(TILE)
+
+        @pl.when((j + 1 == end) | (nxt < 0))
+        def _():
+            copy(s, False).start()
+
+        return groups_read, g, nxt
+
+    groups_read = jax.lax.fori_loop(0, ahead, read, jnp.int32(0))
+    groups_read, _, _ = jax.lax.fori_loop(
+        0, end, add, (groups_read, jnp.int32(-1), word_ref[0])
+    )
+
+    @pl.loop(jnp.maximum(groups_read - slots, 0), groups_read)
+    def _(g):  # the writes not waited for yet
+        copy(g % slots, False).wait()
+
+
+def _tail_add(v, n0, uidx, tot):
+    """The addresses past the last whole tile, ``[n0, N)``, by a small
+    in-place update (no DMA may move their tile, which runs past ``N``). In
+    the benchmark's vector they are the 13 numerics and the bias, which
+    every row touches. ``uidx`` is sorted and unique, so they are the
+    ``N - n0`` entries at most from the first at or past ``n0`` on."""
+    n, cap = v.shape[0], uidx.shape[0]
+    m = min(n - n0, cap)
+    at = jnp.minimum(jnp.sum(uidx < n0), cap - m)
+    near, t = (jax.lax.dynamic_slice(a, (at,), (m,)) for a in (uidx, tot))
+    hits = near[:, None] == n0 + jnp.arange(n - n0, dtype=near.dtype)
+    old = v[n0:]
+    new = jnp.where(jnp.any(hits, 0), old + t[jnp.argmax(hits, 0)], old)
+    return jax.lax.dynamic_update_slice(v, new, (n0,))
+
+
+def tile_scatter_add(v, uidx, tot, distinct, ahead: int = AHEAD,
+                     interpret: bool = False):
+    """``v.at[uidx].add(tot, mode="drop", indices_are_sorted=True)``, bit
+    for bit, in place: ``uidx`` ascending and unique, its first
+    ``distinct`` entries addresses in ``[0, N)`` (non-negative, as
+    :func:`index_plan` takes them) and the rest at or past ``N``, which
+    are dropped. Each touched tile of ``v`` is read once and written once
+    (:func:`_tile_kernel`), the partial tail by :func:`_tail_add`; nothing
+    else of ``v`` is read, written, copied or laid out anew. What the
+    kernel reads of an address is made here, elementwise over ``cap``
+    entries: its word (the sign bit set where it starts a group) and the
+    live addresses' count in each block."""
+    n, cap = v.shape[0], uidx.shape[0]
+    n0 = n // TILE * TILE
+    steps = -(-cap // BLOCK)
+    # a block of a 1-D array is whole or a number of its tiles
+    block = cap if steps == 1 else -(-cap // (steps * TILE)) * TILE
+    if steps * block > cap:
+        pad = steps * block - cap
+        uidx_b = jnp.concatenate([uidx, jnp.full((pad,), n, uidx.dtype)])
+        tot_b = jnp.concatenate([tot, jnp.zeros((pad,), tot.dtype)])
+    else:
+        uidx_b, tot_b = uidx, tot
+    pos = jnp.arange(steps * block, dtype=jnp.int32)
+    live = (pos < distinct) & (uidx_b < n0)
+    tile = jax.lax.shift_right_logical(uidx_b, TILE.bit_length() - 1)
+    starts = live & ((pos % block == 0) | (tile != jnp.roll(tile, 1)))
+    word = jnp.where(starts, uidx_b | jnp.int32(-(1 << 31)), uidx_b)
+    ends = jnp.sum(live.reshape(steps, block).astype(jnp.int32), axis=1)
+    smem = pl.BlockSpec((block,), lambda b, e: (b,), memory_space=pltpu.SMEM)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    params = {} if interpret else {
+        "compiler_params": pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)
+        )
+    }
+    out = pl.pallas_call(
+        functools.partial(_tile_kernel, block, ahead),
+        name="sparse_tile_scatter_add",
+        out_shape=_vma_struct_factory(v)(v.shape, v.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(steps,),
+            in_specs=[smem, smem, hbm],
+            out_specs=hbm,
+            scratch_shapes=[
+                pltpu.VMEM((2 * ahead * TILE,), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2 * ahead)),
+                pltpu.SMEM((2 * ahead,), jnp.int32),
+            ],
+        ),
+        input_output_aliases={3: 0},
+        interpret=interpret,
+        **params,
+    )(ends, word, tot_b, v)
+    if n0 == n:
+        return out
+    return _tail_add(out, n0, uidx, tot)
 
 
 # ---------------------------------------------------------------------------

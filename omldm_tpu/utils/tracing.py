@@ -19,7 +19,8 @@ observability is CountableSerial byte accounting). Four things live here:
   the seconds); :meth:`Recorder.add_counts` adds what is known only later
   (a launch's device counters). :data:`RECORDER` is the process-wide
   recorder ``span`` writes to (``compile`` keeps :data:`COMPILE_RING_CAP`
-  records, a whole run's); it is always on (two clock reads, one inactive
+  records, a whole run's, and ``fit`` :data:`FIT_RING_CAP`, a traced
+  window's launches); it is always on (two clock reads, one inactive
   annotation and one ring slot a span -- measured in ``PERF.md``).
   ``runtime.telemetry.PhaseProfile`` is the table over a recorder, so the
   host plane's phases are these spans.
@@ -60,6 +61,10 @@ RING_CAP = 4096
 # about 4,900 (jax reports every function it traces), its run 5,403 (my chip
 # runs, PR 38)
 COMPILE_RING_CAP = 16384
+# the ``fit`` records of a traced window, one a launch: the criteo cell's
+# 5.2 ms launches wrote 1,936 a window, and the tile kernel of PR 39 more
+# than halves a launch (ISSUE 39)
+FIT_RING_CAP = 8192
 
 ANNOTATION_PREFIX = "omldm."
 
@@ -401,7 +406,7 @@ class Recorder:
 
 
 # the process-wide recorder: always on, outlives any job
-RECORDER = Recorder(caps={"compile": COMPILE_RING_CAP})
+RECORDER = Recorder(caps={"compile": COMPILE_RING_CAP, "fit": FIT_RING_CAP})
 span = RECORDER.span
 current = RECORDER.current
 adopt = RECORDER.adopt

@@ -17,7 +17,7 @@ import pytest
 from omldm_tpu.api.data import DataInstance
 from omldm_tpu.api.requests import LearnerSpec
 from omldm_tpu.learners.registry import make_learner
-from omldm_tpu.ops.sparse import sparse_matvec, sparse_scatter_add
+from omldm_tpu.ops.sparse import sparse_matvec, sparse_scatter_add, tile_scatter_add
 from omldm_tpu.pipelines import MLPipeline
 from omldm_tpu.runtime.vectorizer import SparseMicroBatcher, SparseVectorizer
 
@@ -266,6 +266,152 @@ class TestSparseOps:
         # nor can it move weights that are not 4 bytes wide as int32 bits
         with pytest.raises(ValueError, match="4-byte"):
             sp._resolve_impl(300, 40, impl="plan", dtype=jnp.bfloat16)
+
+
+_N = (1 << 16) + 14  # 64 whole tiles of 1,024 and a tail of 14
+_LAST = _N // 1024 * 1024  # the tail's first address
+
+
+def _random_addresses(n, seed):
+    return np.random.RandomState(seed).choice(_N, size=n, replace=False)
+
+
+# (addresses, cap): the plan's sorted unique totals, the spares N + j after
+# the first ``distinct``
+_TILE_CASES = {
+    "addresses_sharing_tiles": (
+        np.r_[5 * 1024 + np.array([0, 1, 2, 1023]), 6 * 1024,
+              _random_addresses(600, 1)], 1000),
+    "first_and_last_whole_tiles": (
+        np.r_[0, 1, 1023, 1024, _LAST - 1024, _LAST - 1], 8),
+    "tail_with_the_tile_before": (
+        np.r_[np.arange(_LAST - 6, _N), 17], 64),
+    "spares_at_and_past_n": (np.r_[3, 4096, _N - 1], 40),
+    "distinct_0": (np.zeros(0, np.int64), 64),
+    "distinct_cap": (np.r_[_random_addresses(200, 2), np.arange(_LAST, _N)], 214),
+    "many_more_groups_than_slots": (
+        np.r_[_random_addresses(3000, 3), np.arange(_LAST, _N)], 4000),
+}
+
+
+def _tile_inputs(addrs, cap, seed=0):
+    addrs = np.unique(addrs)
+    d = len(addrs)
+    uidx = np.r_[addrs, _N + np.arange(cap - d)].astype(np.int32)
+    rng = np.random.RandomState(seed)
+    v = rng.randn(_N).astype(np.float32)
+    tot = rng.randn(cap).astype(np.float32)
+    return v, uidx, tot, d
+
+
+def _bits(a):
+    return np.asarray(a).view(np.int32)
+
+
+def _xla_line(v, uidx, tot):
+    return v.at[uidx].add(tot, mode="drop", indices_are_sorted=True)
+
+
+class TestTileScatterAdd:
+    """The plan's final add as a Pallas kernel over the touched tiles
+    (``ops.sparse.tile_scatter_add``, interpreted here) against the XLA line
+    it replaces on a TPU, bit for bit, at N = 2^16 + 14."""
+
+    def _check(self, v, uidx, tot, d, ahead=2):
+        args = [jnp.asarray(a) for a in (v, uidx, tot)]
+        want = _xla_line(*args)
+        got = tile_scatter_add(*args, jnp.int32(d), ahead=ahead, interpret=True)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    @pytest.mark.parametrize("case", sorted(_TILE_CASES))
+    def test_matches_the_xla_line(self, case):
+        self._check(*_tile_inputs(*_TILE_CASES[case]))
+
+    def test_zero_totals_keep_negative_zeros(self):
+        """A touched lane is ``row + t`` (-0.0 + 0.0 is +0.0, as the scatter
+        makes it); every other lane of its tile keeps its bits."""
+        v, uidx, tot, d = _tile_inputs(np.r_[2048, 2050, _LAST + 3], 8)
+        v[:] = -0.0
+        tot[:] = 0.0
+        self._check(v, uidx, tot, d)
+
+    def test_a_tile_split_between_grid_steps(self, monkeypatch):
+        """Blocks of 1,024 addresses (2,500 in three steps, the last padded
+        past ``cap``), 35 addresses a tile: the groups that hold positions
+        1,024 and 2,048 straddle two grid steps, and each such tile is
+        written by the first step before the second reads it."""
+        from omldm_tpu.ops import sparse as sp
+
+        monkeypatch.setattr(sp, "BLOCK", 1024)
+        rng = np.random.RandomState(4)
+        spread = np.sort(rng.choice(np.arange(64) * 1024, size=62, replace=False))
+        addrs = np.r_[
+            (spread[:, None] + np.arange(0, 1024, 29)).ravel(),  # 35 a tile
+            np.arange(_LAST, _N),
+        ]
+        v, uidx, tot, d = _tile_inputs(addrs, 2500)
+        for edge in (1024, 2048):
+            assert uidx[edge - 1] // 1024 == uidx[edge] // 1024
+        self._check(v, uidx, tot, d, ahead=4)
+
+    def test_under_shard_map_2x2(self):
+        """dp x hub = 2 x 2: each shard adds into its own block, and some
+        shards are handed addresses past their block, which are dropped.
+        Traced with shard_map's check of varying axes on (as the step is),
+        run with it off (the Pallas interpreter cannot slice a varying
+        block at an unvarying index)."""
+        from jax.sharding import Mesh, PartitionSpec as P
+
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "hub"))
+        cap, parts = 300, []
+        for k in range(4):
+            addrs = np.r_[_random_addresses(150, 10 + k), np.arange(_LAST, _N)]
+            if k % 2:
+                addrs = np.r_[addrs, 2 * _N, 2 * _N + 5]  # not this block's
+            parts.append(_tile_inputs(addrs, cap, seed=k))
+        v, uidx, tot = (np.concatenate([p[i] for p in parts]) for i in range(3))
+        ds = np.array([p[3] for p in parts], np.int32)
+        spec = P(("dp", "hub"))
+
+        def sharded(fn, check=False):
+            return jax.jit(jax.shard_map(
+                fn, mesh=mesh, in_specs=(spec,) * 4, out_specs=spec,
+                check_vma=check,
+            ))
+
+        def kernel(v, u, t, d):
+            return tile_scatter_add(v, u, t, d[0], ahead=2, interpret=True)
+
+        jaxpr = str(jax.make_jaxpr(sharded(kernel, True))(v, uidx, tot, ds))
+        assert "sparse_tile_scatter_add" in jaxpr
+        got = sharded(kernel)(v, uidx, tot, ds)
+        want = sharded(lambda v, u, t, d: _xla_line(v, u, t))(v, uidx, tot, ds)
+        np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    def test_which_add_runs_is_read_from_the_backend_and_counted(self, monkeypatch):
+        """``plan_scatter_add`` counts its final add at trace time under
+        ``sparse_scatter_path``: ``xla`` here, ``pallas`` (the kernel in the
+        program) where the backend is a TPU."""
+        from omldm_tpu.ops.sparse import sparse_update
+        from omldm_tpu.utils import tracing
+
+        d, idx, val = _dup_heavy(4096, 3)()
+        w, coef = jnp.zeros(d, jnp.float32), jnp.ones(idx.shape[0], jnp.float32)
+
+        def traced():
+            before = tracing.RECORDER.counts("sparse_scatter_path")
+            jaxpr = str(jax.make_jaxpr(
+                lambda w, i, v, c: sparse_update(w, i, v, impl="plan")[1](w, c)
+            )(w, idx, val, coef))
+            after = tracing.RECORDER.counts("sparse_scatter_path")
+            return {k: n - before.get(k, 0) for k, n in after.items()
+                    if n != before.get(k, 0)}, jaxpr
+
+        counts, jaxpr = traced()
+        assert counts == {"xla": 1} and "pallas_call" not in jaxpr
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        counts, jaxpr = traced()
+        assert counts == {"pallas": 1} and "sparse_tile_scatter_add" in jaxpr
 
 
 class TestPlanInTheSPMDStep:

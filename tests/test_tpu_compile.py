@@ -34,20 +34,15 @@ def one_chip_mesh():
     return Mesh(np.array(topo.devices[:1]).reshape(1, 1), ("dp", "hub"))
 
 
-def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
+def compiled_plan_update(mesh, batch: int, n_weights: int = (1 << 28) + 14):
     """The sparse PA-II update through the index plan at the benchmark's
-    width (2^28 + 14 weights, a tail step's 256 x 41 slots, which compiles
-    in seconds), in the shape the SPMD step has under Synchronous on one
-    chip: a ``shard_map`` over a 1 x 1 mesh, the state's vector leaf
-    donated (the weights: Synchronous holds no ``est`` and no ``center``
-    beside them), and a sync branch that returns the trainer's own
-    ``_ps_allreduce`` of the weights (the form a mesh of more shards
-    compiles). The vector is updated in place, and NO computation of the
-    module, the sync branch and an overflowing launch's plain pair
-    included, passes over the model's width but the scatter: no copy, no
-    other fusion of N elements."""
-    n_weights, batch, slots = (1 << 28) + 14, 256, 41
-    mesh = one_chip_mesh
+    width, ``batch`` x 41 slots, in the shape the SPMD step has under
+    Synchronous on one chip: a ``shard_map`` over a 1 x 1 mesh, the state's
+    vector leaf donated (the weights: Synchronous holds no ``est`` and no
+    ``center`` beside them), and a sync branch that returns the trainer's
+    own ``_ps_allreduce`` of the weights (the form a mesh of more shards
+    compiles). -> the compiled program."""
+    slots = 41
     ps = SimpleNamespace(shard_size=n_weights)  # hub = 1: one bucket
 
     def step(state, idx, val, y):
@@ -78,7 +73,7 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
             dims, dtype, sharding=NamedSharding(mesh, spec)
         )
 
-    compiled = jax.jit(
+    return jax.jit(
         jax.shard_map(
             step, mesh=mesh, in_specs=(specs, rows, rows, rows),
             out_specs=(specs, (stack, stack)),
@@ -92,11 +87,81 @@ def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
         shape((1, batch, slots), jnp.float32, rows),
         shape((1, batch), jnp.float32, rows),
     ).compile()
+
+
+def test_plan_update_at_2e28_weights_passes_over_them_once(one_chip_mesh):
+    """:func:`compiled_plan_update` at a tail step's 256 x 41 slots (which
+    compiles in seconds), the backend's own choice of the final add (the
+    CPU's here: XLA's scatter). The vector is updated in place, and NO
+    computation of the module, the sync branch and an overflowing launch's
+    plain pair included, passes over the model's width but the scatter: no
+    copy, no other fusion of N elements."""
+    n_weights = (1 << 28) + 14
+    compiled = compiled_plan_update(one_chip_mesh, 256, n_weights)
     text = compiled.as_text()
     assert chip_smoke.hlo_wide_passes(text, n_weights, every_branch=True) == []
     # the three state leaves, the vector among them, are donated
     assert chip_smoke.hlo_aliased_parameters(text) == [0, 1, 2]
     # one vector in, the same out, and nothing model-sized between
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+def test_plan_update_on_a_tpu_adds_by_tiles_in_place(one_chip_mesh, monkeypatch):
+    """The benchmark's launch, 4,096 x 41 slots over 2^28 + 14 weights,
+    with the final add a TPU takes (the tile kernel: the backend is said to
+    be one, the compile is for the chip). The branch every launch that does
+    not overflow takes holds the kernel's custom call and no scatter; the
+    only other instruction there with a result of 2^28 elements is the
+    in-place update of the 14 weights past the last whole tile (a fusion
+    whose root is a ``dynamic-update-slice`` of 14 elements): no copy, no
+    relayout, no other fusion of N. The state stays donated."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    n_weights = (1 << 28) + 14
+    compiled = compiled_plan_update(one_chip_mesh, 4096, n_weights)
+    text = compiled.as_text()
+    comps = chip_smoke.hlo_computations(text)
+
+    def walk(comp, seen):
+        if comp not in seen:
+            seen.add(comp)
+            for _, _, _, line in comps.get(comp, ()):
+                for c in chip_smoke._hlo_called(line):
+                    walk(c, seen)
+        return seen
+
+    def ops(comp):
+        return [(name, op, shape, line) for c in walk(comp, set())
+                for name, op, shape, line in comps.get(c, ())]
+
+    kernel_lines = [l for _, op, _, l in ops("ENTRY") if op == "custom-call"
+                    and "sparse_tile_scatter_add" in l]
+    assert len(kernel_lines) == 1 and "tpu_custom_call" in kernel_lines[0]
+    # the conditional whose branch holds the kernel: the plan's scatter half
+    [(branches, taken)] = {
+        (tuple(branches), b)
+        for _, op, _, line in ops("ENTRY") if op == "conditional"
+        for branches in [chip_smoke._hlo_called(line)] for b in branches
+        if kernel_lines[0] in [l for _, _, _, l in ops(b)]
+    }
+    assert not any(op == "scatter" for _, op, _, _ in ops(taken))
+    # what the branch itself computes at the model's width (its argument
+    # and result tuples aside)
+    wide = [(name, op, line) for name, op, shape, line in comps[taken]
+            if chip_smoke._hlo_wide(shape, n_weights)
+            and op not in ("parameter", "get-tuple-element", "tuple")]
+    assert sorted(op for _, op, _ in wide) == ["custom-call", "fusion"]
+    [fusion] = [line for _, op, line in wide if op == "fusion"]
+    [root] = [line for c in chip_smoke._hlo_called(fusion)
+              for _, op, _, line in comps[c] if line.lstrip().startswith("ROOT ")]
+    assert " dynamic-update-slice(" in root
+    assert "f32[14]" in " ".join(
+        line for c in chip_smoke._hlo_called(fusion)
+        for _, op, shape, line in comps[c] if op == "select")
+    # the other branch (an overflowing launch) keeps XLA's plain scatter
+    assert any(op == "scatter" for b in branches if b != taken
+               for _, op, _, _ in ops(b))
+    assert chip_smoke.hlo_wide_passes(text, n_weights, every_branch=True) == []
+    assert chip_smoke.hlo_aliased_parameters(text) == [0, 1, 2]
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
 
 

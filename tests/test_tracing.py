@@ -531,7 +531,21 @@ def test_a_name_may_keep_more_records_than_the_rest():
     assert [r.key for r in rec.records("fit")] == [6, 7, 8, 9] and rec.dropped("fit") == 6
     assert [r.key for r in rec.records("compile")] == list(range(2, 10)) and rec.dropped("compile") == 2
     # the process-wide recorder keeps a whole run's compiles
-    assert tracing.RECORDER.caps == {"compile": tracing.COMPILE_RING_CAP} and tracing.COMPILE_RING_CAP > tracing.RING_CAP
+    assert tracing.RECORDER.caps["compile"] == tracing.COMPILE_RING_CAP > tracing.RING_CAP
+
+
+def test_the_fit_ring_holds_a_window_of_short_launches():
+    """The process-wide recorder keeps 8,192 ``fit`` records (a traced
+    window of the criteo cell's launches) and 4,096 of other names."""
+    rec = tracing.Recorder(caps=dict(tracing.RECORDER.caps))
+    assert rec.caps == {"compile": tracing.COMPILE_RING_CAP, "fit": 8192}
+    assert tracing.RING_CAP == 4096
+    for k in range(8192 + 5):
+        for name in ("fit", "launch"):
+            rec._note(tracing.Record(0, name, 0.0, 1.0, 1.0, 0, 0, "", k, None), None)
+    assert len(rec.records("fit")) == 8192 and rec.dropped("fit") == 5
+    assert rec.records("fit")[0].key == 5
+    assert len(rec.records("launch")) == 4096 and rec.dropped("launch") == 8192 + 5 - 4096
 
 
 def test_deploy_span_holds_the_trainers_state_build():
