@@ -200,7 +200,8 @@ def plan_scatter_add(
     TPU :func:`tile_scatter_add`, which reads and writes only the touched
     tiles of ``v``; elsewhere XLA's scatter. Which one was traced is counted
     under ``sparse_scatter_path`` (``pallas`` or ``xla``) in
-    ``tracing.RECORDER``."""
+    ``tracing.RECORDER``, the kernel's tiles a batch beside ``pallas``
+    (``tiles_per_batch``, added at each trace like ``pallas``)."""
     n = plan.sidx.shape[0]
     cap = plan_capacity(n)
     upd = upd.reshape(n)
@@ -212,11 +213,13 @@ def plan_scatter_add(
         # a run's total stands at its END
         is_end = jnp.concatenate([plan.is_start[1:], jnp.ones((1,), bool)])
         uidx, tot = _compact(is_end, plan.sidx, v.shape[0], total)
-        tracing.RECORDER.add_counts(
-            "sparse_scatter_path", **{"pallas" if pallas else "xla": 1}
-        )
         if pallas:
+            tracing.RECORDER.add_counts(
+                "sparse_scatter_path", pallas=1,
+                tiles_per_batch=min(CHUNK, v.shape[0] // TILE),
+            )
             return tile_scatter_add(v, uidx[:cap], tot[:cap], plan.distinct)
+        tracing.RECORDER.add_counts("sparse_scatter_path", xla=1)
         # told "sorted" only: told "unique" too, the TPU's compiler copies
         # the whole operand (3.2 ms at 2^28 weights, whatever the number of
         # updates; PERF.md section 6, PR 30)
@@ -240,98 +243,127 @@ def plan_scatter_add(
 # T(1024)), the least a DMA may move of it: Mosaic refuses a slice of 128
 # ("must be aligned to tiling (1024)"). So a tile is the kernel's window.
 TILE = 1024
-# addresses the reads run ahead of the one being added; twice as many VMEM
-# slots, so that a write has as long to land (the probe: PERF.md section
-# 6, PR 39)
-AHEAD = 16
-# addresses a grid step holds in SMEM (1 MiB on a v5e): two int32 / float32
-# arrays, double-buffered, 512 KiB at most (the cell's launch of 4096 x 41
-# slots, 41,984 addresses, is two steps)
-BLOCK = 32768
-# an address's word in the kernel: the address, and in the sign bit
-# whether it is the first of its group
-_ADDRESS = (1 << 31) - 1
+# tiles a batch holds: the kernel reads a batch's tiles, adds into them and
+# writes them back in phases, two batches in VMEM (4 MiB, inside the 16 MiB
+# a v5e's kernel may use by default)
+CHUNK = 512
+# DMAs issued, and addresses added, a loop step: the scalar core that
+# issues them is what bounds the kernel, and a loop step costs it more than
+# a DMA (PERF.md section 6)
+UNROLL = 8
+# addresses a grid step holds in SMEM (1 MiB on a v5e): three int32 /
+# float32 arrays, double-buffered, 384 KiB at most (the cell's launch of
+# 4096 x 41 slots, 41,984 addresses, is three steps)
+BLOCK = 16384
 
 
-def _tile_kernel(block, ahead, ends_ref, word_ref, tot_ref, v_in, v_ref,
-                 rows, sems, tiles):
+def _tile_kernel(chunk, unroll, chunks, groups_ref, bounds_ref, off_ref,
+                 tot_ref, tiles_ref, v_in, v_ref, rows, sems):
     """One grid step: its block's live addresses (before ``distinct`` and
-    in one of the whole tiles; a prefix of the block), in groups that share
-    a tile, one pass. Group ``g``'s tile is read into VMEM slot ``g % (2 *
-    ahead)`` when the pass is ``ahead`` addresses before it, gets each
+    in one of the whole tiles; a prefix of the block) in groups that share
+    a tile, the groups in batches of ``chunk``. Batch ``c``'s tiles are read
+    into VMEM buffer ``c % 2`` while batch ``c - 1`` is added, get each
     address's total at its lane (``row + t``; every other lane keeps its
-    bits) and is written back after its last address. A slot is read into
-    again only once its last write has landed, ``ahead`` groups at least
-    after it began. The addresses are sorted, so a tile is one group and
-    never has two read-modify-writes in flight; a tile that two blocks
-    share is two groups (a block's first address starts one), the first
-    written before the second is read, since a step waits for all of its
-    writes."""
-    slots = 2 * ahead
-    end = ends_ref[pl.program_id(0)]
+    bits) and are written back; a buffer is read into again only once its
+    writes have landed. No loop branches: a batch's reads (or writes) are
+    issued in a loop of their own, ``unroll`` a step, and waited for at
+    once (``wait``); its addresses are added ``unroll`` at a time, each
+    one's place in the buffers made beforehand (``off``). The addresses
+    are sorted, so a
+    tile is one group, in one batch, with one read-modify-write; a tile
+    that two blocks share is two groups (a block's first address starts
+    one), the first written before the second is read, since a step waits
+    for all of its writes."""
+    step = pl.program_id(0)
+    groups = groups_ref[step]
     # a tile as the vector unit holds it, 8 x 128
     lanes = (jax.lax.broadcasted_iota(jnp.int32, (8, 128), 0) * 128
              + jax.lax.broadcasted_iota(jnp.int32, (8, 128), 1))
 
-    def copy(s, read):
-        hbm = v_ref.at[pl.ds(pl.multiple_of(tiles[s] * TILE, TILE), TILE)]
-        vmem = rows.at[pl.ds(pl.multiple_of(s * TILE, TILE), TILE)]
+    def count(c):
+        """Batch ``c``'s tiles; none before the first or past the last."""
+        left = jnp.minimum(groups - c * chunk, (c + 1) * chunk)
+        return jax.lax.clamp(0, left, chunk)
+
+    def copy(hbm, vmem, read, slot):
+        sem = sems.at[0 if read else 1, slot]
         if read:
-            return pltpu.make_async_copy(hbm, vmem, sems.at[0, s])
-        return pltpu.make_async_copy(vmem, hbm, sems.at[1, s])
+            return pltpu.make_async_copy(hbm, vmem, sem)
+        return pltpu.make_async_copy(vmem, hbm, sem)
 
-    def read(r, g):
-        """Where address ``r`` starts a group, read its tile as group
-        ``g``'s: -> the groups read."""
-        w = word_ref[jnp.minimum(r, block - 1)]
-        starts = (r < end) & (w < 0)
+    def unrolled(lo, hi, body):
+        """``body(j, size)`` over ``[lo, hi)``: ``unroll`` at a time, then
+        one at a time."""
+        whole = lo + jax.lax.div(hi - lo, unroll) * unroll
+        pl.loop(lo, whole, step=unroll)(lambda j: body(j, unroll))
+        pl.loop(whole, hi)(lambda j: body(j, 1))
 
-        @pl.when(starts)
-        def _():
-            s = g % slots
+    def issue(c, read):
+        """Start batch ``c``'s reads (or writes); group ``g``'s tile lies at
+        ``g * TILE + shift`` in VMEM."""
+        slot = c & 1
+        shift = (slot - c) * chunk * TILE
 
-            @pl.when(g >= slots)
-            def _():  # group g - slots's write
-                copy(s, False).wait()
+        def start(g, size):
+            at = g * TILE + shift
+            for i in range(size):
+                hbm = pl.multiple_of(tiles_ref[g + i] * TILE, TILE)
+                vmem = pl.multiple_of(at + i * TILE, TILE)
+                copy(v_ref.at[pl.ds(hbm, TILE)], rows.at[pl.ds(vmem, TILE)],
+                     read, slot).start()
 
-            tiles[s] = jax.lax.shift_right_logical(
-                w & _ADDRESS, TILE.bit_length() - 1
-            )
-            copy(s, True).start()
+        unrolled(c * chunk, c * chunk + count(c), start)
 
-        return g + starts.astype(jnp.int32)
+    def wait(c, read):
+        """Batch ``c``'s reads (or writes) landed, exactly the bytes issued
+        on the buffer's semaphore: a whole batch by one wait, the partial
+        last batch of a step by one wait a tile."""
+        n, slot = count(c), c & 1
+        at = pl.multiple_of(slot * chunk * TILE, TILE)
 
-    def add(j, c):
-        groups_read, g, w = c
-        groups_read = read(j + ahead, groups_read)
-        nxt = word_ref[jnp.minimum(j + 1, block - 1)]
-        g = g + (w < 0).astype(jnp.int32)
-        s = g % slots
+        def tiles(size):
+            return copy(v_ref.at[pl.ds(0, size * TILE)],
+                        rows.at[pl.ds(at, size * TILE)], read, slot)
 
-        @pl.when(w < 0)
-        def _():
-            copy(s, True).wait()
+        pl.when(n == chunk)(lambda: tiles(chunk).wait())
+        pl.loop(0, jax.lax.select(n == chunk, 0, n))(
+            lambda k: tiles(1).wait())
 
-        at = pl.multiple_of(s * TILE, TILE)
-        row = rows[pl.ds(at, TILE)].reshape(8, 128)
-        rows[pl.ds(at, TILE)] = jnp.where(
-            lanes == (w & (TILE - 1)), row + tot_ref[j], row
-        ).reshape(TILE)
+    def add(j, size):
+        """Addresses ``j`` to ``j + size``: their tiles loaded together, an
+        address that shares its predecessor's tile adding into that
+        address's result, the results stored in order (the last store to a
+        tile holds every add)."""
+        offs = [off_ref[j + i] for i in range(size)]
+        ats = [pl.multiple_of(o & -TILE, TILE) for o in offs]
+        rows_in = [rows[pl.ds(at, TILE)].reshape(8, 128) for at in ats]
+        out = []
+        for i, (o, row) in enumerate(zip(offs, rows_in)):
+            if i:
+                row = jax.lax.select(
+                    jnp.full((8, 128), ats[i] == ats[i - 1]), out[-1], row
+                )
+            out.append(jax.lax.select(
+                lanes == jnp.full((8, 128), o & (TILE - 1)),
+                row + tot_ref[j + i], row,
+            ))
+        for at, row in zip(ats, out):
+            rows[pl.ds(at, TILE)] = row.reshape(TILE)
 
-        @pl.when((j + 1 == end) | (nxt < 0))
-        def _():
-            copy(s, False).start()
+    # from batch -1, which only reads batch 0 (its count is 0)
+    batches = jax.lax.div(groups + chunk - 1, chunk)
 
-        return groups_read, g, nxt
+    @pl.loop(-1, batches)
+    def _(c):
+        wait(c - 1, False)  # the writes out of batch c + 1's buffer
+        issue(c + 1, True)
+        wait(c, True)
+        at = step * (chunks + 1)
+        unrolled(bounds_ref[at + jnp.maximum(c, 0)], bounds_ref[at + c + 1],
+                 add)
+        issue(c, False)
 
-    groups_read = jax.lax.fori_loop(0, ahead, read, jnp.int32(0))
-    groups_read, _, _ = jax.lax.fori_loop(
-        0, end, add, (groups_read, jnp.int32(-1), word_ref[0])
-    )
-
-    @pl.loop(jnp.maximum(groups_read - slots, 0), groups_read)
-    def _(g):  # the writes not waited for yet
-        copy(g % slots, False).wait()
+    wait(batches - 1, False)
 
 
 def _tail_add(v, n0, uidx, tot):
@@ -350,20 +382,28 @@ def _tail_add(v, n0, uidx, tot):
     return jax.lax.dynamic_update_slice(v, new, (n0,))
 
 
-def tile_scatter_add(v, uidx, tot, distinct, ahead: int = AHEAD,
-                     interpret: bool = False):
+@functools.partial(jax.jit, static_argnames=("chunk", "unroll", "interpret"))
+def tile_scatter_add(v, uidx, tot, distinct, chunk: int = CHUNK,
+                     unroll: int = UNROLL, interpret: bool = False):
     """``v.at[uidx].add(tot, mode="drop", indices_are_sorted=True)``, bit
     for bit, in place: ``uidx`` ascending and unique, its first
     ``distinct`` entries addresses in ``[0, N)`` (non-negative, as
     :func:`index_plan` takes them) and the rest at or past ``N``, which
     are dropped. Each touched tile of ``v`` is read once and written once
-    (:func:`_tile_kernel`), the partial tail by :func:`_tail_add`; nothing
-    else of ``v`` is read, written, copied or laid out anew. What the
-    kernel reads of an address is made here, elementwise over ``cap``
-    entries: its word (the sign bit set where it starts a group) and the
-    live addresses' count in each block."""
+    (:func:`_tile_kernel`, batches of ``chunk`` tiles), the partial tail by
+    :func:`_tail_add`; nothing else of ``v`` is read, written, copied or
+    laid out anew. What the kernel reads is made here over ``cap`` entries,
+    a block a grid step: each address's place in its batch's buffer, the
+    block's tiles in group order (one sort of the block), and each batch's
+    first address. Jitted, so that the programs of a process that hold it
+    at the same shapes trace and lower the kernel once (a few tenths of a
+    second each time)."""
     n, cap = v.shape[0], uidx.shape[0]
     n0 = n // TILE * TILE
+    if n0 == 0:
+        return _tail_add(v, n0, uidx, tot)
+    # a wait's descriptor spans up to a batch of whole tiles
+    chunk = min(chunk, n0 // TILE)
     steps = -(-cap // BLOCK)
     # a block of a 1-D array is whole or a number of its tiles
     block = cap if steps == 1 else -(-cap // (steps * TILE)) * TILE
@@ -377,9 +417,26 @@ def tile_scatter_add(v, uidx, tot, distinct, ahead: int = AHEAD,
     live = (pos < distinct) & (uidx_b < n0)
     tile = jax.lax.shift_right_logical(uidx_b, TILE.bit_length() - 1)
     starts = live & ((pos % block == 0) | (tile != jnp.roll(tile, 1)))
-    word = jnp.where(starts, uidx_b | jnp.int32(-(1 << 31)), uidx_b)
-    ends = jnp.sum(live.reshape(steps, block).astype(jnp.int32), axis=1)
-    smem = pl.BlockSpec((block,), lambda b, e: (b,), memory_space=pltpu.SMEM)
+    starts, live, tile = (
+        a.reshape(steps, block) for a in (starts, live, tile)
+    )
+    group = jnp.cumsum(starts.astype(jnp.int32), axis=1) - 1
+    off = (group // chunk % 2 * chunk + group % chunk) * TILE + (
+        uidx_b.reshape(steps, block) & (TILE - 1))
+    # each block's group starts to its front, in order: its tile list
+    place = pos[:block]
+    _, tiles = jax.lax.sort(
+        (jnp.where(starts, place, block + place), tile), dimension=1,
+        num_keys=1, is_stable=False,
+    )
+    chunks = -(-block // chunk)
+    # bounds[s, c]: step s's first address of batch c (its live count at
+    # the end)
+    first = (jnp.arange(chunks + 1) * chunk)[:, None]
+    bounds = jnp.sum(live[:, None] & (group[:, None] < first), axis=2,
+                     dtype=jnp.int32)
+    smem = pl.BlockSpec((block,), lambda b, g, e: (b,),
+                        memory_space=pltpu.SMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     params = {} if interpret else {
         "compiler_params": pltpu.CompilerParams(
@@ -387,24 +444,26 @@ def tile_scatter_add(v, uidx, tot, distinct, ahead: int = AHEAD,
         )
     }
     out = pl.pallas_call(
-        functools.partial(_tile_kernel, block, ahead),
+        functools.partial(_tile_kernel, chunk, unroll, chunks),
         name="sparse_tile_scatter_add",
         out_shape=_vma_struct_factory(v)(v.shape, v.dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(steps,),
-            in_specs=[smem, smem, hbm],
+            in_specs=[smem, smem, smem, hbm],
             out_specs=hbm,
             scratch_shapes=[
-                pltpu.VMEM((2 * ahead * TILE,), v.dtype),
-                pltpu.SemaphoreType.DMA((2, 2 * ahead)),
-                pltpu.SMEM((2 * ahead,), jnp.int32),
+                pltpu.VMEM((2 * chunk * TILE,), v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
             ],
         ),
-        input_output_aliases={3: 0},
+        input_output_aliases={5: 0},
         interpret=interpret,
         **params,
-    )(ends, word, tot_b, v)
+    )(
+        jnp.sum(starts, axis=1, dtype=jnp.int32), bounds.reshape(-1),
+        off.reshape(-1), tot_b, tiles.reshape(-1), v,
+    )
     if n0 == n:
         return out
     return _tail_add(out, n0, uidx, tot)
@@ -466,8 +525,10 @@ def sparse_update(
     ``sum_k w[idx[b, k]] * val[b, k]``, a function ``add(w2, coef)`` giving
     ``w2[idx[b, k]] += coef[b] * val[b, k]`` (``w2`` is ``w`` or a vector
     derived from it, as pegasos' decayed one), and the launch's counters:
-    ``[distinct addresses, 1 if the launch overflowed, slots]`` (int32)
-    under the plan, ``None`` under the plain pair. The plan's margins are
+    ``[distinct addresses, 1 if the launch overflowed, slots, touched
+    whole tiles]`` (int32; a TPU's final add reads and writes each such
+    tile once, :func:`tile_scatter_add`) under the plan, ``None`` under
+    the plain pair. The plan's margins are
     those of :func:`sparse_matvec` over the same gathered bits."""
     d, n = int(w.shape[0]), int(idx.size)
     name = _resolve_impl(d, n, impl, w.dtype)
@@ -479,10 +540,13 @@ def sparse_update(
         )
     plan = index_plan(idx)
     g, place = plan_gather(w, plan)
+    tile = jax.lax.shift_right_logical(plan.sidx, TILE.bit_length() - 1)
+    new_tile = jnp.concatenate([jnp.ones((1,), bool), tile[1:] != tile[:-1]])
     counters = jnp.stack([
         plan.distinct,
         plan.overflow.astype(jnp.int32),
         jnp.full((), n, jnp.int32),
+        jnp.sum(new_tile & (plan.sidx < d // TILE * TILE), dtype=jnp.int32),
     ])
     return (
         jnp.sum(g * val, axis=1),

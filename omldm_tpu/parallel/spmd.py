@@ -312,7 +312,7 @@ class SPMDTrainer:
         self._fitted_host = 0
         self._steps_host = 0
         self._curve: List[Tuple[Any, int]] = []
-        # lazy [.., dp, hub, 3] counters of the launches whose update
+        # lazy [.., dp, hub, 4] counters of the launches whose update
         # counted (see step_fn), until plan_counts() reads them
         self._counted: List[Any] = []
 
@@ -846,8 +846,9 @@ class SPMDTrainer:
     def plan_counts(self) -> Dict[str, int]:
         """What the launches since the last call report of their sparse
         index plan (``ops.sparse.sparse_update``), summed over launches and
-        workers: ``slots``, the ``slots_distinct`` addresses among them and
-        the ``overflow_launches`` that held more distinct addresses than
+        workers: ``slots``, the ``slots_distinct`` addresses among them, the
+        ``slots_tiles`` whole tiles of 1,024 weights those touch and the
+        ``overflow_launches`` that held more distinct addresses than
         the plan's capacity and ran the plain pair. Empty where no launch ran the
         plan. Waits for the device, like :meth:`curve_slice`."""
         fresh, self._counted = self._counted, []
@@ -855,11 +856,12 @@ class SPMDTrainer:
             return {}
         # hub replicas of a worker agree: take hub shard 0
         per = np.concatenate(
-            [np.asarray(c).reshape(-1, self.hub, 3)[:, 0] for c in fresh]
+            [np.asarray(c).reshape(-1, self.hub, 4)[:, 0] for c in fresh]
         ).astype(np.int64)
         return {
             "slots": int(per[:, 2].sum()),
             "slots_distinct": int(per[:, 0].sum()),
+            "slots_tiles": int(per[:, 3].sum()),
             "overflow_launches": int(per[:, 1].sum()),
         }
 

@@ -284,7 +284,8 @@ class Recorder:
     def add_counts(self, name: str, **counts) -> None:
         """Add to the counters of ``name`` outside any span of it: what a
         launch counted on the device is known only where its outputs are
-        read (``slots``, ``slots_distinct``, ``overflow_launches`` of
+        read (``slots``, ``slots_distinct``, ``slots_tiles``,
+        ``overflow_launches`` of
         ``fit``)."""
         with self._lock:
             into = self._stats_for(name).counts

@@ -11,6 +11,7 @@ import json
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 import numpy as np
 import pytest
 
@@ -222,8 +223,10 @@ class TestSparseOps:
         )
         distinct = len(np.unique(idx))
         cap = plan_capacity(idx.size)
+        # the touched whole tiles: what a TPU's final add reads and writes
+        tiles = len(np.unique(idx[idx < d // 1024 * 1024] // 1024))
         assert list(np.asarray(counters)) == [
-            distinct, int(distinct > cap), idx.size
+            distinct, int(distinct > cap), idx.size, tiles
         ]
         if case == "u_eq_cap":
             assert distinct == cap
@@ -291,7 +294,18 @@ _TILE_CASES = {
     "distinct_cap": (np.r_[_random_addresses(200, 2), np.arange(_LAST, _N)], 214),
     "many_more_groups_than_slots": (
         np.r_[_random_addresses(3000, 3), np.arange(_LAST, _N)], 4000),
+    "only_the_tail": (np.arange(_LAST, _N), 40),
 }
+
+
+def _tiles_addresses(tiles):
+    """Addresses in the first ``tiles`` whole tiles, one to three a tile
+    (so that an unrolled step adds twice into one tile), and the tail."""
+    return np.r_[
+        [a for t in range(tiles)
+         for a in t * 1024 + np.r_[5, 700, 701][: 1 + t % 3]],
+        np.arange(_LAST, _N),
+    ]
 
 
 def _tile_inputs(addrs, cap, seed=0):
@@ -317,11 +331,20 @@ class TestTileScatterAdd:
     (``ops.sparse.tile_scatter_add``, interpreted here) against the XLA line
     it replaces on a TPU, bit for bit, at N = 2^16 + 14."""
 
-    def _check(self, v, uidx, tot, d, ahead=2):
+    def _check(self, v, uidx, tot, d, chunk=2, **kw):
         args = [jnp.asarray(a) for a in (v, uidx, tot)]
         want = _xla_line(*args)
-        got = tile_scatter_add(*args, jnp.int32(d), ahead=ahead, interpret=True)
+        kw.setdefault("interpret", True)
+        got = tile_scatter_add(*args, jnp.int32(d), chunk=chunk, **kw)
         np.testing.assert_array_equal(_bits(got), _bits(want))
+
+    def _check_on_tpu_semantics(self, capfd, *inputs, **kw):
+        """Under the interpreter that models the TPU's DMAs and semaphores:
+        a DMA lands only when a wait asks for its bytes (one not waited for
+        never writes), a wait for more bytes than were issued never
+        returns, and a semaphore left with a count says so at the exit."""
+        self._check(*inputs, interpret=pltpu.InterpretParams(), **kw)
+        assert "non-zero count" not in capfd.readouterr().out
 
     @pytest.mark.parametrize("case", sorted(_TILE_CASES))
     def test_matches_the_xla_line(self, case):
@@ -343,6 +366,7 @@ class TestTileScatterAdd:
         from omldm_tpu.ops import sparse as sp
 
         monkeypatch.setattr(sp, "BLOCK", 1024)
+        sp.tile_scatter_add.clear_cache()  # traced anew with this BLOCK
         rng = np.random.RandomState(4)
         spread = np.sort(rng.choice(np.arange(64) * 1024, size=62, replace=False))
         addrs = np.r_[
@@ -352,7 +376,46 @@ class TestTileScatterAdd:
         v, uidx, tot, d = _tile_inputs(addrs, 2500)
         for edge in (1024, 2048):
             assert uidx[edge - 1] // 1024 == uidx[edge] // 1024
-        self._check(v, uidx, tot, d, ahead=4)
+        self._check(v, uidx, tot, d, chunk=4)
+
+    @pytest.mark.parametrize(
+        "chunk, unroll", [(2, 1), (4, 2), (8, 4), (4, 16)]
+    )
+    def test_batches_and_unrolled_loops(self, chunk, unroll):
+        """Many more tiles in a grid step than two batches, so that each
+        buffer is read into again after its writes were waited for; the
+        issue and add loops unrolled by less, as much as and more than a
+        batch holds, tiles with up to three addresses."""
+        self._check(*_tile_inputs(_tiles_addresses(60), 200), chunk=chunk,
+                    unroll=unroll)
+
+    @pytest.mark.parametrize("tiles", [1, 2, 3, 4, 5, 7, 8, 9, 19])
+    def test_a_last_batch_of_every_size(self, capfd, tiles):
+        """Batches of 8 tiles: a last batch of 1, 2^i and 2^i + 1 tiles,
+        of every bit set and a whole one, each waited for by exactly the
+        bytes issued (a whole batch at once, a partial one a tile at a
+        time), on the TPU's semantics."""
+        self._check_on_tpu_semantics(
+            capfd, *_tile_inputs(_tiles_addresses(tiles), 64), chunk=8,
+            unroll=4,
+        )
+
+    @pytest.mark.parametrize("case", ["distinct_0", "only_the_tail"])
+    def test_no_live_address_waits_for_nothing(self, capfd, case):
+        """No address in a whole tile (none at all, or all in the partial
+        tail): the kernel issues no DMA and waits for none, so it neither
+        hangs nor leaves a count behind."""
+        self._check_on_tpu_semantics(
+            capfd, *_tile_inputs(*_TILE_CASES[case]), chunk=8
+        )
+
+    def test_a_vector_under_one_tile(self):
+        """No whole tile to move: the in-place update of the tail alone."""
+        rng = np.random.RandomState(6)
+        v = rng.randn(700).astype(np.float32)
+        uidx = np.r_[3, 64, 699, 700 + np.arange(5)].astype(np.int32)
+        tot = rng.randn(8).astype(np.float32)
+        self._check(v, uidx, tot, 3)
 
     def test_under_shard_map_2x2(self):
         """dp x hub = 2 x 2: each shard adds into its own block, and some
@@ -380,7 +443,7 @@ class TestTileScatterAdd:
             ))
 
         def kernel(v, u, t, d):
-            return tile_scatter_add(v, u, t, d[0], ahead=2, interpret=True)
+            return tile_scatter_add(v, u, t, d[0], chunk=2, interpret=True)
 
         jaxpr = str(jax.make_jaxpr(sharded(kernel, True))(v, uidx, tot, ds))
         assert "sparse_tile_scatter_add" in jaxpr
@@ -391,8 +454,10 @@ class TestTileScatterAdd:
     def test_which_add_runs_is_read_from_the_backend_and_counted(self, monkeypatch):
         """``plan_scatter_add`` counts its final add at trace time under
         ``sparse_scatter_path``: ``xla`` here, ``pallas`` (the kernel in the
-        program) where the backend is a TPU."""
-        from omldm_tpu.ops.sparse import sparse_update
+        program) where the backend is a TPU, with the kernel's tiles a
+        batch (``tiles_per_batch``: ``CHUNK``, or the vector's whole tiles
+        where it has fewer)."""
+        from omldm_tpu.ops.sparse import CHUNK, sparse_update
         from omldm_tpu.utils import tracing
 
         d, idx, val = _dup_heavy(4096, 3)()
@@ -411,7 +476,10 @@ class TestTileScatterAdd:
         assert counts == {"xla": 1} and "pallas_call" not in jaxpr
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         counts, jaxpr = traced()
-        assert counts == {"pallas": 1} and "sparse_tile_scatter_add" in jaxpr
+        assert counts == {
+            "pallas": 1, "tiles_per_batch": min(CHUNK, d // 1024)
+        }
+        assert "sparse_tile_scatter_add" in jaxpr
 
 
 class TestPlanInTheSPMDStep:
@@ -469,6 +537,12 @@ class TestPlanInTheSPMDStep:
             assert counts["slots"] == steps * 2 * n
             assert counts["slots_distinct"] == sum(
                 len(np.unique(np.append(x[0][w], d - 1)))
+                for x, _, _ in batches for w in range(2)
+            )
+            # the whole tiles of 1,024 they touch (the bias, d - 1, lies
+            # past the last)
+            assert counts["slots_tiles"] == sum(
+                len(np.unique(x[0][w][x[0][w] < d // 1024 * 1024] // 1024))
                 for x, _, _ in batches for w in range(2)
             )
             # 41 addresses at most against a capacity of 72

@@ -7,6 +7,7 @@ fixture would then skip. A compile that passes here is not a chip run.
 """
 
 import os
+import re
 from types import SimpleNamespace
 
 import jax
@@ -114,7 +115,9 @@ def test_plan_update_on_a_tpu_adds_by_tiles_in_place(one_chip_mesh, monkeypatch)
     only other instruction there with a result of 2^28 elements is the
     in-place update of the 14 weights past the last whole tile (a fusion
     whose root is a ``dynamic-update-slice`` of 14 elements): no copy, no
-    relayout, no other fusion of N. The state stays donated."""
+    relayout, no other fusion of N. The state stays donated, and the
+    kernel's VMEM (its two batches of tiles) fits the 16 MiB a v5e's
+    kernel may use by default."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     n_weights = (1 << 28) + 14
     compiled = compiled_plan_update(one_chip_mesh, 4096, n_weights)
@@ -136,6 +139,10 @@ def test_plan_update_on_a_tpu_adds_by_tiles_in_place(one_chip_mesh, monkeypatch)
     kernel_lines = [l for _, op, _, l in ops("ENTRY") if op == "custom-call"
                     and "sparse_tile_scatter_add" in l]
     assert len(kernel_lines) == 1 and "tpu_custom_call" in kernel_lines[0]
+    vmem = [int(size) for space, size in re.findall(
+        r'"memory_space":"(\d+)","offset":"\d+","size":"(\d+)"',
+        kernel_lines[0]) if space == "1"]
+    assert vmem == [2 * sp.CHUNK * sp.TILE * 4] and vmem[0] <= 16 << 20
     # the conditional whose branch holds the kernel: the plan's scatter half
     [(branches, taken)] = {
         (tuple(branches), b)
